@@ -43,7 +43,6 @@ _MESH_MARKERS = _MESH_CTORS | _MAKE_MESH | {
     "jax.sharding.NamedSharding",
     "shard_map",
     "jax.shard_map",
-    "jax.experimental.shard_map.shard_map",
 }
 
 

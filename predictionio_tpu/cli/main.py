@@ -112,7 +112,10 @@ def _mesh_ctx(args, variant: dict | None = None):
     (shape = device counts per data/model axis)."""
     from predictionio_tpu.parallel import distributed
     from predictionio_tpu.parallel.mesh import ComputeContext
+    from predictionio_tpu.utils.compile_cache import configure_compile_cache
 
+    # every verb that compiles passes here first
+    configure_compile_cache()
     distributed.initialize()
     mesh_conf = (variant or {}).get("meshConf") or {}
     mesh_shape = None
@@ -130,6 +133,19 @@ def _mesh_ctx(args, variant: dict | None = None):
             ) from None
     return ComputeContext.create(
         batch=_variant_batch(args, variant), mesh_shape=mesh_shape
+    )
+
+
+def _print_compute_line(ctx) -> None:
+    """The one stdout line of ``train``/``deploy`` that names the
+    backend this process got (same wording as ``status``), plus the
+    mesh laid over it."""
+    from predictionio_tpu.parallel.mesh import describe_devices
+
+    mesh = "x".join(str(n) for n in ctx.mesh.devices.shape)
+    print(
+        f"Compute: {describe_devices(ctx.mesh.devices.flat)} mesh={mesh}",
+        flush=True,
     )
 
 
@@ -602,22 +618,21 @@ def cmd_status(args) -> int:
             args.metrics_url, getattr(args, "access_key", "")
         )
 
+    import jax
+
     from predictionio_tpu.data.storage import get_storage
-    from predictionio_tpu.parallel.mesh import (
-        DeviceInitTimeout,
-        devices_with_timeout,
-    )
+    from predictionio_tpu.parallel.mesh import describe_devices
+
     print(f"PredictionIO-TPU {__version__}")
     try:
-        devices = devices_with_timeout()
-    except DeviceInitTimeout as e:
+        devices = jax.devices()
+    except RuntimeError as e:
+        # e.g. "Unable to initialize backend 'tpu'": another process
+        # (a deployed server) holds the chip, or there is none
         print(f"[ERROR] Compute: {e}")
         print("Compute status: FAILED")
         return 1
-    print(
-        f"Compute: {len(devices)} {devices[0].platform} device(s): "
-        f"{[str(d) for d in devices[:8]]}"
-    )
+    print(f"Compute: {describe_devices(devices)}")
     problems = get_storage().verify_all_data_objects()
     if problems:
         for p in problems:
@@ -1239,6 +1254,8 @@ def cmd_train(args) -> int:
         stop_after_read=args.stop_after_read,
         stop_after_prepare=args.stop_after_prepare,
     )
+    ctx = _mesh_ctx(args, variant_dict)
+    _print_compute_line(ctx)
     instance_id = run_train(
         engine,
         params,
@@ -1246,7 +1263,7 @@ def cmd_train(args) -> int:
         engine_variant=variant,
         engine_factory=args.engine or "",
         workflow=workflow,
-        ctx=_mesh_ctx(args, variant_dict),
+        ctx=ctx,
         checkpoint_dir=args.checkpoint_dir or None,
         checkpoint_every=args.checkpoint_every,
         resume=args.resume,
@@ -1335,12 +1352,33 @@ def cmd_deploy(args) -> int:
                 "error: --feedback requires --event-server-app <existing app>"
             )
         feedback_app_id = app.id
+    multi = args.workers > 1
+    if multi and (err := _reuseport_unsupported()):
+        print(err, file=sys.stderr)
+        return 1
+    ctx = _mesh_ctx(args, variant_dict)
+    _print_compute_line(ctx)
+    platform = ctx.mesh.devices.flat[0].platform
+    if multi and platform != "cpu":
+        # refused before any model is staged: this process now holds
+        # the accelerator, and each re-exec'd worker would stage the
+        # model on its own backend — which fails at backend init,
+        # because a chip belongs to one process
+        print(
+            f"error: --workers {args.workers} needs the cpu backend, "
+            f"this process got {platform!r}: one process owns an "
+            "accelerator, so the other workers could not stage the "
+            "model. Serve the device from one worker (put CPU "
+            "--workers fronts or `pio-tpu router` ahead of it)",
+            file=sys.stderr,
+        )
+        return 1
     server = EngineServer(
         engine,
         params,
         engine_id=engine_id,
         engine_variant=variant,
-        ctx=_mesh_ctx(args, variant_dict),
+        ctx=ctx,
         feedback=args.feedback,
         feedback_app_id=feedback_app_id,
         log_url=args.log_url or None,
@@ -1354,10 +1392,6 @@ def cmd_deploy(args) -> int:
         tenants=tenants,
         quantize=args.quantize,
     )
-    multi = args.workers > 1
-    if multi and (err := _reuseport_unsupported()):
-        print(err, file=sys.stderr)
-        return 1
     http = server.serve(
         host=args.ip, port=args.port,
         reuse_port=multi or args.reuse_port,
@@ -1369,9 +1403,8 @@ def cmd_deploy(args) -> int:
         from predictionio_tpu.serving import workers as _workers
 
         print(
-            "note: every worker stages the model itself — multi-worker "
-            "deploy is for CPU-backend serving fronts (one process owns "
-            "an accelerator); storage must be a shared backend",
+            "note: every worker stages the model itself; storage must "
+            "be a shared backend",
             file=sys.stderr,
         )
         return _workers.serve_with_workers(
@@ -1401,6 +1434,10 @@ def cmd_trainer(args) -> int:
         args.engine_id or args.engine or "default",
     )
     if not args.no_supervise and not args.once:
+        # the supervising parent never initialises a JAX backend (it
+        # imports neither jax nor anything that builds a
+        # ComputeContext; serving.workers is stdlib-only), so the
+        # training child it spawns is the one process on the chip
         from predictionio_tpu.serving import workers as _workers
 
         child_argv = list(args.raw_argv) + [
@@ -2722,13 +2759,6 @@ def main(argv: list[str] | None = None) -> int:
     except CommandError as e:
         print(f"error: {e}", file=sys.stderr)
         return 1
-    except Exception as e:
-        from predictionio_tpu.parallel.mesh import DeviceInitTimeout
-
-        if isinstance(e, DeviceInitTimeout):
-            print(f"error: {e}", file=sys.stderr)
-            return 1
-        raise
 
 
 if __name__ == "__main__":

@@ -369,9 +369,8 @@ class EngineClient:
         """Many queries in one round trip (``/batch/queries.json``,
         ≤100 per call); returns per-query slots:
         ``{"status": 200, "prediction": ...}`` or
-        ``{"status": 4xx/5xx, "message": ...}``. Roughly an order of
-        magnitude more throughput per connection than send_query
-        (BASELINE.md)."""
+        ``{"status": 4xx/5xx, "message": ...}``. One HTTP round trip
+        and one trip through the micro-batcher for the whole list."""
         return _request(
             f"{self._base}/batch/queries.json",
             "POST",

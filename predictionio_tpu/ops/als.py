@@ -58,6 +58,7 @@ from predictionio_tpu.parallel.mesh import (
     assert_phantom_rows_zero,
 )
 from predictionio_tpu.parallel.partition import shard_map
+from predictionio_tpu.utils import profiling
 
 logger = logging.getLogger(__name__)
 
@@ -213,8 +214,13 @@ def _load_alspack():
             c.POINTER(c.c_float), c.POINTER(c.c_float),
         ]
         _ALSPACK_LIB = lib
-    except Exception:  # noqa: BLE001 - native is an optimization only
-        logger.debug("native alspack unavailable", exc_info=True)
+        logger.info("ALS packer: native (native/libpio_alspack.so)")
+    except Exception as e:  # noqa: BLE001 - native is an optimization only
+        # load_native_lib's RuntimeError carries the compiler's output
+        logger.warning(
+            "ALS packer: numpy fallback — native/libpio_alspack.so "
+            "unavailable (%s: %s)", type(e).__name__, e,
+        )
         _ALSPACK_LIB = None
     return _ALSPACK_LIB
 
@@ -446,9 +452,9 @@ def _resolve_compute(compute_dtype: str | None):
     backend, f32 elsewhere. The default is bf16-on-TPU because the
     quality impact is unmeasurable on ranking tasks — planted-cluster
     precision@10 0.9729 (f32) vs 0.9730 (bf16), top-10 overlap 99.5%
-    (BASELINE.md quality A/B) — while epochs run 12–14% faster; pass
-    ``"float32"`` (or set the env knob) to opt out. Unknown names fail
-    here — at solver build — with the supported list.
+    (CPU quality A/B); pass ``"float32"`` (or set the env knob) to opt
+    out. Unknown names fail here — at solver build — with the
+    supported list.
     """
     name = (compute_dtype or "").strip().lower()
     if not name:
@@ -515,8 +521,8 @@ def _resolve_gather_layout() -> str:
       width, unpadded whenever ``s·block_len`` is a multiple of 128
       (true for every bucket with s ≥ 2 at the default block_len=64;
       the s=1 bucket stays lane-padded). Same math, same results.
-    * ``auto`` (default) — kmajor on the TPU backend (measured 4%
-      faster epochs on v5e, BASELINE.md A/B table), kminor elsewhere.
+    * ``auto`` (default) — kmajor on the TPU backend, kminor
+      elsewhere.
     """
     name = os.environ.get(
         "PIO_ALS_GATHER_LAYOUT", "auto"
@@ -807,7 +813,7 @@ def make_train_step(
     Returned fn: ``(x, y, u_slabs, u_heavy, i_slabs, i_heavy, lam,
     n_iters) → (x, y)`` with ``n_iters`` static. Epochs chain on-device
     through a ``fori_loop``, amortizing host↔device dispatch latency
-    (material on tunneled TPU platforms) across the whole run.
+    across the whole run.
     """
     solve_u = make_bucketed_solver(
         ctx, user_packed, implicit, alpha, compute_dtype
@@ -1533,10 +1539,10 @@ def train_als(
                 time.sleep(chaos_sleep)
             with timer.step("als/user_solve", sync_value=None):
                 user_factors = solve_u_half(item_factors, lam)
-                _sync_scalar(user_factors)
+                profiling.sync(user_factors)
             with timer.step("als/item_solve", sync_value=None):
                 item_factors = solve_i_half(user_factors, lam)
-                _sync_scalar(item_factors)
+                profiling.sync(item_factors)
             ran_any = True
             _maybe_checkpoint(
                 ckpt_path, checkpoint_every, it + 1, iterations,
@@ -1666,15 +1672,6 @@ def _maybe_checkpoint(
                 "Mid-training factor checkpoints written (atomic npz; "
                 "resume picks up the latest after a crash)",
             ).inc()
-
-
-def _sync_scalar(arr) -> None:
-    # device→host fetch: the only reliable barrier on every platform.
-    # This helper is the DELIBERATE sync point for the training loop —
-    # keep it out of jit bodies and the batch_predict_launch path,
-    # where the device-sync lint rules (docs/static_analysis.md) ban
-    # implicit barriers
-    jax.device_get(arr[0, 0])
 
 
 def _write_checkpoint(path: str, **arrays) -> None:

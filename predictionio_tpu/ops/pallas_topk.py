@@ -12,11 +12,11 @@ Top-k inside the kernel is lazy extraction (Mosaic has no ``lax.top_k``
 lowering): a ``while_loop`` of (row-max, first-argmax-by-iota,
 sorted-insert) that runs only while some row's remaining block scores
 beat that row's kth-best — a warm best-list absorbs a random-order
-block in ~1-2 iterations. Measured on v5e-1: B=256..1024 × I=1M is
-21-29% faster than the XLA matmul+top_k path, with O(B·num) memory
-instead of the [B, I] intermediate (4 GB at B=1024); below ~0.5 GB of
-intermediate XLA wins slightly, which the dispatcher in
-:mod:`predictionio_tpu.ops.similarity` accounts for.
+block in ~1-2 iterations. Memory is O(B·num) instead of the [B, I]
+intermediate (4 GB at B=1024 × I=1M). How it compares in time with the
+XLA matmul+top_k path is not measured on the installed JAX (ROADMAP
+S4); the dispatcher in :mod:`predictionio_tpu.ops.similarity` hands it
+only shapes whose intermediate reaches 512 MiB.
 
 Replaces the reference's per-query Spark job
 (examples/scala-parallel-recommendation/custom-query/src/main/scala/

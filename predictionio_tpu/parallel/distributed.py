@@ -54,13 +54,11 @@ def initialize(
         process_id=process_id,
     )
     _initialized = True
-    from predictionio_tpu.parallel.mesh import devices_with_timeout
-
     logger.info(
         "jax.distributed initialized: process %d/%d, %d global devices",
         jax.process_index(),
         jax.process_count(),
-        len(devices_with_timeout()),
+        len(jax.devices()),
     )
 
 
@@ -93,6 +91,12 @@ def launch_processes(
     flows through exactly as the reference forwards it). Returns the
     first nonzero child exit code, else 0; on failure or timeout the
     remaining children are terminated.
+
+    One process drives every chip of its host, and a chip belongs to
+    one process, so ``num_processes > 1`` is for several hosts (or the
+    CPU backend's virtual devices): N copies started on ONE TPU host
+    each try to take all of its chips, and all but the first fail at
+    backend init. Nothing here pins a process to a subset of chips.
     """
     import subprocess
     import time as _time

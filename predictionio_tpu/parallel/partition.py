@@ -172,53 +172,22 @@ def shard_pytree(
 
 
 # --------------------------------------------------------------------------
-# shard_map (version-portable)
+# shard_map
 # --------------------------------------------------------------------------
 
 
 def shard_map(body, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across the jax versions this repo meets.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=)``; the 0.4.x
-    line only has ``jax.experimental.shard_map.shard_map(...,
-    check_rep=)`` — same semantics, renamed knob. The sharded ALS path
-    (and with it every multichip measurement) must run on BOTH: before
-    this shim the model-sharded trainer raised ``AttributeError`` on
-    0.4.x and the entire sharded test block sat in
-    scripts/known_failures.txt, dryrun-green but never measured.
-    """
-    if hasattr(jax, "shard_map"):
-        import inspect
-
-        # discriminate on the kwarg the THIS version accepts, not on
-        # attribute presence: the 0.5–0.6 band exposes jax.shard_map
-        # with the old check_rep name, so keying on hasattr alone
-        # would TypeError on exactly the versions this shim spans
-        try:
-            params = inspect.signature(jax.shard_map).parameters
-        except (TypeError, ValueError):  # C-accelerated / no signature
-            params = {}
-        if "check_vma" in params:
-            kwargs = {"check_vma": check}
-        elif "check_rep" in params:
-            kwargs = {"check_rep": check}
-        else:
-            kwargs = {}
-        return jax.shard_map(
-            body,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            **kwargs,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
+    """``jax.shard_map`` with the varying-manual-axes check off by
+    default: the ALS epoch ``fori_loop`` carries factors that enter
+    unvarying over ``data`` and come back varying (after the
+    ``all_gather`` + ``take`` reassembly), which ``check_vma=True``
+    rejects as a carry type mismatch."""
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        check_rep=check,
+        check_vma=check,
     )
 
 
@@ -263,9 +232,7 @@ def mesh_from_topology(
     ``n_devices`` (the multichip bench sweeps 1→2→4→8 this way on one
     simulated host platform).
     """
-    from predictionio_tpu.parallel.mesh import devices_with_timeout
-
-    devs = list(devices if devices is not None else devices_with_timeout())
+    devs = list(devices if devices is not None else jax.devices())
     n = n_devices if n_devices is not None else len(devs)
     if n > len(devs):
         raise ValueError(f"need {n} devices, have {len(devs)}")

@@ -12,11 +12,10 @@ Capability parity with the reference's ServerActor/MasterActor
 * ``POST /batch/queries.json`` → many queries in one HTTP round trip
   with per-query statuses (shape mirrors the event API's
   ``/batch/events.json``). TPU-first extension with no reference
-  counterpart: the Python HTTP tier costs ~3.5 ms/request on a host
-  core (BASELINE.md) while the batched device path serves tens of
-  thousands of predictions per second — batching amortizes the HTTP
-  tier away and the submitted queries coalesce in the micro-batcher
-  into full device dispatches
+  counterpart: the Python HTTP tier costs far more per request than
+  the batched device path does per prediction — batching amortizes the
+  HTTP tier away and the submitted queries coalesce in the
+  micro-batcher into full device dispatches
 * ``POST /reload``       → hot-swap to the latest COMPLETED instance
   (MasterActor :337-363)
 * ``POST /stop``         → undeploy (Console.undeploy posts here, :905-932)
@@ -692,10 +691,7 @@ class EngineServer:
                 # device barrier before the batcher stops its sync
                 # clock: async dispatch would otherwise make
                 # pio_device_sync_seconds measure enqueue, not work
-                if isinstance(out, (list, tuple)) and out:
-                    profiling.sync(out[-1])
-                else:
-                    profiling.sync(out)
+                profiling.sync(out)
                 return out
 
             return single
@@ -731,12 +727,13 @@ class EngineServer:
         first occurrence). Algorithms expose a neutral ``warmup_query``
         (default ``{}``).
 
-        Failure policy: a first-bucket failure means the warmup query is
-        unsupported for this algorithm (INFO, served cold by design); a
-        failure AFTER a smaller bucket succeeded suggests predict itself
-        is broken at that shape (WARNING). One failing bucket does not
-        skip the rest — larger buckets may compile fine — but repeated
-        failures cap out rather than burn the whole reload window.
+        Failure policy: an algorithm whose ``warmup_query`` is None is
+        served cold by design (INFO). A bucket that raises — a failed
+        compile as much as an unsupported query — is a WARNING naming
+        the exception type, and leaves ``pio_warmup_complete`` at 0.
+        One failing bucket does not skip the rest — larger buckets may
+        compile fine — but repeated failures cap out rather than burn
+        the whole reload window.
 
         Returns True when every attempted bucket compiled (cold-by-
         design algorithms don't count against it) — the condition for
@@ -789,17 +786,17 @@ class EngineServer:
                     )
                     failures += 1
                     if compiled == 0:
-                        logger.info(
-                            "%s: warmup query unsupported (batch %d: %s)"
-                            " — serving cold",
-                            name, bucket, e,
+                        logger.warning(
+                            "%s: warmup FAILED at batch %d (%s: %s) — "
+                            "serving cold, pio_warmup_complete stays 0",
+                            name, bucket, type(e).__name__, e,
                         )
                     else:
                         logger.warning(
                             "%s: warmup FAILED at batch %d after smaller "
                             "buckets compiled — predict may be broken at "
-                            "this shape: %s",
-                            name, bucket, e,
+                            "this shape: %s: %s",
+                            name, bucket, type(e).__name__, e,
                         )
                     if failures >= 3:
                         break

@@ -3,8 +3,8 @@
 Why: the reference's HTTP tier (spray on the JVM,
 ``CreateServer.scala:495-647``) scales across cores with threads; a
 Python front-end cannot — the GIL serializes request parsing, so one
-process saturates one core at ~1k QPS while the framework underneath
-does ~48k predictions/s (BASELINE.md). The multi-worker shape is N
+process saturates one core long before the batched predict path
+underneath does. The multi-worker shape is N
 processes, each binding the same host:port with ``SO_REUSEPORT``; the
 kernel load-balances accepted connections across them, no proxy in
 front.
@@ -25,10 +25,11 @@ Caveats:
 * every worker opens storage independently — the backends must be
   multi-process-shared (sqlite/eventlog/postgres/mysql/httpstore; the
   ``memory`` backend is per-process and will serve inconsistent data).
-* for ``deploy``, each worker stages the model on its own backend. On a
-  host-attached accelerator only one process can own the device — use
-  workers > 1 for CPU-backend serving fronts, or keep the device server
-  single-worker behind these as a second tier.
+* for ``deploy``, each worker stages the model on its own backend. A
+  chip belongs to one process, so ``pio-tpu deploy --workers N`` with
+  N > 1 is refused on any backend but ``cpu``: use workers > 1 for
+  CPU-backend serving fronts, and keep the device server single-worker
+  behind them (or behind ``pio-tpu router``) as a second tier.
 """
 
 from __future__ import annotations

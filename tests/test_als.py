@@ -251,7 +251,7 @@ class TestComputeDtype:
 
         monkeypatch.delenv("PIO_ALS_COMPUTE_DTYPE", raising=False)
         # default is "auto": f32 on the CPU backend the tests pin
-        # (bf16 on TPU — quality A/B in BASELINE.md)
+        # (bf16 on TPU — see ops.als._resolve_compute)
         assert _resolve_compute(None) is None
         assert _resolve_compute("auto") is None
         assert _resolve_compute("float32") is None

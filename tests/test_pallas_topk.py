@@ -162,45 +162,34 @@ class TestDispatch:
 )
 class TestCompiledMosaicOnTPU:
     """Compiled (non-interpreter) Mosaic kernel vs the XLA path on real
-    hardware — covers layouts CI's interpreter runs can't: non-128-
-    multiple num, non-power-of-two batch (ADVICE r1). The test process
-    pins CPU, so the compiled check runs in a TPU subprocess."""
+    hardware, in the variants and at the shapes serving hands it: f32
+    and int8+scale items, with and without an [I] phantom mask, B=256
+    and B=8 at rank 32, I=524,288 (the dispatcher's threshold) and a
+    ragged tail; then f32 at layouts that are neither powers of two nor
+    multiples of 128 (odd batch and num, rank 16, block 512). The
+    shapes and checks live in ``chip_smoke.py``'s kernel child, which this runs on the TPU (the test process itself
+    pins CPU). A chip that cannot be had is a failure, not a skip."""
 
     def test_compiled_matches_xla(self):
+        import json
         import subprocess
         import sys
 
-        code = r"""
-import os
-
-import numpy as np
-import jax, jax.numpy as jnp
-from predictionio_tpu.ops.pallas_topk import fused_top_k_dot
-from predictionio_tpu.ops.similarity import _top_k_dot_xla
-assert jax.default_backend() == "tpu", jax.default_backend()
-rng = np.random.default_rng(3)
-for b, n_items, num in ((5, 4000, 7), (3, 1000, 50), (8, 2048, 100)):
-    q = jnp.asarray(rng.normal(size=(b, 16)).astype(np.float32))
-    it = jnp.asarray(rng.normal(size=(n_items, 16)).astype(np.float32))
-    ps, pi = fused_top_k_dot(q, it, num, block=512)
-    xs, xi = _top_k_dot_xla(q, it, num)
-    np.testing.assert_allclose(
-        np.asarray(jax.device_get(ps)), np.asarray(jax.device_get(xs)),
-        rtol=1e-4, atol=1e-4,
-    )
-    assert (np.asarray(jax.device_get(pi))
-            == np.asarray(jax.device_get(xi))).all(), (b, n_items, num)
-print("compiled mosaic OK")
-"""
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         env = {
-            k: v for k, v in os.environ.items()
-            if k not in ("JAX_PLATFORMS", "XLA_FLAGS")
+            k: v for k, v in os.environ.items() if k != "XLA_FLAGS"
         }
+        env["JAX_PLATFORMS"] = "tpu"
+        env["PYTHONPATH"] = repo
         out = subprocess.run(
-            [sys.executable, "-c", code],
-            env=env, capture_output=True, text=True, timeout=600,
+            [
+                sys.executable, os.path.join(repo, "chip_smoke.py"),
+                "--child", "kernels",
+            ],
+            env=env, capture_output=True, text=True, timeout=900,
         )
-        if "UNAVAILABLE" in (out.stderr or ""):
-            pytest.skip("TPU backend unavailable")
         assert out.returncode == 0, out.stderr[-2000:]
-        assert "compiled mosaic OK" in out.stdout
+        result = json.loads(out.stdout.strip().splitlines()[-1])
+        assert result["device"]["platform"] == "tpu"
+        assert result["cases"] == 15 and result["mosaic"] is True
+        assert result["dispatcher_takes_kernel"] is True

@@ -170,33 +170,6 @@ class TestTopology:
             partition.mesh_from_topology(99)
 
 
-class TestShardMapCompat:
-    def test_shim_runs_on_this_jax(self, ctx42):
-        """The version-portable shard_map executes a trivial body —
-        guards the 0.4.x (check_rep) vs newer (check_vma) seam that
-        kept the whole sharded block in known_failures."""
-        import jax.numpy as jnp
-
-        def body(x):
-            return x * 2
-
-        f = jax.jit(
-            partition.shard_map(
-                body,
-                mesh=ctx42.mesh,
-                in_specs=(P(MODEL_AXIS, None),),
-                out_specs=P(MODEL_AXIS, None),
-            )
-        )
-        x = jax.device_put(
-            np.ones((8, 2), np.float32),
-            NamedSharding(ctx42.mesh, P(MODEL_AXIS, None)),
-        )
-        np.testing.assert_allclose(np.asarray(f(x)), 2.0)
-        assert isinstance(f(x), jax.Array)
-        del jnp
-
-
 class TestStageFactorMatrix:
     def test_pads_and_masks(self, ctx42):
         arr = np.random.default_rng(0).normal(size=(9, 4)).astype(
