@@ -2,9 +2,10 @@
 registered with one consistent (kind, label-set) project-wide.
 
 ``span-leak``: a span context manager (``tracer.trace(...)``,
-``tracer.child(...)``, ``tracing.span(...)``) or raw ``tracing.Span``
-construction must reach a ``with`` statement — directly, via a variable
-later used as a ``with`` context expression in the same function (the
+``tracer.child(...)``, ``tracing.span(...)``, ``tracing.stage(...)``)
+or raw ``tracing.Span`` construction must reach a ``with`` statement —
+directly, via a variable later used as a ``with`` context expression in
+the same function (the
 ``span_cm = ... ; with span_cm:`` pattern), or by being returned to the
 caller. Anything else can leak an open span on an exception path, which
 pins the trace in the recorder's open table until eviction.
@@ -33,8 +34,8 @@ _METRIC_KINDS = {"counter", "gauge", "histogram"}
 def _span_call_desc(call: ast.Call) -> str | None:
     func = call.func
     dotted = astutil.dotted_name(func)
-    if dotted == "tracing.span":
-        return "tracing.span(...)"
+    if dotted in ("tracing.span", "tracing.stage"):
+        return f"{dotted}(...)"
     if dotted == "tracing.Span":
         return "tracing.Span(...)"
     if isinstance(func, ast.Attribute):

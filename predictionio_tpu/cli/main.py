@@ -858,7 +858,35 @@ def cmd_profile(args) -> int:
         "spans.json opens at https://ui.perfetto.dev; "
         "jax_trace/ loads in TensorBoard."
     )
+    try:
+        with open(os.path.join(dest, "summary.json")) as f:
+            _print_profile_summary(json.load(f))
+    except (OSError, ValueError):
+        pass  # an older server's artifact carries no summary
     return 0
+
+
+def _print_profile_summary(summary: dict) -> None:
+    """summary.json (utils/profiling.summarize) as a few lines: the
+    device's share of the window, its time by named scope, and what
+    the host was doing while it idled."""
+    if not summary:
+        print("summary.json: the trace holds no stage or device event.")
+        return
+    device, idle = summary["device"], summary["idle"]
+    print(
+        f"window {summary['window_s']:.3f} s: device busy "
+        f"{device['busy_s']:.3f} s, idle {100 * device['idle_share']:.1f}%"
+    )
+    for scope, seconds in list(device["by_scope"].items())[:8]:
+        print(f"  device {scope:<28s} {seconds:9.4f} s")
+    for state, seconds in sorted(idle.items(), key=lambda kv: -kv[1]):
+        if seconds > 0:
+            print(f"  idle   {state:<28s} {seconds:9.4f} s")
+    print(
+        f"  {100 * summary['idle_attributed_share']:.1f}% of the idle "
+        "time has a named state (docs/observability.md)"
+    )
 
 
 def cmd_lint(args) -> int:

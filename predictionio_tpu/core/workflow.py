@@ -238,15 +238,18 @@ def run_train(
             timer = StepTimer()
             for algo in algorithms:
                 algo.timer = timer
-            with timer.step("train/total"), trace():
+            # train-time telemetry joins the process registry: a trainer
+            # that also serves (or exposes /metrics) scrapes both as one
+            from predictionio_tpu.obs import get_registry
+            from predictionio_tpu.obs.device import CompileWatch
+
+            with timer.step("train/total"), trace(), CompileWatch(
+                get_registry()
+            ):
                 models = engine.train(
                     ctx, params, workflow, algorithms=algorithms
                 )
             timer.log_summary(prefix=f"[{engine_id}] ")
-            # train-time telemetry joins the process registry: a trainer
-            # that also serves (or exposes /metrics) scrapes both as one
-            from predictionio_tpu.obs import get_registry
-
             timer.publish(get_registry())
             instance = dataclasses.replace(
                 instance, env={"timing": timer.to_json()}

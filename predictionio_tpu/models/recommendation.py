@@ -33,6 +33,7 @@ from predictionio_tpu.core import (
 from predictionio_tpu.core.controller import SanityCheck
 from predictionio_tpu.data.eventframe import Interactions
 from predictionio_tpu.data.store import EventStore
+from predictionio_tpu.obs import tracing
 from predictionio_tpu.ops import similarity
 from predictionio_tpu.ops.als import train_als
 from predictionio_tpu.parallel import partition
@@ -270,28 +271,30 @@ class ALSAlgorithm(Algorithm[RecTrainingData, ALSRecModel, dict, dict]):
         top-k size clamps to the REAL catalog, never the padded one."""
         if not queries:
             return None
-        n_items = len(model.item_map)
-        num = max(int(q.get("num", 10)) for q in queries)
-        num = min(num, n_items)
-        # bucket the jit-static shapes (top-k size and batch rows) to
-        # powers of two so arbitrary client input cannot force unbounded
-        # recompiles at serving time
-        num_bucket = min(1 << max(0, (num - 1)).bit_length(), n_items)
-        user_idx = np.asarray(
-            [model.user_map.get(q.get("user", ""), -1) for q in queries],
-            np.int32,
-        )
-        idx = np.clip(user_idx, 0, None)
-        batch_bucket = 1 << max(0, (len(idx) - 1)).bit_length()
-        if batch_bucket > len(idx):
-            idx = np.pad(idx, (0, batch_bucket - len(idx)))
+        with tracing.stage(tracing.PREDICT_PREP):
+            n_items = len(model.item_map)
+            num = max(int(q.get("num", 10)) for q in queries)
+            num = min(num, n_items)
+            # bucket the jit-static shapes (top-k size and batch rows)
+            # to powers of two so arbitrary client input cannot force
+            # unbounded recompiles at serving time
+            num_bucket = min(1 << max(0, (num - 1)).bit_length(), n_items)
+            user_idx = np.asarray(
+                [model.user_map.get(q.get("user", ""), -1) for q in queries],
+                np.int32,
+            )
+            idx = np.clip(user_idx, 0, None)
+            batch_bucket = 1 << max(0, (len(idx) - 1)).bit_length()
+            if batch_bucket > len(idx):
+                idx = np.pad(idx, (0, batch_bucket - len(idx)))
         # fused gather + score + top-k on device: uploads only `idx`
         # (factors are staged jax.Arrays after stage_model; the
         # evaluation path passes host arrays and pays the upload there)
-        scores, items = similarity.gather_top_k_dot(
-            model.user_factors, idx, model.item_factors, num_bucket,
-            mask=getattr(model, "item_phantom_mask", None),
-        )
+        with tracing.stage(tracing.PREDICT_ENQUEUE):
+            scores, items = similarity.gather_top_k_dot(
+                model.user_factors, idx, model.item_factors, num_bucket,
+                mask=getattr(model, "item_phantom_mask", None),
+            )
         return scores, items, user_idx, num
 
     def batch_predict_collect(
@@ -303,24 +306,28 @@ class ALSAlgorithm(Algorithm[RecTrainingData, ALSRecModel, dict, dict]):
             return []
         scores, items, user_idx, num = handle
         # one device_get for both arrays: one barrier, one transfer
-        scores, items = jax.device_get((scores, items))
-        out = []
-        for i, q in enumerate(queries):
-            if user_idx[i] < 0:
-                out.append({"itemScores": []})  # unknown user
-                continue
-            n = min(int(q.get("num", 10)), num)
-            out.append(
-                {
-                    "itemScores": [
-                        {
-                            "item": model.item_map.inverse(int(items[i, j])),
-                            "score": float(scores[i, j]),
-                        }
-                        for j in range(n)
-                    ]
-                }
-            )
+        with tracing.stage(tracing.PREDICT_DEVICE_GET):
+            scores, items = jax.device_get((scores, items))
+        with tracing.stage(tracing.PREDICT_MATERIALIZE):
+            out = []
+            for i, q in enumerate(queries):
+                if user_idx[i] < 0:
+                    out.append({"itemScores": []})  # unknown user
+                    continue
+                n = min(int(q.get("num", 10)), num)
+                out.append(
+                    {
+                        "itemScores": [
+                            {
+                                "item": model.item_map.inverse(
+                                    int(items[i, j])
+                                ),
+                                "score": float(scores[i, j]),
+                            }
+                            for j in range(n)
+                        ]
+                    }
+                )
         return out
 
 

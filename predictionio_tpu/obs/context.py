@@ -13,8 +13,8 @@ from __future__ import annotations
 import contextvars
 import json
 import logging
+import random
 import re
-import secrets
 import time
 
 _request_id: contextvars.ContextVar[str | None] = contextvars.ContextVar(
@@ -28,8 +28,18 @@ _request_id: contextvars.ContextVar[str | None] = contextvars.ContextVar(
 ID_OK = re.compile(r"^[A-Za-z0-9._:-]{1,128}$")
 
 
+def new_id() -> str:
+    """16 hex digits for a request or a span: a correlation ID, not a
+    secret. From the interpreter's own generator (seeded from the
+    kernel at start-up and again in a forked child) and not from
+    ``secrets``: that reads the kernel's generator with the interpreter
+    lock released, and on a loaded server a handler thread then waited
+    about a millisecond to get the lock back, per ID (PERF.md, PR 25)."""
+    return f"{random.getrandbits(64):016x}"
+
+
 def new_request_id() -> str:
-    return secrets.token_hex(8)
+    return new_id()
 
 
 def set_request_id(request_id: str | None) -> str:

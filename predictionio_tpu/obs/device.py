@@ -15,6 +15,12 @@ sites (the engine server's warm-up buckets, the trainer's step fn):
 sees a *different* signature — the "shape churn is recompiling the
 model" smell.
 
+``CompileWatch`` counts what XLA itself compiled, wherever the call
+came from: ``pio_xla_compiles_total{cache}`` and
+``pio_xla_compile_seconds{cache}`` (``miss`` = the backend compiled,
+``hit`` = the persistent cache had it), from one process-wide
+``jax.monitoring`` listener that lives while any watch is open.
+
 The module is import-safe without jax (``obs/`` stays stdlib-only at
 import time): jax is imported lazily inside the sampler, and backends
 without memory stats (CPU CI) degrade to a clean no-op — the thread
@@ -27,9 +33,15 @@ import os
 import threading
 from typing import Callable
 
-from predictionio_tpu.obs.registry import MetricRegistry
+from predictionio_tpu.obs.registry import TRAIN_STEP_BUCKETS, MetricRegistry
 
 _MIN_SAMPLE_S = 0.05
+
+#: JAX 0.9.0 times every ``compile_or_get_cached`` under the first
+#: event, persistent-cache hits included, and reports a hit by the
+#: second just before (jax/_src/compiler.py, interpreters/pxla.py)
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
 def _env_float(name: str, default: float) -> float:
@@ -209,3 +221,93 @@ class CompileTracker:
         if retrace:
             self._retraces.labels(site).inc()
         return True
+
+
+class _CompileSink:
+    """The two families' children in one registry, and how many open
+    watches feed it (two servers on one registry count once)."""
+
+    __slots__ = ("watches", "compiles", "seconds")
+
+    def __init__(self, registry: MetricRegistry) -> None:
+        self.watches = 0
+        compiles = registry.counter(
+            "pio_xla_compiles_total",
+            "Programs XLA compiled (cache=miss) or loaded from the "
+            "persistent compilation cache (cache=hit), process-wide",
+            ("cache",),
+        )
+        seconds = registry.histogram(
+            "pio_xla_compile_seconds",
+            "Time of one backend compilation or persistent-cache load",
+            ("cache",),
+            buckets=TRAIN_STEP_BUCKETS,
+        )
+        self.compiles = {c: compiles.labels(c) for c in ("hit", "miss")}
+        self.seconds = {c: seconds.labels(c) for c in ("hit", "miss")}
+
+
+_watch_lock = threading.Lock()
+#: the sink of every registry that an open watch feeds
+_watched: dict[MetricRegistry, _CompileSink] = {}
+_compile_thread = threading.local()
+
+
+def _on_compile_event(event: str, duration_secs: float, **_kw) -> None:
+    if event == _CACHE_RETRIEVAL_EVENT:
+        # the compile event of this thread that follows was a hit
+        _compile_thread.hit = True
+        return
+    if event != _BACKEND_COMPILE_EVENT:
+        return
+    cache = "hit" if getattr(_compile_thread, "hit", False) else "miss"
+    _compile_thread.hit = False
+    with _watch_lock:
+        sinks = list(_watched.values())
+    for sink in sinks:
+        sink.compiles[cache].inc()
+        sink.seconds[cache].observe(duration_secs)
+
+
+class CompileWatch:
+    """Counts XLA's compilations into ``registry`` until closed (or its
+    ``with`` block ends): one owner's claim on the process-wide compile
+    listener; closing the last one takes the listener off
+    ``jax.monitoring`` again."""
+
+    def __init__(self, registry: MetricRegistry) -> None:
+        import jax.monitoring
+
+        self._registry: MetricRegistry | None = registry
+        with _watch_lock:
+            if not _watched:
+                jax.monitoring.register_event_duration_secs_listener(
+                    _on_compile_event
+                )
+            sink = _watched.get(registry)
+            if sink is None:
+                sink = _watched[registry] = _CompileSink(registry)
+            sink.watches += 1
+
+    def close(self) -> None:
+        import jax.monitoring
+
+        with _watch_lock:
+            registry, self._registry = self._registry, None
+            if registry is None:
+                return  # closed before
+            sink = _watched[registry]
+            sink.watches -= 1
+            if sink.watches == 0:
+                del _watched[registry]
+            if not _watched:
+                jax.monitoring.unregister_event_duration_listener(
+                    _on_compile_event
+                )
+
+    def __enter__(self) -> "CompileWatch":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.close()
+        return False

@@ -30,6 +30,14 @@ LATENCY_BUCKETS = (
     0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
 )
 
+#: stage buckets (seconds): the stages of one request or batch
+#: (obs/tracing.stage) run from a few microseconds (a flag test, a
+#: histogram observe) to a whole predict time-out
+STAGE_BUCKETS = (
+    0.00001, 0.000025, 0.00005, 0.0001, 0.00025, 0.0005, 0.001,
+    0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 1.0, 10.0,
+)
+
 #: batch-size buckets: powers of two, matching the micro-batcher's
 #: compile buckets so occupancy reads directly as "which program ran"
 OCCUPANCY_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256)
@@ -468,6 +476,24 @@ def _count_open_fds() -> float:
     return float(len(os.listdir("/proc/self/fd")))
 
 
+def install_process_clocks(registry: MetricRegistry) -> None:
+    """The process's CPU time beside a clock of the same scrape, read
+    at scrape time: the ratio of their deltas over a window is how many
+    cores the process kept busy (1.0 = one core, the most one
+    interpreter lock lets Python bytecode use). Every server installs
+    them on the registry it is GIVEN, so an embedding caller that
+    passes its own registry sees them too."""
+    registry.gauge(
+        "pio_process_cpu_seconds_total",
+        "User + system CPU time of this process (time.process_time)",
+    ).set_function(time.process_time)
+    registry.gauge(
+        "pio_process_clock_seconds_total",
+        "Monotonic clock read in the same scrape as "
+        "pio_process_cpu_seconds_total (time.monotonic)",
+    ).set_function(time.monotonic)
+
+
 def _install_process_metrics(registry: MetricRegistry) -> None:
     """Deploy-correlation gauges on the default registry:
     ``pio_build_info{version=...} 1`` identifies which build answered a
@@ -498,6 +524,7 @@ def _install_process_metrics(registry: MetricRegistry) -> None:
             "pio_process_open_fds",
             "Open file descriptors of this process (/proc/self/fd)",
         ).set_function(_count_open_fds)
+    install_process_clocks(registry)
 
 
 _default_registry = MetricRegistry()

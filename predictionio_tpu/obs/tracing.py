@@ -30,6 +30,15 @@ keyed internally by root span ID, not trace ID — two servers in one
 process handling the same distributed trace record two linked trees
 instead of corrupting each other.
 
+Stages: :func:`stage` times the fixed boundaries a request crosses
+between the two sockets (read, admit, decode, submit, await, serve,
+respond on the handler thread; window, backpressure, prep, enqueue on
+the batcher's; device_get, materialize, settle on the completer's)
+into ``pio_stage_seconds{stage}`` and, through a profiler annotation
+of the same name, onto the clock of any ``jax.profiler`` trace, so a
+device trace shows what the host was doing in every gap
+(docs/observability.md "Stages").
+
 Export: ``Tracer.chrome_trace()`` renders Chrome trace-event JSON that
 loads directly in Perfetto (https://ui.perfetto.dev) — served at
 ``GET /debug/traces`` by every server, pulled by ``pio-tpu trace``.
@@ -41,12 +50,16 @@ import contextvars
 import heapq
 import logging
 import os
-import secrets
 import threading
 import time
 from collections import OrderedDict, deque
 
-from predictionio_tpu.obs.context import ID_OK
+from predictionio_tpu.obs.context import ID_OK, new_id
+from predictionio_tpu.obs.registry import (
+    STAGE_BUCKETS,
+    MetricRegistry,
+    get_registry,
+)
 
 logger = logging.getLogger(__name__)
 
@@ -66,6 +79,14 @@ _current_span: contextvars.ContextVar["Span | None"] = contextvars.ContextVar(
     "pio_span", default=None
 )
 
+#: stages are a closed set of 16 names and run once a request each; the
+#: bound only keeps a span that lives for hours (``pio_train``) small
+_MAX_STAGES_PER_SPAN = 64
+
+#: key under which a finalized trace holds the spans whose stages have
+#: not been made child spans yet (never exported: ``_snapshot`` pops it)
+_STAGED = "stagedSpans"
+
 
 def now() -> float:
     """Epoch seconds on the perf_counter clock (monotonic-consistent)."""
@@ -73,7 +94,7 @@ def now() -> float:
 
 
 def new_span_id() -> str:
-    return secrets.token_hex(8)
+    return new_id()
 
 
 def _json_safe(value, depth: int = 3):
@@ -148,6 +169,7 @@ class Span:
         "duration",
         "attributes",
         "root",
+        "stages",
         "_token",
     )
 
@@ -171,10 +193,22 @@ class Span:
         self.duration = 0.0
         self.attributes = dict(attributes) if attributes else {}
         self.root = root
+        #: ``(name, perf_counter at start, seconds, error or None)`` of
+        #: the stages that ran under this span; they become child spans
+        #: when the trace is finalized (:func:`stage`)
+        self.stages: list | None = None
         self._token = None
 
     def set(self, key: str, value) -> None:
         self.attributes[key] = value
+
+    def add_stage(
+        self, name: str, t0: float, seconds: float, error: str | None
+    ) -> None:
+        if self.stages is None:
+            self.stages = []
+        if len(self.stages) < _MAX_STAGES_PER_SPAN:
+            self.stages.append((name, t0, seconds, error))
 
     def __enter__(self) -> "Span":
         self.start = now()
@@ -208,6 +242,21 @@ class Span:
             "durationMs": round(self.duration * 1000, 3),
             "attributes": _json_safe(self.attributes),
         }
+
+    def stage_dicts(self) -> list[dict]:
+        """This span's stages as child spans, in :meth:`to_dict`'s form."""
+        return [
+            {
+                "traceId": self.trace_id,
+                "spanId": new_span_id(),
+                "parentId": self.span_id,
+                "name": name,
+                "start": round(_EPOCH + t0, 6),
+                "durationMs": round(seconds * 1000, 3),
+                "attributes": {"error": error} if error else {},
+            }
+            for name, t0, seconds, error in self.stages or ()
+        ]
 
 
 class _TraceBuf:
@@ -336,6 +385,12 @@ class Tracer:
                 "droppedSpans": buf.dropped,
                 "spans": [s.to_dict() for s in buf.spans],
             }
+            staged = [s for s in buf.spans if s.stages]
+            if staged:
+                # stages become child spans when the trace is READ
+                # (_snapshot): every request pays for noting them, only
+                # an export for building them
+                trace[_STAGED] = staged
             self._ring.append(trace)
             self._seq += 1
             item = (root.duration, self._seq, trace)
@@ -361,6 +416,9 @@ class Tracer:
                     self._flight, key=lambda it: -it[0]
                 )
             ]
+            for trace in (*ring, *flight):
+                for span in trace.pop(_STAGED, ()):
+                    trace["spans"].extend(span.stage_dicts())
         return ring, flight
 
     def traces(self) -> list[dict]:
@@ -474,6 +532,152 @@ def span(name: str, **attributes):
     if parent is None:
         return NOOP
     return parent.tracer.child(parent, name, attributes or None)
+
+
+# -- stages -------------------------------------------------------------------
+
+HTTP_READ = "http.read"
+HTTP_ADMIT = "http.admit"
+ENGINE_DECODE = "engine.decode"
+ENGINE_SUBMIT = "engine.submit"
+ENGINE_AWAIT = "engine.await"
+ENGINE_SERVE = "engine.serve"
+HTTP_RESPOND = "http.respond"
+HTTP_ENCODE = "http.encode"
+HTTP_WRITE = "http.write"
+BATCH_WINDOW = "batch.window"
+BATCH_BACKPRESSURE = "batch.backpressure"
+PREDICT_PREP = "predict.prep"
+PREDICT_ENQUEUE = "predict.enqueue"
+PREDICT_DEVICE_GET = "predict.device_get"
+PREDICT_MATERIALIZE = "predict.materialize"
+BATCH_SETTLE = "batch.settle"
+
+#: the closed set: one child of ``pio_stage_seconds`` each, resolved
+#: when a sink is built, never on the hot path
+STAGES = (
+    HTTP_READ, HTTP_ADMIT, ENGINE_DECODE, ENGINE_SUBMIT, ENGINE_AWAIT,
+    ENGINE_SERVE, HTTP_RESPOND, HTTP_ENCODE, HTTP_WRITE, BATCH_WINDOW,
+    BATCH_BACKPRESSURE, PREDICT_PREP, PREDICT_ENQUEUE,
+    PREDICT_DEVICE_GET, PREDICT_MATERIALIZE, BATCH_SETTLE,
+)
+
+#: ``factory(name, **keywords)`` -> context manager that writes a host
+#: event into a running profiler's trace, and ``active()`` -> whether a
+#: profiler runs (a flag test; a stage builds no annotation while none
+#: does). Installed once by the code that imports JAX (utils/profiling:
+#: ``jax.profiler.TraceAnnotation`` and its ``is_enabled``); without
+#: them ``obs/`` stays free of JAX and a stage is histogram-only.
+_annotation_factory = None
+
+
+def _no_profiler() -> bool:
+    return False
+
+
+_annotation_active = _no_profiler
+
+
+def set_annotation_factory(factory, active) -> None:
+    global _annotation_factory, _annotation_active
+    _annotation_factory = factory
+    _annotation_active = active
+
+
+#: (children of pio_stage_seconds by stage, annotation keywords) bound
+#: to this thread of a server: the handler binds its request ID, the
+#: batcher and the completer their batch's number and size
+_bound_stages: contextvars.ContextVar[tuple[dict, dict] | None] = (
+    contextvars.ContextVar("pio_stages", default=None)
+)
+
+
+class StageSink:
+    """``pio_stage_seconds{stage}`` of one registry (``None``: the
+    process's), every child resolved here. A server builds one at
+    wiring time and each of its threads binds it (with the keywords its
+    annotations carry) before the stages it runs."""
+
+    __slots__ = ("_children",)
+
+    def __init__(self, registry: MetricRegistry | None):
+        if registry is None:
+            registry = get_registry()
+        family = registry.histogram(
+            "pio_stage_seconds",
+            "Time in one stage of a request, post or batch between "
+            "the two sockets (docs/observability.md \"Stages\")",
+            ("stage",),
+            buckets=STAGE_BUCKETS,
+        )
+        self._children = {name: family.labels(name) for name in STAGES}
+
+    def bind(self, **keywords) -> None:
+        """Stages on this context observe here from now on, and their
+        annotations carry ``keywords`` (request_id=, or batch= and n=)."""
+        _bound_stages.set((self._children, keywords))
+
+
+#: where a stage observes on a context no server has bound (a model's
+#: predict called from an evaluation, a batcher built with no registry)
+_default_sink = StageSink(None)
+
+
+class _Stage:
+    __slots__ = ("_name", "_child", "_keywords", "_annotation", "_t0")
+
+    def __init__(self, name: str, child, keywords: dict):
+        self._name = name
+        self._child = child
+        self._keywords = keywords
+        self._annotation = None
+
+    def __enter__(self) -> "_Stage":
+        if _annotation_active():
+            self._annotation = _annotation_factory(
+                self._name, **self._keywords
+            )
+            self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        t0 = self._t0
+        seconds = time.perf_counter() - t0
+        self._child.observe(seconds)
+        parent = _current_span.get()
+        if parent is not None and parent.tracer.enabled:
+            # noted on the open span, made a child span when its trace
+            # is finalized: the stage never becomes the context's
+            # current span, so what it encloses (a batcher submit, a
+            # store call) keeps hanging off the request's own span
+            parent.add_stage(
+                self._name, t0, seconds,
+                f"{exc_type.__name__}: {exc}" if exc_type else None,
+            )
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        return False
+
+
+def stage(name: str) -> _Stage:
+    """Context manager around one stage (a name of :data:`STAGES`) of a
+    request, a post or a batch — never of one query inside a post or
+    of one item. Always observes ``pio_stage_seconds{stage}``; while a
+    profiler runs (whoever started it) it is an annotation of the same
+    name, so any profiler session sees the stage on the device trace's
+    clock; and under an open span of an enabled tracer it is also that
+    span's child in the finished trace."""
+    children, keywords = _bound_stages.get() or (
+        _default_sink._children, {}
+    )
+    child = children.get(name)
+    if child is None:
+        raise ValueError(
+            f"unknown stage {name!r}: stages are a closed set "
+            "(obs.tracing.STAGES)"
+        )
+    return _Stage(name, child, keywords)
 
 
 #: process-global tracer (every server defaults to it, like the default

@@ -555,23 +555,30 @@ def _slab_stats(y, idx, weights, valid, implicit, alpha, dtype,
     if compute is not None:
         aw = aw.astype(compute)
         bw = bw.astype(compute)
+    # the scopes name the device operations in a profiler trace
+    # (docs/observability.md "Named device work")
     if gather_layout == "kmajor":
-        ygT = jnp.take(y.T, idx, axis=1)  # [k, R, W] — unpadded minor W
-        a = jnp.einsum(
-            "krl,rl,mrl->rkm", ygT, aw, ygT,
-            preferred_element_type=dtype,
-        )
-        b = jnp.einsum(
-            "krl,rl->rk", ygT, bw, preferred_element_type=dtype
-        )
+        with jax.named_scope("gather"):
+            ygT = jnp.take(y.T, idx, axis=1)  # [k, R, W] — unpadded minor W
+        with jax.named_scope("gramian"):
+            a = jnp.einsum(
+                "krl,rl,mrl->rkm", ygT, aw, ygT,
+                preferred_element_type=dtype,
+            )
+            b = jnp.einsum(
+                "krl,rl->rk", ygT, bw, preferred_element_type=dtype
+            )
     else:
-        yg = y[idx]  # [R, W, k] gather (unique rows per device slice)
-        a = jnp.einsum(
-            "rlk,rl,rlm->rkm", yg, aw, yg, preferred_element_type=dtype
-        )
-        b = jnp.einsum(
-            "rlk,rl->rk", yg, bw, preferred_element_type=dtype
-        )
+        with jax.named_scope("gather"):
+            yg = y[idx]  # [R, W, k] gather (unique rows per device slice)
+        with jax.named_scope("gramian"):
+            a = jnp.einsum(
+                "rlk,rl,rlm->rkm", yg, aw, yg,
+                preferred_element_type=dtype,
+            )
+            b = jnp.einsum(
+                "rlk,rl->rk", yg, bw, preferred_element_type=dtype
+            )
     cnt = mask.sum(axis=1)
     return a, b, cnt
 
@@ -622,6 +629,7 @@ def _chol_solve_batched(a, b):
     return jnp.stack(xs, axis=-1)
 
 
+@jax.named_scope("solve")
 def _solve(a, b, cnt, yty, lam, implicit, k, dtype):
     if implicit:
         a = a + yty[None] + lam * jnp.eye(k, dtype=dtype)[None]
@@ -676,11 +684,12 @@ def _assemble_and_solve(
         # dtype must not be inferred from it.
         dtype = jnp.float32
         y = y.astype(compute)
-    yty = (
-        jnp.einsum("ik,im->km", y, y, preferred_element_type=dtype)
-        if implicit
-        else None
-    )
+    with jax.named_scope("gramian"):
+        yty = (
+            jnp.einsum("ik,im->km", y, y, preferred_element_type=dtype)
+            if implicit
+            else None
+        )
     n_regular = 0
     parts_x = []
     for (idx, weights, valid) in slab_arrays:
@@ -1014,6 +1023,17 @@ def stage_sharded(
     )
 
 
+@jax.named_scope("all_gather")
+def _all_gather_factors(loc, compute):
+    """The opposite side's factor rows from every model shard, cast to
+    the compute dtype BEFORE the collective (half the bytes on the
+    wire)."""
+    return lax.all_gather(
+        loc.astype(compute) if compute is not None else loc,
+        MODEL_AXIS, axis=0, tiled=True,
+    )
+
+
 def _sharded_half(
     y_full, side_slabs, side_heavy, inv_local, n_heavy_local,
     implicit, alpha, lam, compute=None, gather_layout="kminor",
@@ -1033,8 +1053,9 @@ def _sharded_half(
     )
     # device-major reassembly: model (minor) then data (major) matches
     # the P((data, model)) row split of the slabs
-    xs = lax.all_gather(x_stats, MODEL_AXIS, axis=0, tiled=True)
-    xs = lax.all_gather(xs, DATA_AXIS, axis=0, tiled=True)
+    with jax.named_scope("all_gather"):
+        xs = lax.all_gather(x_stats, MODEL_AXIS, axis=0, tiled=True)
+        xs = lax.all_gather(xs, DATA_AXIS, axis=0, tiled=True)
     return jnp.take(xs, inv_local, axis=0)
 
 
@@ -1101,18 +1122,12 @@ def make_sharded_train_step(
                  i_slabs, i_heavy, i_inv, lam_):
             def it(_, carry):
                 xl, yl = carry
-                y_full = lax.all_gather(
-                    yl.astype(compute) if compute is not None else yl,
-                    MODEL_AXIS, axis=0, tiled=True,
-                )
+                y_full = _all_gather_factors(yl, compute)
                 xl = _sharded_half(
                     y_full, u_slabs, u_heavy, u_inv, u_nh,
                     implicit, alpha, lam_, compute, gather_layout,
                 )
-                x_full = lax.all_gather(
-                    xl.astype(compute) if compute is not None else xl,
-                    MODEL_AXIS, axis=0, tiled=True,
-                )
+                x_full = _all_gather_factors(xl, compute)
                 yl = _sharded_half(
                     x_full, i_slabs, i_heavy, i_inv, i_nh,
                     implicit, alpha, lam_, compute, gather_layout,
@@ -1171,10 +1186,7 @@ def make_sharded_half_step(
     def _solve(y, slabs_a, heavy_a, inv_a, lam):
         y = lax.with_sharding_constraint(y, factor_sharding)
         def body(y_loc, slabs, heavy, inv, lam_):
-            y_full = lax.all_gather(
-                y_loc.astype(compute) if compute is not None else y_loc,
-                MODEL_AXIS, axis=0, tiled=True,
-            )
+            y_full = _all_gather_factors(y_loc, compute)
             return _sharded_half(
                 y_full, slabs, heavy, inv, nh, implicit, alpha, lam_,
                 compute, gather_layout,

@@ -210,47 +210,49 @@ def fused_top_k_dot(
             scale is not None,
         )
 
-        best_s, best_i = pl.pallas_call(
-            kernel,
-            grid=(n_blocks,),
-            in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((b, num), lambda j: (0, 0)),
-                pl.BlockSpec((b, num), lambda j: (0, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((b, num), jnp.float32),
-                jax.ShapeDtypeStruct((b, num), jnp.int32),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((b, num), jnp.float32),
-                pltpu.VMEM((b, num), jnp.int32),
-            ],
-            interpret=interpret,
-        )(*operands)
+        with jax.named_scope("fused_top_k"):
+            best_s, best_i = pl.pallas_call(
+                kernel,
+                grid=(n_blocks,),
+                in_specs=in_specs,
+                out_specs=[
+                    pl.BlockSpec((b, num), lambda j: (0, 0)),
+                    pl.BlockSpec((b, num), lambda j: (0, 0)),
+                ],
+                out_shape=[
+                    jax.ShapeDtypeStruct((b, num), jnp.float32),
+                    jax.ShapeDtypeStruct((b, num), jnp.int32),
+                ],
+                scratch_shapes=[
+                    pltpu.VMEM((b, num), jnp.float32),
+                    pltpu.VMEM((b, num), jnp.int32),
+                ],
+                interpret=interpret,
+            )(*operands)
     else:
         best_s = jnp.full((b, num), _NEG, jnp.float32)
         best_i = jnp.zeros((b, num), jnp.int32)
 
     if head < n_items:
-        tail_items = items[head:]
-        if tail_items.dtype != jnp.float32:
-            tail_items = tail_items.astype(jnp.float32)
-        ts = queries @ tail_items.T
-        if scale is not None:
-            ts = ts * scale[None, head:].astype(jnp.float32)
-        tail_s = jnp.where(jnp.isnan(ts), _NEG, ts).astype(jnp.float32)
-        if mask is not None:
-            tail_s = jnp.where(mask[:, head:], _NEG, tail_s)
-        tail_i = head + jax.lax.broadcasted_iota(
-            jnp.int32, (b, n_items - head), dimension=1
-        )
-        # best entries precede tail candidates, so lax.top_k's
-        # first-occurrence tie rule keeps lower item indices first
-        cat_s = jnp.concatenate([best_s, tail_s], axis=1)
-        cat_i = jnp.concatenate([best_i, tail_i], axis=1)
-        best_s, pos = jax.lax.top_k(cat_s, num)
-        best_i = jnp.take_along_axis(cat_i, pos, axis=1)
+        with jax.named_scope("tail_top_k"):
+            tail_items = items[head:]
+            if tail_items.dtype != jnp.float32:
+                tail_items = tail_items.astype(jnp.float32)
+            ts = queries @ tail_items.T
+            if scale is not None:
+                ts = ts * scale[None, head:].astype(jnp.float32)
+            tail_s = jnp.where(jnp.isnan(ts), _NEG, ts).astype(jnp.float32)
+            if mask is not None:
+                tail_s = jnp.where(mask[:, head:], _NEG, tail_s)
+            tail_i = head + jax.lax.broadcasted_iota(
+                jnp.int32, (b, n_items - head), dimension=1
+            )
+            # best entries precede tail candidates, so lax.top_k's
+            # first-occurrence tie rule keeps lower item indices first
+            cat_s = jnp.concatenate([best_s, tail_s], axis=1)
+            cat_i = jnp.concatenate([best_i, tail_i], axis=1)
+            best_s, pos = jax.lax.top_k(cat_s, num)
+            best_i = jnp.take_along_axis(cat_i, pos, axis=1)
     return best_s, best_i
 
 

@@ -126,13 +126,15 @@ def stage_quantized(qf: QuantizedFactors) -> QuantizedFactors:
 
 @partial(jax.jit, static_argnames=("num",))
 def _top_k_dot_quant_xla(queries, data, scale, num, mask=None):
-    scores = queries @ data.astype(jnp.float32).T  # dequant fuses in
-    if scale is not None:
-        scores = scores * scale[None, :]
-    scores = jnp.where(jnp.isnan(scores), -jnp.inf, scores)
-    if mask is not None:
-        scores = jnp.where(mask, -jnp.inf, scores)
-    return jax.lax.top_k(scores, num)
+    with jax.named_scope("score_dequant"):
+        scores = queries @ data.astype(jnp.float32).T  # dequant fuses in
+        if scale is not None:
+            scores = scores * scale[None, :]
+        scores = jnp.where(jnp.isnan(scores), -jnp.inf, scores)
+        if mask is not None:
+            scores = jnp.where(mask, -jnp.inf, scores)
+    with jax.named_scope("top_k"):
+        return jax.lax.top_k(scores, num)
 
 
 def top_k_dot_quantized(
@@ -164,10 +166,11 @@ def top_k_dot_quantized(
 
 @jax.jit
 def _gather_rows_quant(data, scale, idx):
-    rows = jnp.take(data, idx, axis=0).astype(jnp.float32)
-    if scale is not None:
-        rows = rows * jnp.take(scale, idx)[:, None]
-    return rows
+    with jax.named_scope("gather_dequant"):
+        rows = jnp.take(data, idx, axis=0).astype(jnp.float32)
+        if scale is not None:
+            rows = rows * jnp.take(scale, idx)[:, None]
+        return rows
 
 
 def gather_rows(qf: "QuantizedFactors | jax.Array", idx) -> jax.Array:

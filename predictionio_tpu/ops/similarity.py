@@ -36,15 +36,20 @@ def _top_k_dot_xla(
     num: int,
     mask: jax.Array | None = None,  # [B, I] or [I] — True = exclude
 ) -> tuple[jax.Array, jax.Array]:
-    scores = queries @ items.T  # [B, I] — MXU
-    # NaN scores (corrupted factors) map to -inf, matching the Pallas
-    # kernel's masking — both top_k_dot paths must rank identically
-    scores = jnp.where(jnp.isnan(scores), -jnp.inf, scores)
-    if mask is not None:
-        # [I] masks (per-item, e.g. phantom padding rows of a sharded
-        # catalog) broadcast over the batch dim
-        scores = jnp.where(mask, -jnp.inf, scores)
-    return jax.lax.top_k(scores, num)
+    # the scopes name the device operations in a profiler trace
+    # (docs/observability.md "Named device work")
+    with jax.named_scope("score"):
+        scores = queries @ items.T  # [B, I] — MXU
+        # NaN scores (corrupted factors) map to -inf, matching the
+        # Pallas kernel's masking — both top_k_dot paths must rank
+        # identically
+        scores = jnp.where(jnp.isnan(scores), -jnp.inf, scores)
+        if mask is not None:
+            # [I] masks (per-item, e.g. phantom padding rows of a
+            # sharded catalog) broadcast over the batch dim
+            scores = jnp.where(mask, -jnp.inf, scores)
+    with jax.named_scope("top_k"):
+        return jax.lax.top_k(scores, num)
 
 
 def _pallas_mask(mask, batch: int):
@@ -165,7 +170,8 @@ def _gather_top_k_dot_xla(
     num: int,
     mask: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    vecs = jnp.take(factors, idx, axis=0)
+    with jax.named_scope("gather"):
+        vecs = jnp.take(factors, idx, axis=0)
     return _top_k_dot_xla(vecs, items, num, mask)
 
 
@@ -191,7 +197,8 @@ def gather_top_k_dot(
     if _use_pallas(idx.shape[0], items.shape[0]):
         from predictionio_tpu.ops.pallas_topk import fused_top_k_dot
 
-        vecs = jnp.take(factors, idx, axis=0)
+        with jax.named_scope("gather"):
+            vecs = jnp.take(factors, idx, axis=0)
         return fused_top_k_dot(
             vecs, items, num, _pallas_mask(mask, idx.shape[0]),
             interpret=jax.default_backend() != "tpu",
@@ -206,12 +213,13 @@ def _gather_mean_top_k_cosine_xla(
     num: int,
     mask: jax.Array | None = None,  # [I] True = exclude (phantom rows)
 ) -> tuple[jax.Array, jax.Array]:
-    valid = idx >= 0
-    rows = jnp.take(items_f, jnp.clip(idx, 0, None), axis=0)
-    w = valid.astype(items_f.dtype)[:, None]
-    q = (rows * w).sum(axis=0, keepdims=True) / jnp.maximum(
-        w.sum(), 1.0
-    )
+    with jax.named_scope("gather"):
+        valid = idx >= 0
+        rows = jnp.take(items_f, jnp.clip(idx, 0, None), axis=0)
+        w = valid.astype(items_f.dtype)[:, None]
+        q = (rows * w).sum(axis=0, keepdims=True) / jnp.maximum(
+            w.sum(), 1.0
+        )
     return _top_k_dot_xla(
         l2_normalize(q), l2_normalize(items_f), num, mask
     )

@@ -54,6 +54,7 @@ exactly which requests rode in it.
 
 from __future__ import annotations
 
+import itertools
 import logging
 import math
 import os
@@ -71,6 +72,11 @@ from predictionio_tpu.obs.registry import LATENCY_BUCKETS, OCCUPANCY_BUCKETS
 from predictionio_tpu.serving import admission, resilience
 
 logger = logging.getLogger(__name__)
+
+#: batch numbers are process-wide, so that the ``batch=`` keyword joins
+#: a batcher's and its completer's stage annotations in a profiler
+#: trace of a pool of batchers
+_BATCH_SEQ = itertools.count(1)
 
 
 class BatcherOverloaded(Exception):
@@ -133,6 +139,7 @@ class _Inflight(NamedTuple):
     t0: float  # perf_counter at dispatch entry
     enqueue_s: float
     traced: bool
+    seq: int  # the batch's number, for the completer's stage keywords
 
 
 class _NullMetrics:
@@ -310,9 +317,9 @@ class _BatcherMetrics:
         ).labels(name)
         self._sync = registry.histogram(
             "pio_device_sync_seconds",
-            "Device barrier + host result materialization of one "
-            "batch (two-phase collect(), or the whole single-phase "
-            "batch_fn)",
+            "Device barrier, transfer to the host and result "
+            "materialization of one batch (two-phase collect(), or the "
+            "whole single-phase batch_fn)",
             ("batcher",),
             buckets=LATENCY_BUCKETS,
         ).labels(name)
@@ -491,6 +498,9 @@ class MicroBatcher:
             if registry is not None
             else _NullMetrics()
         )
+        #: where both worker threads time their stages (and the model's
+        #: predict.* stages that run on them)
+        self._stages = tracing.StageSink(registry)
         #: wait queue + its condition: submit appends and notifies, the
         #: collector selects under the same lock. One lock, never held
         #: across dispatch or any blocking wait (Condition.wait excepted)
@@ -710,22 +720,29 @@ class MicroBatcher:
                     self._cv.wait()
                 if not self._buf:
                     break  # closed and fully drained
-                if not self._closed.is_set():
-                    # coalesce: wait out the window from the FIRST
-                    # queued item unless the batch fills (or close
-                    # lands — a drain dispatches immediately)
-                    window_end = time.monotonic() + self._current_wait
-                    while (
-                        len(self._buf) < self._max_batch
-                        and not self._closed.is_set()
-                    ):
-                        remaining = window_end - time.monotonic()
-                        if remaining <= 0:
-                            break
-                        self._cv.wait(remaining)
-                batch = self._select_batch()
+                seq = next(_BATCH_SEQ)
+                # the window's n is what was queued when it opened
+                self._stages.bind(batch=seq, n=len(self._buf))
+                with tracing.stage(tracing.BATCH_WINDOW):
+                    if not self._closed.is_set():
+                        # coalesce: wait out the window from the FIRST
+                        # queued item unless the batch fills (or close
+                        # lands — a drain dispatches immediately)
+                        window_end = (
+                            time.monotonic() + self._current_wait
+                        )
+                        while (
+                            len(self._buf) < self._max_batch
+                            and not self._closed.is_set()
+                        ):
+                            remaining = window_end - time.monotonic()
+                            if remaining <= 0:
+                                break
+                            self._cv.wait(remaining)
+                    batch = self._select_batch()
+            self._stages.bind(batch=seq, n=len(batch))
             full = len(batch) >= self._max_batch
-            self._dispatch_batch(batch)
+            self._dispatch_batch(batch, seq)
             if self._adaptive:
                 # hot: a full batch means backlog is doing the
                 # coalescing — halve the window toward 0 so queue wait
@@ -741,14 +758,15 @@ class MicroBatcher:
         if self._completer is not None:
             self._pending.put(None)  # completer drains in order, then exits
 
-    def _dispatch_batch(self, batch) -> None:
+    def _dispatch_batch(self, batch, seq: int) -> None:
         # backpressure BEFORE the cancellation/deadline cutoff: while
         # the collector waits for a pipeline slot (device slow, depth
         # exhausted) waiters can still cancel and budgets can still
         # expire — the cutoff below must be the last word before the
         # device sees the work
         if self._completer is not None:
-            self._inflight.acquire()
+            with tracing.stage(tracing.BATCH_BACKPRESSURE):
+                self._inflight.acquire()
         # transition every slot to running; cancelled slots drop out
         # HERE, before the device sees them — cancellation is how an
         # abandoning caller turns wasted dispatch into avoided dispatch.
@@ -816,7 +834,7 @@ class MicroBatcher:
         self._pending.put(
             _Inflight(
                 live, handle, start_wall, start_mono, t0, enqueue_s,
-                traced,
+                traced, seq,
             )
         )
 
@@ -826,6 +844,7 @@ class MicroBatcher:
             rec = self._pending.get()
             if rec is None:
                 return
+            self._stages.bind(batch=rec.seq, n=len(rec.live))
             try:
                 t1 = time.perf_counter()
                 sync_s = 0.0
@@ -932,48 +951,50 @@ class MicroBatcher:
         self, live, results, elapsed: float, start_wall: float,
         start_mono: float, traced: bool, enqueue_s: float, sync_s: float,
     ) -> None:
-        self._observe_batch_time(elapsed)
-        self._metrics.dispatched(len(live), elapsed)
-        self._attribute(live, start_mono, enqueue_s, sync_s, "ok")
-        if traced:
-            self._record_dispatch_spans(
-                live, start_wall, start_mono, elapsed,
-                enqueue_s=enqueue_s, sync_s=sync_s,
+        with tracing.stage(tracing.BATCH_SETTLE):
+            self._observe_batch_time(elapsed)
+            self._metrics.dispatched(len(live), elapsed)
+            self._attribute(live, start_mono, enqueue_s, sync_s, "ok")
+            if traced:
+                self._record_dispatch_spans(
+                    live, start_wall, start_mono, elapsed,
+                    enqueue_s=enqueue_s, sync_s=sync_s,
+                )
+            log_json(
+                logger, logging.DEBUG, "batch_dispatch",
+                batcher=self.name, occupancy=len(live),
+                ms=round(elapsed * 1000, 3),
+                enqueueMs=round(enqueue_s * 1000, 3),
+                requestIds=[s.request_id for s in live if s.request_id],
             )
-        log_json(
-            logger, logging.DEBUG, "batch_dispatch",
-            batcher=self.name, occupancy=len(live),
-            ms=round(elapsed * 1000, 3),
-            enqueueMs=round(enqueue_s * 1000, 3),
-            requestIds=[s.request_id for s in live if s.request_id],
-        )
-        for slot, result in zip(live, results):
-            slot.future.set_result(result)
+            for slot, result in zip(live, results):
+                slot.future.set_result(result)
 
     def _settle_failure(
         self, live, exc: Exception, elapsed: float, start_wall: float,
         start_mono: float, traced: bool, enqueue_s: float, sync_s: float,
         phase: str,
     ) -> None:
-        self._observe_batch_time(elapsed)
-        self._metrics.dispatched(len(live), elapsed)
-        self._attribute(live, start_mono, enqueue_s, sync_s, "error")
-        if traced:
-            self._record_dispatch_spans(
-                live, start_wall, start_mono, elapsed,
-                enqueue_s=enqueue_s, sync_s=sync_s,
+        with tracing.stage(tracing.BATCH_SETTLE):
+            self._observe_batch_time(elapsed)
+            self._metrics.dispatched(len(live), elapsed)
+            self._attribute(live, start_mono, enqueue_s, sync_s, "error")
+            if traced:
+                self._record_dispatch_spans(
+                    live, start_wall, start_mono, elapsed,
+                    enqueue_s=enqueue_s, sync_s=sync_s,
+                    error=f"{type(exc).__name__}: {exc}",
+                )
+            log_json(
+                logger, logging.WARNING, "batch_dispatch_failed",
+                batcher=self.name, occupancy=len(live), phase=phase,
+                ms=round(elapsed * 1000, 3),
                 error=f"{type(exc).__name__}: {exc}",
+                requestIds=[s.request_id for s in live if s.request_id],
             )
-        log_json(
-            logger, logging.WARNING, "batch_dispatch_failed",
-            batcher=self.name, occupancy=len(live), phase=phase,
-            ms=round(elapsed * 1000, 3),
-            error=f"{type(exc).__name__}: {exc}",
-            requestIds=[s.request_id for s in live if s.request_id],
-        )
-        for slot in live:
-            if not slot.future.done():
-                slot.future.set_exception(exc)
+            for slot in live:
+                if not slot.future.done():
+                    slot.future.set_exception(exc)
 
     def _record_dispatch_spans(
         self, live, start_wall: float, start_mono: float,

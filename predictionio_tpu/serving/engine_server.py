@@ -57,7 +57,11 @@ from predictionio_tpu.data.storage import Storage, get_storage
 from predictionio_tpu.obs import MetricRegistry, get_registry
 from predictionio_tpu.obs import timeline as timeline_mod
 from predictionio_tpu.obs import tracing
-from predictionio_tpu.obs.device import CompileTracker, DeviceSampler
+from predictionio_tpu.obs.device import (
+    CompileTracker,
+    CompileWatch,
+    DeviceSampler,
+)
 from predictionio_tpu.parallel.mesh import ComputeContext
 from predictionio_tpu.serving import admission as admission_mod
 from predictionio_tpu.serving import canary as canary_mod
@@ -286,6 +290,9 @@ class EngineServer:
         # without memory stats degrade to a clean no-op.
         self._device_sampler = DeviceSampler(self._registry)
         self._compile_tracker = CompileTracker(self._registry)
+        # what XLA itself compiled, from here (the loads and warm-ups
+        # below included) until close()
+        self._compile_watch = CompileWatch(self._registry)
         #: one profile capture at a time (jax.profiler is process-
         #: global) — guarded by self._lock, never held across the
         #: capture window itself
@@ -1038,7 +1045,8 @@ class EngineServer:
 
     def _queries_inner(self, request: Request) -> Response:
         t0 = time.perf_counter()
-        query = request.json()
+        with tracing.stage(tracing.ENGINE_DECODE):
+            query = request.json()
         if not isinstance(query, dict):
             raise HTTPError(400, "query must be a JSON object")
         claim = None
@@ -1137,42 +1145,48 @@ class EngineServer:
             # the snapshot holds the tenant's pool pin (multi-tenant)
             # for the WHOLE submit→collect span, so eviction can't
             # close the generation under an in-flight query
-            with self._serving_snapshot(request) as (serving, batchers):
-                supplemented = serving.supplement(query)
-                futures = []
-                # single-flight leaders submit at the HIGHEST class
-                # coalesced so far: a CRITICAL waiter must not sit
-                # behind a SHEDDABLE leader's batcher slot
-                escalate = (
-                    admission_mod.criticality(claim.criticality())
-                    if claim is not None
-                    else contextlib.nullcontext()
-                )
-                try:
-                    with escalate:
-                        for b in batchers:
-                            futures.append(b.submit(supplemented))
-                except BatcherOverloaded:
-                    # queue-depth bound hit: shed immediately instead of
-                    # queueing into a predict-timeout hang. Earlier
-                    # algorithms' accepted submits must not run for
-                    # nothing.
-                    self._abandon(futures)
-                    raise HTTPError(
-                        503, "server overloaded; retry later",
-                        headers=self._shed_headers(),
+            with contextlib.ExitStack() as pinned:
+                # the stage covers taking the snapshot too: in a pool
+                # that is the tenant's pin, possibly its load
+                with tracing.stage(tracing.ENGINE_SUBMIT):
+                    serving, batchers = pinned.enter_context(
+                        self._serving_snapshot(request)
                     )
-                except resilience.DeadlineExceeded:
-                    self._abandon(futures)
-                    raise HTTPError(
-                        504, "deadline expired before dispatch"
+                    supplemented = serving.supplement(query)
+                    futures = []
+                    # single-flight leaders submit at the HIGHEST class
+                    # coalesced so far: a CRITICAL waiter must not sit
+                    # behind a SHEDDABLE leader's batcher slot
+                    escalate = (
+                        admission_mod.criticality(claim.criticality())
+                        if claim is not None
+                        else contextlib.nullcontext()
                     )
-                except RuntimeError:
-                    # /reload swapped+closed the batchers between our
-                    # snapshot and submit — retry once against the
-                    # fresh set (a re-pin in multi-tenant mode)
-                    self._abandon(futures)
-                    continue
+                    try:
+                        with escalate:
+                            for b in batchers:
+                                futures.append(b.submit(supplemented))
+                    except BatcherOverloaded:
+                        # queue-depth bound hit: shed immediately
+                        # instead of queueing into a predict-timeout
+                        # hang. Earlier algorithms' accepted submits
+                        # must not run for nothing.
+                        self._abandon(futures)
+                        raise HTTPError(
+                            503, "server overloaded; retry later",
+                            headers=self._shed_headers(),
+                        )
+                    except resilience.DeadlineExceeded:
+                        self._abandon(futures)
+                        raise HTTPError(
+                            504, "deadline expired before dispatch"
+                        )
+                    except RuntimeError:
+                        # /reload swapped+closed the batchers between
+                        # our snapshot and submit — retry once against
+                        # the fresh set (a re-pin in multi-tenant mode)
+                        self._abandon(futures)
+                        continue
                 try:
                     prediction = self._serve_one(
                         serving, query, supplemented, futures
@@ -1230,24 +1244,28 @@ class EngineServer:
                 return Response(200, prediction)
         raise HTTPError(503, "server is reloading; retry")
 
-    def _serve_one(self, serving, query, supplemented, futures,
-                   deadline: float | None = None):
-        """Collect one query's per-algorithm futures and run the shared
-        tail of the predict pipeline: serve → feedback → plugin
-        block/sniff (CreateServer.scala:603-606). Used by the single and
-        the batch routes so their semantics cannot diverge.
+    def _serve_one(self, serving, query, supplemented, futures):
+        """One query of the single route: wait for its per-algorithm
+        futures, then the shared tail of the predict pipeline."""
+        with tracing.stage(tracing.ENGINE_AWAIT):
+            predictions = self._await_predictions(
+                futures, time.monotonic() + self._predict_timeout_s
+            )
+        with tracing.stage(tracing.ENGINE_SERVE):
+            return self._serve_tail(
+                serving, query, supplemented, predictions
+            )
 
-        ``deadline`` (a ``time.monotonic()`` value) bounds the TOTAL
-        wait across all futures; default is one predict timeout from
-        now, further capped by the request's propagated X-PIO-Deadline
-        when one rode in."""
-        if deadline is None:
-            deadline = time.monotonic() + self._predict_timeout_s
+    def _await_predictions(self, futures, deadline: float) -> list:
+        """Block on one query's per-algorithm futures. ``deadline`` (a
+        ``time.monotonic()`` value) bounds the TOTAL wait across all of
+        them, further capped by the request's propagated
+        X-PIO-Deadline when one rode in."""
         request_deadline = resilience.get_deadline()
         if request_deadline is not None:
             deadline = min(deadline, request_deadline.expires_mono)
         try:
-            predictions = [
+            return [
                 f.result(timeout=max(0.001, deadline - time.monotonic()))
                 for f in futures
             ]
@@ -1260,6 +1278,11 @@ class EngineServer:
                     "deadline expired while queued for dispatch"
                 ) from None
             raise
+
+    def _serve_tail(self, serving, query, supplemented, predictions):
+        """serve → feedback → plugin block/sniff
+        (CreateServer.scala:603-606). Used by the single and the batch
+        routes so their semantics cannot diverge."""
         prediction = serving.serve(supplemented, predictions)
         if self._feedback:
             prediction = self._record_feedback(query, prediction)
@@ -1286,7 +1309,8 @@ class EngineServer:
         result is collected, so a batch fills device dispatches instead
         of serializing one query per dispatch."""
         t0 = time.perf_counter()
-        payload = request.json()
+        with tracing.stage(tracing.ENGINE_DECODE):
+            payload = request.json()
         if not isinstance(payload, list):
             raise HTTPError(400, "batch must be a JSON array of queries")
         if len(payload) > self.MAX_QUERY_BATCH:
@@ -1300,10 +1324,14 @@ class EngineServer:
         for _attempt in range(2):
             # pin (multi-tenant) spans submit AND collection, same as
             # the single-query route
-            with self._serving_snapshot(request) as (serving, batchers):
-                entries, any_submitted = self._submit_batch(
-                    serving, batchers, payload
-                )
+            with contextlib.ExitStack() as pinned:
+                with tracing.stage(tracing.ENGINE_SUBMIT):
+                    serving, batchers = pinned.enter_context(
+                        self._serving_snapshot(request)
+                    )
+                    entries, any_submitted = self._submit_batch(
+                        serving, batchers, payload
+                    )
                 if _attempt == 0 and not any_submitted and any(
                     e[0] == "reloading" for e in entries
                 ):
@@ -1341,60 +1369,82 @@ class EngineServer:
         # hold the connection for N sequential predict timeouts
         deadline = time.monotonic() + self._predict_timeout_s
 
+        # every wait first, then every tail: the response leaves when
+        # the last query is done either way, and each stage is one
+        # interval of the post, observed once
+        awaited: dict[int, Any] = {}
+        with tracing.stage(tracing.ENGINE_AWAIT):
+            for i, (state, _data, futures) in enumerate(entries):
+                if state != "ok":
+                    continue
+                try:
+                    awaited[i] = self._await_predictions(futures, deadline)
+                except Exception as exc:  # noqa: BLE001 - per-slot status below
+                    awaited[i] = exc
+
         results = []
         logged = False  # one remote report per batch, not per slot
-        for (state, data, futures), q in zip(entries, payload):
-            if state == "bad":
-                results.append(
-                    {"status": 400,
-                     "message": "query must be a JSON object"}
-                )
-                continue
-            if state == "shed":
-                results.append(
-                    {"status": 503,
-                     "message": "server overloaded; retry later"}
-                )
-                continue
-            if state == "reloading":
-                results.append(
-                    {"status": 503,
-                     "message": "server is reloading; retry"}
-                )
-                continue
-            if state == "expired":
-                results.append(
-                    {"status": 504,
-                     "message": "deadline expired before dispatch"}
-                )
-                continue
-            if state == "error":
-                if self._log_queue is not None and not logged:
-                    self._post_remote_log(data, request)
-                    logged = True
-                results.append({"status": 500, "message": str(data)})
-                continue
-            try:
-                prediction = self._serve_one(
-                    serving, q, data, futures, deadline=deadline
-                )
-                results.append({"status": 200, "prediction": prediction})
-            except resilience.DeadlineExceeded:
-                results.append(
-                    {"status": 504,
-                     "message": "deadline expired before device dispatch"}
-                )
-            except BatcherOverloaded:
-                self._abandon([f for f in futures if not f.done()])
-                results.append(
-                    {"status": 503,
-                     "message": "shed under overload; retry later"}
-                )
-            except Exception as exc:  # noqa: BLE001 - per-slot status
-                if self._log_queue is not None and not logged:
-                    self._post_remote_log(exc, request)
-                    logged = True
-                results.append({"status": 500, "message": str(exc)})
+        with tracing.stage(tracing.ENGINE_SERVE):
+            for i, ((state, data, futures), q) in enumerate(
+                zip(entries, payload)
+            ):
+                if state == "bad":
+                    results.append(
+                        {"status": 400,
+                         "message": "query must be a JSON object"}
+                    )
+                    continue
+                if state == "shed":
+                    results.append(
+                        {"status": 503,
+                         "message": "server overloaded; retry later"}
+                    )
+                    continue
+                if state == "reloading":
+                    results.append(
+                        {"status": 503,
+                         "message": "server is reloading; retry"}
+                    )
+                    continue
+                if state == "expired":
+                    results.append(
+                        {"status": 504,
+                         "message": "deadline expired before dispatch"}
+                    )
+                    continue
+                if state == "error":
+                    if self._log_queue is not None and not logged:
+                        self._post_remote_log(data, request)
+                        logged = True
+                    results.append({"status": 500, "message": str(data)})
+                    continue
+                try:
+                    predictions = awaited[i]
+                    if isinstance(predictions, Exception):
+                        raise predictions
+                    prediction = self._serve_tail(
+                        serving, q, data, predictions
+                    )
+                    results.append(
+                        {"status": 200, "prediction": prediction}
+                    )
+                except resilience.DeadlineExceeded:
+                    results.append(
+                        {"status": 504,
+                         "message": "deadline expired before device "
+                         "dispatch"}
+                    )
+                except BatcherOverloaded:
+                    self._abandon([f for f in futures if not f.done()])
+                    results.append(
+                        {"status": 503,
+                         "message": "shed under overload; retry later"}
+                    )
+                except Exception as exc:  # noqa: BLE001 - per-slot status
+                    if self._log_queue is not None and not logged:
+                        self._post_remote_log(exc, request)
+                        logged = True
+                    results.append({"status": 500, "message": str(exc)})
         return results
 
     def _abandon(self, futures) -> None:
@@ -2003,6 +2053,7 @@ class EngineServer:
             # their threads on a dead leader
             self._cache.close()
         self._device_sampler.stop()
+        self._compile_watch.close()
         self._plugins.close()
         if self._log_queue is not None:
             # stop the sender so a retired server (and its staged

@@ -23,6 +23,7 @@ from typing import Any, Callable
 from urllib.parse import parse_qs, urlparse
 
 from predictionio_tpu.obs import MetricRegistry, set_request_id
+from predictionio_tpu.obs.registry import install_process_clocks
 from predictionio_tpu.obs import tracing
 from predictionio_tpu.obs.slo import SLOMonitor
 from predictionio_tpu.obs.context import log_json, redact_keys
@@ -321,7 +322,10 @@ class HTTPServer:
         chaos_ref = router.chaos_middleware
         admission_ref = router.admission
         state = resilience.DrainState()
+        #: where this server's handler threads time their stages
+        stages = tracing.StageSink(registry)
         if registry is not None:
+            install_process_clocks(registry)
             requests_total = registry.counter(
                 "pio_http_requests_total",
                 "HTTP requests by service, method, and status",
@@ -436,6 +440,35 @@ class HTTPServer:
                     )
                 return None
 
+            def _shed_response(self, request, rej) -> Response:
+                """The answer to a request the adaptive limiter refused:
+                429/503 with the cooperative ``Retry-After``."""
+                request.route = (
+                    router_ref.match_route(request) or "(unmatched)"
+                )
+                if rejected_total is not None:
+                    rejected_total.labels(service, "overload").inc()
+                return Response(
+                    rej.status,
+                    {
+                        "message": (
+                            "server overloaded"
+                            if rej.reason == "limit"
+                            else "tenant over fair share"
+                        )
+                        + "; retry after the hinted delay",
+                        "reason": rej.reason,
+                    },
+                    headers={
+                        "Retry-After": admission.format_retry_after(
+                            rej.retry_after_s
+                        ),
+                        # refused BEFORE the handler: nothing ran, so
+                        # even a POST replays safely
+                        admission.SHED_HEADER: rej.reason,
+                    },
+                )
+
             def _handle(self):
                 # count the request in-flight for the WHOLE handler —
                 # until the response bytes are written, so the process
@@ -456,29 +489,33 @@ class HTTPServer:
                     state.end_request()
 
             def _handle_inner(self):
-                parsed = urlparse(self.path)
-                query = {
-                    k: v[0] for k, v in parse_qs(parsed.query).items()
-                }
-                length = int(self.headers.get("Content-Length") or 0)
-                body = self.rfile.read(length) if length else b""
-                request = Request(
-                    method=self.command,
-                    path=parsed.path,
-                    query=query,
-                    headers=self.headers,
-                    body=body,
-                    path_params={},
+                # forwarded or minted; installed in the thread context so
+                # the batcher and log lines downstream can read it, and
+                # bound first so that every stage's annotation carries it
+                request_id = set_request_id(
+                    self.headers.get("X-Request-ID")
                 )
+                stages.bind(request_id=request_id)
+                with tracing.stage(tracing.HTTP_READ):
+                    parsed = urlparse(self.path)
+                    query = {
+                        k: v[0] for k, v in parse_qs(parsed.query).items()
+                    }
+                    length = int(self.headers.get("Content-Length") or 0)
+                    body = self.rfile.read(length) if length else b""
+                    request = Request(
+                        method=self.command,
+                        path=parsed.path,
+                        query=query,
+                        headers=self.headers,
+                        body=body,
+                        path_params={},
+                    )
                 try:
                     request.client_addr = "%s:%s" % self.client_address[:2]
                 except (TypeError, IndexError):  # AF_UNIX and friends
                     request.client_addr = str(self.client_address)
-                # forwarded or minted; installed in the thread context so
-                # the batcher and log lines downstream can read it
-                request.request_id = set_request_id(
-                    self.headers.get("X-Request-ID")
-                )
+                request.request_id = request_id
                 # the remaining-budget deadline rides the same context;
                 # set unconditionally — a keep-alive connection reuses
                 # this thread, and a stale deadline must not leak into
@@ -511,52 +548,28 @@ class HTTPServer:
                     parsed.path.startswith(("/metrics", "/debug/"))
                 )
                 t0 = time.perf_counter()
-                early = self._admission(request, parsed.path, deadline,
-                                        telemetry_path)
-                # adaptive overload gate, AFTER drain/deadline refusals
-                # (those must not consume limiter slots) and never for
-                # the telemetry surface. Every admit is paired with
-                # exactly one release below — including the chaos-reset
-                # early return.
-                admitted = False
-                if (
-                    early is None
-                    and admission_ref is not None
-                    and not telemetry_path
-                ):
-                    try:
-                        admission_ref.try_acquire(
-                            request.criticality, tenant
-                        )
-                        admitted = True
-                    except admission.AdmissionRejected as rej:
-                        request.route = (
-                            router_ref.match_route(request)
-                            or "(unmatched)"
-                        )
-                        if rejected_total is not None:
-                            rejected_total.labels(
-                                service, "overload"
-                            ).inc()
-                        early = Response(
-                            rej.status,
-                            {
-                                "message": (
-                                    "server overloaded"
-                                    if rej.reason == "limit"
-                                    else "tenant over fair share"
-                                )
-                                + "; retry after the hinted delay",
-                                "reason": rej.reason,
-                            },
-                            headers={
-                                "Retry-After": admission
-                                .format_retry_after(rej.retry_after_s),
-                                # refused BEFORE the handler: nothing
-                                # ran, so even a POST replays safely
-                                admission.SHED_HEADER: rej.reason,
-                            },
-                        )
+                with tracing.stage(tracing.HTTP_ADMIT):
+                    early = self._admission(
+                        request, parsed.path, deadline, telemetry_path
+                    )
+                    # adaptive overload gate, AFTER drain/deadline
+                    # refusals (those must not consume limiter slots)
+                    # and never for the telemetry surface. Every admit
+                    # is paired with exactly one release below —
+                    # including the chaos-reset early return.
+                    admitted = False
+                    if (
+                        early is None
+                        and admission_ref is not None
+                        and not telemetry_path
+                    ):
+                        try:
+                            admission_ref.try_acquire(
+                                request.criticality, tenant
+                            )
+                            admitted = True
+                        except admission.AdmissionRejected as rej:
+                            early = self._shed_response(request, rej)
                 # True when the response carries NO verdict about this
                 # server's capacity (dependency fast-fail, injected
                 # fault): released without feeding the limiter
@@ -693,6 +706,18 @@ class HTTPServer:
                         else:
                             outcome = admission.OUTCOME_OK
                         admission_ref.release(elapsed, outcome, tenant)
+                with tracing.stage(tracing.HTTP_RESPOND):
+                    self._respond(
+                        request, response, parsed.path, elapsed,
+                        telemetry_path,
+                    )
+
+            def _respond(
+                self, request, response, path, elapsed, telemetry_path
+            ) -> None:
+                """Encode, account, log and write one response;
+                ``elapsed`` is the handler's time, which the request
+                histogram, the SLO and the access log share."""
                 if response.status >= 400 and isinstance(
                     response.body, dict
                 ):
@@ -701,7 +726,8 @@ class HTTPServer:
                     response.body = {
                         **response.body, "requestId": request.request_id
                     }
-                payload = response.payload()
+                with tracing.stage(tracing.HTTP_ENCODE):
+                    payload = response.payload()
                 route = request.route or "(unmatched)"
                 if requests_total is not None:
                     requests_total.labels(
@@ -724,19 +750,20 @@ class HTTPServer:
                     "http_request",
                     service=service,
                     method=self.command,
-                    path=parsed.path,
+                    path=path,
                     route=route,
                     status=response.status,
                     ms=round(elapsed * 1000, 3),
                 )
-                self.send_response(response.status)
-                self.send_header("Content-Type", response.content_type)
-                self.send_header("Content-Length", str(len(payload)))
-                self.send_header("X-Request-ID", request.request_id)
-                for k, v in response.headers.items():
-                    self.send_header(k, v)
-                self.end_headers()
-                self.wfile.write(payload)
+                with tracing.stage(tracing.HTTP_WRITE):
+                    self.send_response(response.status)
+                    self.send_header("Content-Type", response.content_type)
+                    self.send_header("Content-Length", str(len(payload)))
+                    self.send_header("X-Request-ID", request.request_id)
+                    for k, v in response.headers.items():
+                        self.send_header(k, v)
+                    self.end_headers()
+                    self.wfile.write(payload)
 
             do_GET = do_POST = do_DELETE = do_PUT = _handle
 

@@ -802,6 +802,7 @@ class TestProfileCapture:
             "jax_trace/",
             "manifest.json",
             "spans.json",
+            "summary.json",
         ]
         with open(f"{art}/spans.json") as f:
             spans = json.load(f)

@@ -396,7 +396,9 @@ class TestProfilingTrace:
     def profiler_calls(self, monkeypatch):
         calls = []
 
-        def fake_trace(trace_dir):
+        def fake_trace(trace_dir, profiler_options=None):
+            # Python's own tracer is off in every trace the package takes
+            assert profiler_options.python_tracer_level == 0
             calls.append(trace_dir)
             return contextlib.nullcontext()
 
@@ -863,7 +865,7 @@ class TestTrainTimeline:
         monkeypatch.setattr(
             profiling.jax.profiler,
             "trace",
-            lambda d: contextlib.nullcontext(),
+            lambda d, **options: contextlib.nullcontext(),
         )
         instance_id = run_train(
             _engine(), _params(), engine_id="tl", ctx=ctx,
@@ -894,7 +896,7 @@ class TestTrainTimeline:
         monkeypatch.setattr(
             profiling.jax.profiler,
             "trace",
-            lambda d: contextlib.nullcontext(),
+            lambda d, **options: contextlib.nullcontext(),
         )
         params = EngineParams(
             data_source=("", FakeParams(id=1, error=True)),
