@@ -2428,7 +2428,8 @@ def build_parser() -> argparse.ArgumentParser:
     )
     p.add_argument(
         "--max-wait-ms", dest="max_wait_ms", type=float, default=2.0,
-        help="micro-batcher fill window in milliseconds",
+        help="micro-batcher fill window in milliseconds (the upper "
+             "limit of the adaptive window)",
     )
     p.add_argument(
         "--pipeline-depth", dest="pipeline_depth", type=int, default=2,
@@ -2438,8 +2439,8 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument(
         "--no-adaptive-wait", dest="no_adaptive_wait",
         action="store_true",
-        help="disable the self-tuning fill window (full batches shrink "
-             "the next wait toward 0; idle traffic restores it)",
+        help="wait out the whole fill window every batch, instead of "
+             "only while the observed arrival gap is within it",
     )
     p.add_argument(
         "--no-admission", dest="no_admission", action="store_true",

@@ -78,6 +78,15 @@ logger = logging.getLogger(__name__)
 #: trace of a pool of batchers
 _BATCH_SEQ = itertools.count(1)
 
+#: the adaptive window's arrival-gap estimate (MicroBatcher._gap_ewma).
+#: Half the weight on the newest gap and no gap counted as more than
+#: three windows: one long gap after a burst lifts the mean over the
+#: window (3/2 of it), so an idle tenant's next query is not held, and
+#: two close arrivals bring it back under (3/4), so a burst after an
+#: idle hour coalesces from its third item on
+_GAP_WEIGHT = 0.5
+_GAP_CAP_WINDOWS = 3.0
+
 
 class BatcherOverloaded(Exception):
     """Queue depth bound hit — shed the request instead of queuing it.
@@ -154,6 +163,9 @@ class _NullMetrics:
         pass
 
     def dispatched(self, occupancy: int, seconds: float) -> None:
+        pass
+
+    def window_waited(self) -> None:
         pass
 
     def enqueued(self, seconds: float) -> None:
@@ -272,6 +284,7 @@ class _BatcherMetrics:
 
     __slots__ = ("_depth", "_shed", "_shed_class", "_name", "_occupancy",
                  "_dispatch", "_enqueue", "_sync", "_batches",
+                 "_windows_waited",
                  "_cancelled", "_expired", "_leaked",
                  "_tenant_device", "_tenant_wait", "_tenant_requests",
                  "_attr_lock", "_noisy")
@@ -326,6 +339,12 @@ class _BatcherMetrics:
         self._batches = registry.counter(
             "pio_batches_total",
             "Device batches dispatched",
+            ("batcher",),
+        ).labels(name)
+        self._windows_waited = registry.counter(
+            "pio_batch_windows_waited_total",
+            "Coalescing windows in which the collector slept for "
+            "company (over pio_batches_total: the waited share)",
             ("batcher",),
         ).labels(name)
         self._cancelled = registry.counter(
@@ -385,6 +404,9 @@ class _BatcherMetrics:
         self._shed.inc()
         self._shed_class.labels(self._name, criticality).inc()
 
+    def window_waited(self) -> None:
+        self._windows_waited.inc()
+
     def dispatched(self, occupancy: int, seconds: float) -> None:
         self._batches.inc()
         self._occupancy.observe(occupancy)
@@ -429,11 +451,17 @@ class MicroBatcher:
     A batch is dispatched when ``max_batch`` items are waiting or the
     coalescing wait elapsed since the first queued item — the classic
     latency/throughput knob. With ``adaptive_wait`` (default on) the
-    wait self-tunes: each batch that fills to ``max_batch`` halves the
-    next window toward 0 (a hot queue refills instantly from backlog —
-    waiting only adds latency), and the first non-full batch restores
-    the full ``max_wait_ms`` (idle traffic keeps the whole window to
-    coalesce). ``max_queue`` bounds queued items: beyond it, ``submit``
+    window is waited only while another arrival is expected inside it:
+    ``submit`` keeps ``_gap_ewma``, an exponentially weighted mean of
+    the gaps between consecutive arrivals (weight ``_GAP_WEIGHT``, each
+    gap counted as at most ``_GAP_CAP_WINDOWS`` windows), and the
+    collector skips the window when that mean is longer than
+    ``max_wait_ms`` — waiting only adds latency when nothing will join.
+    Otherwise, and always with no history (fewer than two arrivals) or
+    with ``adaptive_wait`` off, it waits up to ``max_wait_ms``. Under
+    load the coalescing comes from backlog: what arrives while the
+    collector dispatches leaves together. ``max_queue`` bounds queued
+    items: beyond it, ``submit``
     raises :class:`BatcherOverloaded` so overload turns into fast
     shedding rather than client-side timeout hangs.
 
@@ -485,9 +513,12 @@ class MicroBatcher:
         self._max_batch = max_batch
         self._max_wait = max_wait_ms / 1000.0
         self._adaptive = adaptive_wait
-        #: the live coalescing window (introspectable; updated by the
-        #: collector after every batch when adaptive_wait is on)
-        self._current_wait = self._max_wait
+        #: the arrival gap the window is held against (introspectable,
+        #: seconds; cv held): 0 until two arrivals have been seen, so a
+        #: batcher with no history waits
+        self._gap_ewma = 0.0
+        self._gap_cap = _GAP_CAP_WINDOWS * self._max_wait
+        self._last_arrival: float | None = None
         self._close_join_timeout_s = close_join_timeout_s
         self._max_queue = (
             max_queue if max_queue is not None else 8 * max_batch
@@ -565,14 +596,20 @@ class MicroBatcher:
             parent_span = tracing.current_span()
             # submit time is stamped unconditionally (not just under a
             # trace): per-tenant queue-wait attribution needs it for
-            # every slot
+            # every slot, and the window rule the gap to the arrival
+            # before it
+            now = time.monotonic()
+            if self._last_arrival is not None:
+                gap = min(now - self._last_arrival, self._gap_cap)
+                self._gap_ewma += _GAP_WEIGHT * (gap - self._gap_ewma)
+            self._last_arrival = now
             self._buf.append(
                 _Slot(
                     item,
                     future,
                     get_request_id(),
                     parent_span,
-                    time.monotonic(),
+                    now,
                     deadline,
                     criticality,
                     tenant,
@@ -724,13 +761,16 @@ class MicroBatcher:
                 # the window's n is what was queued when it opened
                 self._stages.bind(batch=seq, n=len(self._buf))
                 with tracing.stage(tracing.BATCH_WINDOW):
-                    if not self._closed.is_set():
-                        # coalesce: wait out the window from the FIRST
-                        # queued item unless the batch fills (or close
-                        # lands — a drain dispatches immediately)
-                        window_end = (
-                            time.monotonic() + self._current_wait
-                        )
+                    # coalesce only while company is expected: the
+                    # arrivals' recent gap is within the window (or the
+                    # window is fixed). A full batch, or close landing —
+                    # a drain dispatches immediately — ends it early
+                    slept = False
+                    if (
+                        not self._adaptive
+                        or self._gap_ewma <= self._max_wait
+                    ):
+                        window_end = time.monotonic() + self._max_wait
                         while (
                             len(self._buf) < self._max_batch
                             and not self._closed.is_set()
@@ -739,22 +779,12 @@ class MicroBatcher:
                             if remaining <= 0:
                                 break
                             self._cv.wait(remaining)
+                            slept = True
+                    if slept:
+                        self._metrics.window_waited()
                     batch = self._select_batch()
             self._stages.bind(batch=seq, n=len(batch))
-            full = len(batch) >= self._max_batch
             self._dispatch_batch(batch, seq)
-            if self._adaptive:
-                # hot: a full batch means backlog is doing the
-                # coalescing — halve the window toward 0 so queue wait
-                # stops taxing p50. The first non-full batch restores
-                # the whole window for idle-traffic coalescing.
-                if full:
-                    wait = self._current_wait * 0.5
-                    if wait < self._max_wait / 64:
-                        wait = 0.0
-                    self._current_wait = wait
-                else:
-                    self._current_wait = self._max_wait
         if self._completer is not None:
             self._pending.put(None)  # completer drains in order, then exits
 

@@ -339,44 +339,200 @@ class TestSinglePhaseCompat:
             b.close()
 
 
-class TestAdaptiveWait:
-    def test_full_batch_shrinks_wait_idle_restores_it(self):
-        release = threading.Event()
-        release.set()
-        b = MicroBatcher(
-            lambda items: list(items), max_batch=2, max_wait_ms=50,
-        )
-        try:
-            full = b._max_wait  # seconds
-            assert b._current_wait == full
-            # a full batch must shrink the next window
-            fs = [b.submit(1), b.submit(2)]
-            [f.result(5) for f in fs]
-            deadline = time.monotonic() + 2
-            while b._current_wait >= full and time.monotonic() < deadline:
-                time.sleep(0.005)
-            assert b._current_wait < full
-            # a partial (idle-traffic) batch restores it
-            b.submit(3).result(5)
-            deadline = time.monotonic() + 2
-            while b._current_wait != full and time.monotonic() < deadline:
-                time.sleep(0.005)
-            assert b._current_wait == full
-        finally:
-            b.close()
+#: the window and the two gaps of the arrival processes below: generous
+#: real time, so that a loaded test machine cannot turn one into the other
+_W = 0.1
+_LONG = 3 * _W
+_SHORT = _W / 20
 
-    def test_adaptive_off_keeps_the_window(self):
-        b = MicroBatcher(
-            lambda items: list(items), max_batch=2, max_wait_ms=50,
-            adaptive_wait=False,
+
+class _Arrivals:
+    """Drives one MicroBatcher through a script of arrivals and keeps what
+    the window rule can be judged by: the batches as dispatched and the
+    registry's view (batches, windows waited, ``batch.window`` stage)."""
+
+    def __init__(self, **kwargs):
+        self.registry = MetricRegistry()
+        self.batches: list[list] = []
+        self.futures: list = []
+        self.batcher = MicroBatcher(
+            self._record, max_wait_ms=1e3 * _W, registry=self.registry,
+            name="rule", **kwargs,
         )
+
+    def _record(self, items):
+        self.batches.append(list(items))
+        return list(items)
+
+    def submit(self, n=1, gap=0.0, locked=False):
+        """``n`` arrivals ``gap`` apart; ``locked`` holds the batcher's
+        (re-entrant) condition so the collector sees them all at once."""
+        if locked:
+            with self.batcher._cv:
+                return self.submit(n, gap)
+        for i in range(n):
+            if i and gap:
+                time.sleep(gap)
+            self.futures.append(self.batcher.submit(len(self.futures)))
+        return self.futures[-1]
+
+    def settle(self):
+        return [f.result(5) for f in self.futures]
+
+    def close(self):
+        self.batcher.close()
+        data = self.registry.to_dict()
+
+        def value(family, field, **labels):
+            [sample] = [
+                s for s in data[family]["samples"] if s["labels"] == labels
+            ]
+            return sample[field]
+
+        self.n_batches = value("pio_batches_total", "value", batcher="rule")
+        self.waited = value(
+            "pio_batch_windows_waited_total", "value", batcher="rule"
+        )
+        self.windows = value("pio_stage_seconds", "count", stage="batch.window")
+        self.window_s = value("pio_stage_seconds", "sum", stage="batch.window")
+
+
+def _lone(a):
+    a.submit(5, gap=_LONG)
+    a.settle()
+    # the first has no history; every later gap is far over the window
+    assert [len(b) for b in a.batches] == [1] * 5
+    return 1
+
+
+def _dense(a):
+    a.submit(12, gap=_SHORT)
+    a.settle()
+    # 12 arrivals inside about 0.6 of a window leave together
+    assert len(a.batches) <= 2 and len(a.batches[0]) >= 6
+    return len(a.batches)
+
+
+def _no_history(a):
+    t0 = time.monotonic()
+    a.submit().result(5)
+    assert time.monotonic() - t0 >= 0.9 * _W
+    return 1
+
+
+def _burst_after_idleness(a):
+    a.submit(2, gap=_LONG)
+    time.sleep(_LONG)
+    # the burst's first item is not held for company...
+    a.submit().result(_W / 2)
+    a.submit(7, gap=_SHORT)
+    a.settle()
+    # ...and the rest is not split into singles: two close arrivals are
+    # enough to wait again
+    burst = a.batches[2:]
+    assert len(burst) <= 3 and len(burst[-1]) >= 6, a.batches
+    return 2  # the arrival with no history, and the burst's rest
+
+
+def _long_gap_after_burst(a):
+    a.submit(8, gap=_SHORT)
+    a.settle()
+    time.sleep(_LONG)
+    # one long gap is enough to stop waiting
+    a.submit().result(_W / 2)
+    assert a.batches[-1] == [8]
+    return len(a.batches) - 1
+
+
+def _full_batch(a):
+    # no history, so the window would be waited: the fill ends it
+    a.submit(4, locked=True).result(_W / 2)
+    assert a.batches == [[0, 1, 2, 3]]
+    return 0
+
+
+def _backlog_over_full(a):
+    a.submit(10, locked=True)
+    a.settle()
+    # a full batch leaves at once; the rest (no gap over the window
+    # seen) waits for company as a partial batch always did
+    assert [len(b) for b in a.batches] == [4, 4, 2]
+    return 1
+
+
+def _posts_after_idleness(a):
+    # a post is back-to-back submits of one thread: the interpreter
+    # lock keeps the collector out until the post is in, whatever the
+    # rule thinks of the gap before it
+    for _ in range(6):
+        time.sleep(_LONG)
+        a.submit(16)
+    a.settle()
+    assert len(a.batches) <= 9, [len(b) for b in a.batches]
+    return None  # a split post's rest may wait or not
+
+
+def _fixed_window(a):
+    a.submit(3, gap=_LONG)
+    a.settle()
+    assert [len(b) for b in a.batches] == [1] * 3
+    return 3
+
+
+class TestAdaptiveWait:
+    """The window follows the arrival gap the batcher observes
+    (docs/serving.md "Adaptive fill window")."""
+
+    @pytest.mark.parametrize(
+        "process, kwargs, share",
+        [
+            (_lone, {}, (0.0, 0.25)),
+            (_dense, {}, (0.99, 1.0)),
+            (_no_history, {}, None),
+            (_burst_after_idleness, {}, None),
+            (_long_gap_after_burst, {}, None),
+            (_full_batch, {"max_batch": 4}, None),
+            (_backlog_over_full, {"max_batch": 4}, None),
+            (_posts_after_idleness, {"max_batch": 16}, None),
+            (_fixed_window, {"adaptive_wait": False}, (1.0, 1.0)),
+        ],
+        ids=lambda v: v.__name__.strip("_") if callable(v) else "",
+    )
+    def test_window_follows_the_arrival_process(self, process, kwargs, share):
+        a = _Arrivals(**kwargs)
         try:
-            fs = [b.submit(1), b.submit(2)]
-            [f.result(5) for f in fs]
-            b.submit(3).result(5)
-            assert b._current_wait == b._max_wait
+            waited = process(a)
         finally:
-            b.close()
+            a.close()
+        assert a.settle() == list(range(len(a.futures)))
+        # what reads the mechanism: one window observed a batch, waited
+        # or not, and the counter holds exactly the windows slept
+        assert a.windows == a.n_batches == len(a.batches)
+        assert a.waited <= a.n_batches
+        if waited is not None:
+            assert a.waited == waited
+            # a skipped window takes no time
+            assert a.window_s < (waited + 1) * _W
+        if share is not None:
+            # the waited share tells the regimes apart
+            assert share[0] <= a.waited / a.n_batches <= share[1]
+
+    def test_gap_estimate_is_introspectable(self):
+        a = _Arrivals()
+        try:
+            b = a.batcher
+            assert b._gap_ewma == 0.0
+            a.submit().result(5)
+            assert b._gap_ewma == 0.0  # one arrival: no gap yet
+            time.sleep(_LONG)
+            a.submit().result(5)
+            # a gap counts as three windows at most, at half the weight
+            assert b._gap_ewma == pytest.approx(1.5 * _W)
+            a.submit(2, locked=True)
+            a.settle()
+            assert b._gap_ewma < 0.5 * _W
+        finally:
+            a.close()
 
 
 class TestPipelineTelemetry:
