@@ -81,6 +81,18 @@ def idle_share(run, spec):
     return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])
 
 
+def idle_state_share(run, spec):
+    """The idle seconds of the state the metric's file names (`state`, one
+    of `trace_reduce.IDLE_STATES` or `no_request`, `unattributed`) over the
+    traced window, in percent; nothing where the trace holds no stage
+    event and the idle time is one lump."""
+    trace = run["trace"] or {}
+    seconds = dict(trace.get("idle_gaps") or ()).get(spec["state"])
+    if seconds is None:
+        return None
+    return 100.0 * seconds / trace["window_s"]
+
+
 def _topk_runs(run) -> int:
     runs = run["trace"].get("module_runs") or {}
     return runs.get("jit_" + run["config"]["jit_names"][-1], 0)
@@ -129,7 +141,7 @@ def serve_mfu(run, spec):
 READERS = {
     f.__name__: f for f in (
         histogram_mean, counter_share, load_number, memory_gib, idle_share,
-        topk_device_ms, topk_roofline, serve_mfu,
+        idle_state_share, topk_device_ms, topk_roofline, serve_mfu,
     )
 }
 

@@ -27,18 +27,20 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 
 
-def load_cell(workload: str, rehearse: bool):
-    """(bench, cell, config, traffic) of a workload, by name."""
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+def load_cell(workload: str, rehearse: bool, root: str = ROOT):
+    """(bench, cell, config, traffic) of a workload, by name, in the
+    checkout at ``root``."""
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
         raise SystemExit(f"no workload {workload!r} in BENCHMARK.json")
     cell = cells[workload]
     files = {c["name"]: c["file"] for c in bench["configs"]}
-    with open(os.path.join(ROOT, files[cell["config"]])) as f:
+    with open(os.path.join(root, files[cell["config"]])) as f:
         config = json.load(f)
-    with open(os.path.join(HERE, "traffic", cell["traffic"] + ".json")) as f:
+    here = os.path.join(root, os.path.relpath(HERE, ROOT))
+    with open(os.path.join(here, "traffic", cell["traffic"] + ".json")) as f:
         traffic = json.load(f)
     if rehearse:
         config = {**config, **config["rehearse"]}

@@ -29,6 +29,12 @@ import roofline
 import trace_reduce
 
 ENGINE_ID = "chipbench"
+#: what this runner reads of a configuration's file, beside the general keys
+#: every configuration has: `build_server`, `send_plans`, the roofline readers
+CONFIG_KEYS = (
+    "n_users", "n_items", "rank", "tenants", "num", "zipf_exponent",
+    "quantize", "table_format", "jit_names",
+)
 _LOADGEN = os.path.join(os.path.dirname(os.path.abspath(loadgen.__file__)), "loadgen.py")
 #: children answer within the request time-out after the window closes
 _COLLECT_TIMEOUT_S = loadgen.REQUEST_TIMEOUT_S + 60.0
@@ -130,12 +136,14 @@ def _sleep_until(t: float) -> None:
         time.sleep(wait)
 
 
-def watch_window(times, registry, traffic, trace: bool) -> dict:
+def watch_window(times, registry, traffic, trace_dir: str | None) -> dict:
     """What the server's process does during the window: nothing, or in a
     traced run the registry at both ends and the profiler over a slice of
-    steady traffic in the middle."""
+    steady traffic in the middle, written under ``trace_dir``. The trace is
+    read once the window's requests are all in (`run`): reading it here
+    held the interpreter lock for 0.4 s of the window it measures."""
     _sleep_until(times["t_window"])
-    if not trace:
+    if not trace_dir:
         _sleep_until(times["t_end"])
         return {}
     import jax
@@ -143,21 +151,16 @@ def watch_window(times, registry, traffic, trace: bool) -> dict:
     seen = {"before": registry.to_dict()}
     trace_s = min(traffic["trace_s"], (times["t_end"] - times["t_window"]) / 2)
     _sleep_until((times["t_window"] + times["t_end"] - trace_s) / 2)
-    trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-")
-    try:
-        # Python's own tracer multiplies the host's work; the device lines
-        # and the window's span need none of it
-        options = jax.profiler.ProfileOptions()
-        options.python_tracer_level = 0
-        jax.profiler.start_trace(trace_dir, profiler_options=options)
-        q0 = registry.to_dict()
-        with jax.profiler.TraceAnnotation(trace_reduce.WINDOW_EVENT):
-            time.sleep(trace_s)
-        q1 = registry.to_dict()
-        jax.profiler.stop_trace()
-        seen["events"] = trace_reduce.load_events(trace_dir)
-    finally:
-        shutil.rmtree(trace_dir, ignore_errors=True)
+    # Python's own tracer multiplies the host's work; the device lines and
+    # the window's span need none of it
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    q0 = registry.to_dict()
+    with jax.profiler.TraceAnnotation(trace_reduce.WINDOW_EVENT):
+        time.sleep(trace_s)
+    q1 = registry.to_dict()
+    jax.profiler.stop_trace()
     seen["traced_queries"] = layer_metrics.delta(
         {"before": q0, "after": q1}, "pio_batch_occupancy", {}, "sum"
     )
@@ -275,6 +278,7 @@ def run(cell, bench, config, traffic, args, t_process_start, device) -> dict:
         os.sched_setaffinity(0, server_cores)
     procs = start_generators(traffic)
     server = http = None
+    trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-") if args.trace else None
     phases = {"start_s": time.monotonic() - t_process_start}
     try:
         server, http, registry = build_server(
@@ -285,8 +289,9 @@ def run(cell, bench, config, traffic, args, t_process_start, device) -> dict:
             procs, traffic, config, http.port, args.seed, args.seconds,
             generator_cores,
         )
-        seen = watch_window(times, registry, traffic, bool(args.trace))
+        seen = watch_window(times, registry, traffic, trace_dir)
         results = collect(procs)
+        events = trace_reduce.load_events(trace_dir) if trace_dir else []
         stats = jax.devices()[0].memory_stats() or {}
         memory_peak = int(stats.get("peak_bytes_in_use", 0))
         evicted = evictions(registry)
@@ -299,6 +304,8 @@ def run(cell, bench, config, traffic, args, t_process_start, device) -> dict:
             http.shutdown()
         if server is not None:
             server.close()
+        if trace_dir:
+            shutil.rmtree(trace_dir, ignore_errors=True)
     # the program's state goes before the reference runs
     del server, http
     gc.collect()
@@ -319,8 +326,7 @@ def run(cell, bench, config, traffic, args, t_process_start, device) -> dict:
     }
     if args.trace:
         trace = trace_reduce.reduce(
-            seen.get("events") or [],
-            ["jit_" + n for n in config["jit_names"]],
+            events, ["jit_" + n for n in config["jit_names"]]
         )
         gathered = {
             "before": seen["before"], "after": seen["after"], "trace": trace,

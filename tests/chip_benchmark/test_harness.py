@@ -1,7 +1,8 @@
 """The chip benchmark's harness, on the CPU at tiny size: the manifest and
-its data files, the arithmetic of the yardstick, the trace reduction on a
-small recorded trace, the command's contract, the control, and a run with
-the timed path broken underneath."""
+its data files (the rules are `manifest_rules`, functions of the manifest
+and its checkout, shown on a made-up addition too), the arithmetic of the
+yardstick, the trace reduction on small recorded traces, the command's
+contract, the control, and a run with the timed path broken underneath."""
 
 from __future__ import annotations
 
@@ -9,6 +10,7 @@ import argparse
 import json
 import os
 import re
+import shutil
 import subprocess
 import sys
 import time
@@ -18,28 +20,25 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 CHIP = os.path.join(ROOT, "benchmarks", "chip")
-sys.path[:0] = [CHIP, ROOT]
+sys.path[:0] = [CHIP, ROOT, os.path.dirname(os.path.abspath(__file__))]
 
 import layer_metrics  # noqa: E402
 import loadgen  # noqa: E402
+import manifest_rules as rules  # noqa: E402
 import reference  # noqa: E402
 import roofline  # noqa: E402
 import run as chip_run  # noqa: E402
 import trace_reduce  # noqa: E402
 
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-
-with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
-    BENCH = json.load(_f)
-CELLS = [w["name"] for w in BENCH["workloads"]]
+NAME, UNIT = rules.NAME, rules.UNIT
+BENCH = rules.load_bench(ROOT)
 CONFIGS = [c["name"] for c in BENCH["configs"]]
+with open(os.path.join(os.path.dirname(__file__), "data", "accepted_per_layer.json")) as _f:
+    ACCEPTED_PER_LAYER = json.load(_f)["names"]
 
 
 def _config(name):
-    entry = next(c for c in BENCH["configs"] if c["name"] == name)
-    with open(os.path.join(ROOT, entry["file"])) as f:
-        return json.load(f)
+    return rules.config_body(BENCH, ROOT, name)
 
 
 # -- the manifest and its data files ----------------------------------------
@@ -86,53 +85,44 @@ def test_names_units_and_lines(entry):
 @pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
 def test_cell_finds_its_files_and_metrics(cell):
     bench, found, config, traffic = chip_run.load_cell(cell["name"], False)
-    assert found == cell and cell["chips"] in (1, 4)
-    assert os.path.exists(os.path.join(CHIP, "runners", traffic["runner"] + ".py"))
-    for key in ("n_users", "n_items", "rank", "tenants", "limits", "control"):
-        assert key in config
-    reports = {
-        kind: [
-            m["name"] for m in bench[kind]
-            if cell["name"] in m.get("workloads", [cell["name"]])
-        ]
-        for kind in ("end_to_end", "per_layer")
-    }
-    assert "setup_s" in reports["end_to_end"] and len(reports["end_to_end"]) >= 2
-    assert reports["per_layer"]
+    assert (bench, found) == (BENCH, cell)
+    assert config == _config(cell["config"])
+    assert traffic == rules.traffic_body(BENCH, ROOT, cell["traffic"])
+    rules.check_cell(BENCH, ROOT, cell)
+
+
+def test_runner_declares_what_it_reads_of_a_configuration():
+    from runners import serve_http
+
+    assert rules.runner_config_keys(BENCH, ROOT, "serve_http") == serve_http.CONFIG_KEYS
+    # what the runner and the readers it feeds take from the configuration
+    read = set()
+    for path in ("runners/serve_http.py", "layer_metrics.py"):
+        with open(os.path.join(CHIP, path)) as f:
+            read |= set(re.findall(r'(?:config|cfg|"config"\])\["(\w+)"\]', f.read()))
+    assert read - set(rules.CONFIG_KEYS) == set(serve_http.CONFIG_KEYS)
 
 
 @pytest.mark.parametrize("metric", BENCH["per_layer"], ids=lambda m: m["name"])
 def test_per_layer_metric_has_reader_and_target(metric):
-    end_to_end = {m["name"]: m for m in BENCH["end_to_end"]}
-    target = end_to_end[metric["moves"]]
-    assert set(metric) <= {
-        "name", "unit", "better", "source", "layer", "moves", "workloads"
-    }
-    for cell in metric["workloads"]:
-        assert cell in CELLS
-        assert cell in target.get("workloads", CELLS)
-    base = metric["name"].split(".", 1)[-1]
-    assert any(
-        os.path.exists(os.path.join(CHIP, "metrics", stem + ext))
-        for stem in (metric["name"], base) for ext in (".json", ".py")
-    )
-    if metric["name"].endswith("_roofline") or "mfu" in metric["name"]:
-        assert metric["unit"] == "%"
+    rules.check_per_layer_metric(BENCH, ROOT, metric)
 
 
 @pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
 def test_config_entry(config):
-    assert set(config) == {"name", "source", "file", "reduced", "why"}
-    assert any(config["file"].startswith(p + "/") for p in BENCH["paths"])
-    assert any(w["config"] == config["name"] for w in BENCH["workloads"])
-    body = _config(config["name"])
-    # the widths of KDD Cup 2011 Track 1, uncut
-    assert (body["n_users"], body["n_items"], body["rank"]) == (1000990, 624961, 32)
-    assert body["resident_table_bytes"] > 0.25 * 2**34
-    assert body["server"] == {
-        "max_batch": 64, "max_wait_ms": 2.0, "pipeline_depth": 2,
-        "adaptive_wait": True, "admission": True, "warmup": True,
-    }
+    rules.check_config_entry(BENCH, ROOT, config)
+    if config["name"] in rules.ACCEPTED_CONFIGS:
+        rules.check_accepted_config(BENCH, ROOT, config)
+
+
+def test_accepted_configurations_and_cells_are_all_there():
+    assert CONFIGS[:2] == list(rules.ACCEPTED_CONFIGS)
+    assert [w["name"] for w in BENCH["workloads"]][:3] == list(rules.ACCEPTED_CELLS)
+
+
+@pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
+def test_every_control_is_held_to_the_limits_by_some_test(config):
+    rules.check_control_is_tested(BENCH, ROOT, config)
 
 
 def test_files_under_paths_have_plain_names():
@@ -143,6 +133,188 @@ def test_files_under_paths_have_plain_names():
                 assert re.match(r"^[A-Za-z0-9_.\-]+$", name), name
     for word in BENCH["command"]:
         assert not word.startswith("/") and ".." not in word
+
+
+# -- what a later PR may add as files, shown on a made-up addition ------------
+
+
+def _all_rules(bench, root, accepted):
+    """Every rule of the manifest over every entry of it."""
+    for entry in bench["configs"]:
+        rules.check_config_entry(bench, root, entry)
+        rules.check_control_is_tested(bench, root, entry)
+        if entry["name"] in rules.ACCEPTED_CONFIGS:
+            rules.check_accepted_config(bench, root, entry)
+    for cell in bench["workloads"]:
+        rules.check_cell(bench, root, cell)
+    for metric in bench["per_layer"]:
+        rules.check_per_layer_metric(bench, root, metric)
+    rules.check_per_layer_order(bench, root, accepted)
+
+
+def _copy_of_the_benchmark(tmp_path):
+    root = str(tmp_path)
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
+    for path in BENCH["paths"]:
+        shutil.copytree(
+            os.path.join(ROOT, path), os.path.join(root, path),
+            ignore=shutil.ignore_patterns("__pycache__"),
+        )
+    return rules.load_bench(root), root
+
+
+def _write(root, path, body):
+    with open(os.path.join(root, path), "w") as f:
+        json.dump(body, f)
+
+
+ML20M = "benchmarks/chip/configs/rec-pool-ml20m.json"
+
+
+def _made_up_addition(tmp_path):
+    """A copy of the benchmark with what a `model_config` PR would bring
+    as files and entries: a configuration of another source's widths on
+    another server setting, one cell, two per-layer metrics with their
+    readers. Nothing of it is measured; the names are made up."""
+    bench, root = _copy_of_the_benchmark(tmp_path)
+    tenants, n_users, n_items, rank = 400, 138493, 26744, 10
+    body = {
+        **_config("rec-pool-kddcup11"),
+        "source": "MovieLens 20M: 138,493 users x 26,744 movies (Harper and Konstan, ACM TiiS 5(4), 2015)",
+        "deployment": "a pool of small tenants on one chip, posts of 256",
+        "published": {"n_users": n_users, "n_items": n_items, "rank": rank},
+        "n_users": n_users, "n_items": n_items, "rank": rank, "tenants": tenants,
+        "server": {**rules.DEPLOY_DEFAULTS, "max_batch": 256},
+        "resident_table_bytes": tenants * (n_users + n_items) * rank * 4,
+        "floor_note": "400 tenants x 6.61 MB are 2.64 GB, 15.4% of 16 GiB: over the "
+        "12.5% floor, which holds where the device is busy 75% of the traced window",
+        "assumed": {"tenants": tenants, "server.max_batch": 256, "zipf_exponent": 1.0, "num": 10},
+    }
+    _write(root, ML20M, body)
+    bench["configs"].append({
+        "name": "rec-pool-ml20m", "source": body["source"], "file": ML20M,
+        "reduced": [], "why": "hundreds of small tenants",
+    })
+    cell = "serve-pool-ml20m-batch256"
+    bench["workloads"].append({
+        "name": cell, "config": "rec-pool-ml20m", "traffic": "batch_closed_loop",
+        "chips": 1, "why": "posts of 256 over 400 small tenants",
+    })
+    next(m for m in bench["end_to_end"] if m["name"] == "queries_per_s")["workloads"].append(cell)
+    for name, spec in (
+        ("ml20m.evictions_per_s", {"reader": "counter_share", "families": ["pio_pool_evictions_total"], "over": "pio_process_clock_seconds_total"}),
+        ("ml20m.restage_ms", {"reader": "histogram_mean", "families": ["pio_pool_stage_seconds"], "scale": 1000.0}),
+    ):
+        bench["per_layer"].append({
+            "name": name, "unit": "1", "better": "lower", "source": "program_counter",
+            "layer": "tenant pool", "moves": "queries_per_s", "workloads": [cell],
+        })
+        _write(root, f"benchmarks/chip/metrics/{name.split('.')[1]}.json", spec)
+    return bench, root
+
+
+def _cut_a_width(bench, root):
+    body = rules.config_body(bench, root, "rec-pool-ml20m")
+    _write(root, ML20M, {**body, "n_items": 8192})
+
+
+def _set_the_server(bench, root):
+    body = rules.config_body(bench, root, "rec-pool-ml20m")
+    _write(root, ML20M, {**body, "server": {**body["server"], "pipeline_depth": 4}})
+
+
+def _turn_the_cache_on(bench, root):
+    body = rules.config_body(bench, root, "rec-pool-ml20m")
+    _write(root, ML20M, {**body, "server": {**body["server"], "cache": True}})
+
+
+def _shrink_the_pool(bench, root):
+    body = rules.config_body(bench, root, "rec-pool-ml20m")
+    _write(root, ML20M, {**body, "tenants": 300, "resident_table_bytes": 300 * 6609480})
+
+
+def _insert_before_the_accepted(bench, root):
+    bench["per_layer"].insert(3, bench["per_layer"].pop())
+
+
+def _name_a_reference_that_is_not_there(bench, root):
+    body = rules.config_body(bench, root, "rec-pool-ml20m")
+    _write(root, ML20M, {**body, "reference": "reference_sequences"})
+
+
+def _bring_a_reference_with_no_test(bench, root):
+    _name_a_reference_that_is_not_there(bench, root)
+    shutil.copy(
+        os.path.join(CHIP, "reference.py"),
+        os.path.join(root, "benchmarks/chip/reference_sequences.py"),
+    )
+
+
+def _leave_out_what_the_runner_reads(bench, root):
+    body = rules.config_body(bench, root, "rec-pool-ml20m")
+    del body["table_format"]
+    _write(root, ML20M, body)
+
+
+@pytest.mark.parametrize("fault,message", [
+    (None, None),
+    (_cut_a_width, "width 'n_items' differs from the published one and is not under reduced"),
+    (_set_the_server, "server setting 'pipeline_depth' differs from deploy's default and is not under assumed"),
+    (_turn_the_cache_on, "server setting 'cache' differs from deploy's default and is not under assumed"),
+    (_shrink_the_pool, "resident_table_bytes is under 12.5% of the memory"),
+    (_insert_before_the_accepted, "the accepted per-layer entries come first"),
+    (_name_a_reference_that_is_not_there, "names a reference 'reference_sequences' that is not there"),
+    (_bring_a_reference_with_no_test, "no test_control_reference_sequences.py holds"),
+    (_leave_out_what_the_runner_reads, "runner 'serve_http' reads 'table_format'"),
+], ids=lambda v: getattr(v, "__name__", None) or ("sound" if v is None else "message"))
+def test_made_up_addition_meets_the_rules_and_each_fault_its_own(tmp_path, fault, message):
+    """A configuration of other widths, another server setting, a cell and
+    two per-layer entries land as files and entries with no test edited;
+    each planted fault fails on the assertion meant for it."""
+    bench, root = _made_up_addition(tmp_path)
+    if fault is None:
+        _all_rules(bench, root, ACCEPTED_PER_LAYER)
+        _write(root, "BENCHMARK.json", bench)
+        # and the harness itself finds the cell's files there
+        _b, cell, config, traffic = chip_run.load_cell("serve-pool-ml20m-batch256", True, root)
+        assert (config["rank"], config["server"]["max_batch"]) == (10, 256)
+        assert config["n_users"] == 3000 and traffic["runner"] == "serve_http"
+        return
+    fault(bench, root)
+    with pytest.raises(AssertionError, match=re.escape(message)):
+        _all_rules(bench, root, ACCEPTED_PER_LAYER)
+
+
+def _re_source_the_widths(body):
+    return {**body, "n_users": 480189, "published": {**body["published"], "n_users": 480189}}
+
+
+def _halve_the_pool(body):
+    return {**body, "resident_table_bytes": int(0.2 * 2**34)}
+
+
+def _tune_the_server(body):
+    return {
+        **body, "server": {**body["server"], "max_batch": 256},
+        "assumed": {**body["assumed"], "server.max_batch": 256},
+    }
+
+
+@pytest.mark.parametrize("config", rules.ACCEPTED_CONFIGS)
+@pytest.mark.parametrize("alter,message", [
+    (_re_source_the_widths, "the widths of KDD Cup 2011 Track 1, uncut"),
+    (_halve_the_pool, "a quarter of 16 GiB resident"),
+    (_tune_the_server, "pio-tpu deploy's defaults"),
+], ids=lambda v: getattr(v, "__name__", "message"))
+def test_accepted_configuration_stays_pinned(tmp_path, config, alter, message):
+    """An altered copy of an accepted configuration that meets the general
+    rule (it says what it changed) still fails the pin of its name."""
+    bench, root = _copy_of_the_benchmark(tmp_path)
+    entry = next(c for c in bench["configs"] if c["name"] == config)
+    _write(root, entry["file"], alter(rules.config_body(bench, root, config)))
+    rules.check_config_entry(bench, root, entry)
+    with pytest.raises(AssertionError, match=re.escape(message)):
+        rules.check_accepted_config(bench, root, entry)
 
 
 # -- the yardstick's arithmetic ---------------------------------------------
@@ -279,6 +451,101 @@ def test_trace_reduce_on_hand_made_events():
     assert trace_reduce.union_seconds([(0, 10), (5, 20), (30, 40)]) == pytest.approx(30e-9)
 
 
+def test_idle_time_goes_to_the_first_open_state_inside_the_window():
+    dev, host = "/device:TPU:0", "/host:CPU"
+    ops = trace_reduce.OPS_LINE
+    events = [
+        (host, "python", trace_reduce.WINDOW_EVENT, 1_000, 10_000),  # 1,000..11,000
+        (dev, ops, "%fusion.1 = f32[64,16]{1,0} fusion(...)", 2_000, 1_000, "top_k"),
+        (dev, ops, "%copy = f32[8]{0} copy(...)", 6_000, 500, ""),
+        # a handler from before the window to 9,000, its stages inside
+        (host, "t1", "http.read", 500, 1_000),            # clipped to 1,000..1,500
+        (host, "t1", "engine.await", 1_500, 6_500),       # no state of its own
+        (host, "t1", "http.respond", 8_000, 1_000),
+        # the batcher's threads: launch outranks the device_get it overlaps
+        (host, "t2", "predict.enqueue", 1_800, 1_700),    # 1,800..3,500, busy 2,000..3,000
+        (host, "t3", "predict.device_get", 3_200, 1_300),  # 3,200..4,500
+        (host, "t2", "batch.window", 10_500, 5_000),      # clipped to 10,500..11,000
+        (host, "t9", "not.a.stage", 1_000, 10_000),
+    ]
+    got = trace_reduce.reduce(events)
+    gaps = dict(got["idle_gaps"])
+    assert list(gaps) == sorted(gaps, key=lambda k: -gaps[k])
+    assert set(gaps) == {s for s, _ in trace_reduce.IDLE_STATES} | {"no_request", "unattributed"}
+    ns = {state: round(seconds * 1e9) for state, seconds in gaps.items()}
+    assert ns == {
+        "launch": 200 + 500,            # 1,800..2,000 and 3,000..3,500
+        "device_get": 1_000,            # 3,500..4,500
+        "materialize_settle": 0, "backpressure": 0,
+        "batch_window": 500,
+        "request_in": 500,              # 1,000..1,500
+        "response_out": 1_000,
+        # the handler waits, no stage of a state runs: 1,500..1,800,
+        # 4,500..6,000, 6,500..8,000
+        "unattributed": 300 + 1_500 + 1_500,
+        "no_request": 1_500,            # 9,000..10,500
+    }
+    assert sum(gaps.values()) == pytest.approx(got["window_s"] - got["busy_s"], abs=1e-12)
+    assert got["busy_s"] == pytest.approx(1_500e-9)
+    assert got["device_ops"] == [
+        ["top_k/fusion.1 f32[64,16]", pytest.approx(1_000e-9)],
+        ["copy f32[8]", pytest.approx(500e-9)],
+    ]
+    # two device planes: the mean of their idle times, state by state
+    second = [("/device:TPU:1", *e[1:]) for e in events if e[0] == dev][:1]
+    both = dict(trace_reduce.reduce(events + second)["idle_gaps"])
+    assert both["unattributed"] == pytest.approx((3_300 + 3_800) / 2 * 1e-9)
+    assert both["launch"] == pytest.approx(700e-9)
+    # no stage event: the one lump, as before the stages
+    bare = trace_reduce.reduce([e for e in events if e[2] not in trace_reduce.STAGES])
+    assert bare["idle_gaps"] == [[trace_reduce.LUMP, pytest.approx(8_500e-9)]]
+    run = {"trace": got}
+    assert layer_metrics.read("batch.launch_idle_share", run) == pytest.approx(7.0)
+    assert layer_metrics.read("single.launch_idle_share", {"trace": bare}) is None
+    assert layer_metrics.read("single.launch_idle_share", {"trace": {}}) is None
+
+
+def test_stage_names_and_idle_states_are_the_program_s():
+    from predictionio_tpu.obs import tracing
+    from predictionio_tpu.utils import profiling
+
+    assert trace_reduce.STAGES == tracing.STAGES
+    assert trace_reduce.IDLE_STATES == profiling.IDLE_STATES
+    assert trace_reduce.HANDLER_STAGES == profiling._HANDLER_STAGES
+
+
+def test_idle_states_agree_with_the_program_s_summary_on_a_recorded_trace():
+    """PR 25's recorded v5e trace of one f32 and one int8 batch: given the
+    window `profiling.summarize` takes (first event's start to the last
+    one's end), the harness's own reduction reads the same states, busy
+    time and scopes."""
+    from predictionio_tpu.utils import profiling
+
+    trace_dir = os.path.join(ROOT, "tests", "data", "stages_trace")
+    events = trace_reduce.load_events(trace_dir)
+    assert {e[2] for e in events if e[0].startswith("/host:")} <= set(trace_reduce.STAGES)
+    spans = [(e[3], e[3] + e[4]) for e in events]
+    lo, hi = min(s for s, _e in spans), max(e for _s, e in spans)
+    events.append(("/host:CPU", "python", trace_reduce.WINDOW_EVENT, lo, hi - lo, ""))
+    got = trace_reduce.reduce(events, ["jit__gather_top_k_dot_xla", "jit__top_k_dot_quant_xla"])
+    summary = profiling.summarize(trace_dir)
+    assert got["window_s"] == pytest.approx(summary["window_s"], abs=1e-6)
+    assert got["busy_s"] == pytest.approx(summary["device"]["busy_s"], abs=1e-6)
+    gaps = dict(got["idle_gaps"])
+    assert set(gaps) == set(summary["idle"])
+    for state, seconds in summary["idle"].items():
+        assert gaps[state] == pytest.approx(seconds, abs=1e-6), state
+    assert gaps["launch"] > 0 and gaps["device_get"] > 0
+    assert sum(gaps.values()) == pytest.approx(got["window_s"] - got["busy_s"], abs=1e-6)
+    # the longest operations under the scope the summary's by_scope sums
+    top_k = [seconds for name, seconds in got["device_ops"] if name.startswith("top_k/")]
+    assert got["device_ops"][0][0] == "top_k/fusion.1 f32[64,16]"
+    assert sum(top_k) == pytest.approx(summary["device"]["by_scope"]["top_k"], rel=0.02)
+    assert sum(top_k) <= summary["device"]["by_scope"]["top_k"] + 1e-9
+    assert all(len(name) <= 80 for name, _ in got["device_ops"])
+    assert sum(got["module_runs"].values()) == 2
+
+
 def test_trace_reduce_on_a_recorded_trace():
     """100 events of a v5e run of `serve-pool-batch` (PR 24): the first
     0.2 s of its traced window."""
@@ -311,11 +578,15 @@ def _exact_answers(users, items, idx, num=10):
     return [(top_idx[q], top[q]) for q in range(len(idx))]
 
 
-@pytest.mark.parametrize("config", CONFIGS)
+@pytest.mark.parametrize(
+    "config", [c for c in CONFIGS if rules.reference_module(BENCH, ROOT, c) == "reference"]
+)
 @pytest.mark.parametrize("seed", [1, 2, 3])
 def test_control_comes_out_not_correct(config, seed):
     """The reference one precision step down, in the program's place, fails
-    the configuration's limits; the exact answers pass them."""
+    the configuration's limits; the exact answers pass them. For the
+    configurations `reference.py` judges: one that names another reference
+    brings `test_control_<module>.py` with a test of this name."""
     cfg = _config(config)
     users, items = _tables(seed)
     idx = np.arange(128)

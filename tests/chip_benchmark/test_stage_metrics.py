@@ -16,14 +16,16 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 CHIP = os.path.join(ROOT, "benchmarks", "chip")
-sys.path[:0] = [CHIP, ROOT]
+sys.path[:0] = [CHIP, ROOT, os.path.dirname(os.path.abspath(__file__))]
 
 import layer_metrics  # noqa: E402
+import manifest_rules as rules  # noqa: E402
 import modelstore  # noqa: E402
 import run as chip_run  # noqa: E402
 
-with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
-    BENCH = json.load(_f)
+BENCH = rules.load_bench(ROOT)
+with open(os.path.join(os.path.dirname(__file__), "data", "accepted_per_layer.json")) as _f:
+    ACCEPTED_PER_LAYER = json.load(_f)["names"]
 
 STAGE_METRICS = {
     "decode_ms": "engine.decode", "respond_ms": "http.respond",
@@ -33,6 +35,9 @@ STAGE_METRICS = {
     "materialize_ms": "predict.materialize",
 }
 COUNTER_METRICS = ("host_cpu_share", "compiles_in_window")
+#: PR 27's: the window PR 26 read by hand; `launch_idle_share` needs a device
+#: plane in the trace and is read on recorded traces in test_harness.py
+WINDOW_METRICS = {"window_ms": ("ms", "program_span"), "window_waited_share": ("%", "program_counter")}
 HANDLER_STAGES = (
     "http.read", "http.admit", "engine.decode", "engine.submit",
     "engine.await", "engine.serve", "http.respond", "http.encode",
@@ -98,17 +103,64 @@ def test_counter_metrics_on_hand_made_runs_and_on_a_program_without_them():
 
 
 def test_new_entries_only_follow_the_accepted_ones():
+    """The accepted names, in their order, are a prefix of the list; what a
+    later PR appends after them is free (the made-up addition of
+    test_harness.py shows it, and an insertion failing)."""
+    rules.check_per_layer_order(BENCH, ROOT, ACCEPTED_PER_LAYER)
     names = [m["name"] for m in BENCH["per_layer"]]
-    first_new = names.index("single.decode_ms")
-    assert first_new == 23  # the accepted benchmark's entries come first
-    assert names[first_new:] == [
+    # PR 24's 23, PR 25's 21 with setup_compile_s their last, PR 27's six
+    assert ACCEPTED_PER_LAYER[23:44] == [
         *_new_metrics("single"), *_new_metrics("batch"), "setup_compile_s",
     ]
-    setup = BENCH["per_layer"][-1]
-    assert setup["moves"] == "setup_s" and setup["unit"] == "s"
-    assert sorted(setup["workloads"]) == sorted(
-        w["name"] for w in BENCH["workloads"]
-    )
+    assert ACCEPTED_PER_LAYER[44:] == [
+        f"{prefix}.{base}" for prefix in ("single", "batch")
+        for base in (*WINDOW_METRICS, "launch_idle_share")
+    ]
+    assert len(ACCEPTED_PER_LAYER) == 50 <= len(names)
+
+
+@pytest.mark.parametrize("base", [*WINDOW_METRICS, "launch_idle_share"])
+def test_window_and_launch_metrics_and_their_entries(base):
+    from predictionio_tpu.obs import tracing
+
+    with open(os.path.join(CHIP, "metrics", base + ".json")) as f:
+        spec = json.load(f)
+    unit, source = WINDOW_METRICS.get(base, ("%", "device_trace"))
+    layer = "predict" if base == "launch_idle_share" else "micro-batcher"
+    for prefix, moves in (("single", "query_p90_ms"), ("batch", "queries_per_s")):
+        entry = next(m for m in BENCH["per_layer"] if m["name"] == f"{prefix}.{base}")
+        sibling = next(m for m in BENCH["per_layer"] if m["name"] == f"{prefix}.queue_wait_ms")
+        assert (entry["moves"], entry["workloads"]) == (moves, sibling["workloads"])
+        assert (entry["unit"], entry["source"], entry["layer"]) == (unit, source, layer)
+        assert entry["better"] == "lower"
+
+    def snapshot(batches, waited, count, total):
+        return {
+            "pio_batches_total": {"samples": [
+                {"labels": {"batcher": "a"}, "value": batches - 10},
+                {"labels": {"batcher": "b"}, "value": 10},
+            ]},
+            "pio_batch_windows_waited_total": {"samples": [
+                {"labels": {"batcher": "a"}, "value": waited},
+            ]},
+            "pio_stage_seconds": {"samples": [
+                {"labels": {"stage": tracing.BATCH_WINDOW}, "count": count, "sum": total},
+                {"labels": {"stage": tracing.BATCH_SETTLE}, "count": count, "sum": 9.0},
+            ]},
+        }
+
+    run = {
+        "before": snapshot(100, 50, 100, 0.25), "after": snapshot(1100, 55, 1100, 0.27),
+        "traffic": {}, "trace": {},
+    }
+    want = {"window_ms": 0.02, "window_waited_share": 0.5, "launch_idle_share": None}
+    assert layer_metrics.read(f"single.{base}", run) == pytest.approx(want[base])
+    if base == "launch_idle_share":
+        assert spec == {**spec, "reader": "idle_state_share", "state": "launch"}
+        run["trace"] = {"window_s": 4.0, "idle_gaps": [["launch", 1.5], ["no_request", 0.5]]}
+        assert layer_metrics.read(f"batch.{base}", run) == pytest.approx(37.5)
+    # a program older than the stage and the counter: nothing, never a 0
+    assert layer_metrics.read(f"batch.{base}", {"before": {}, "after": {}, "traffic": {}, "trace": {}}) is None
 
 
 def _stage_counts(registry):
@@ -184,9 +236,13 @@ def test_traced_rehearsal_reports_every_new_metric(cell, prefix):
     )
     assert result["correct"] is True, result["compared"]
     metrics = result["metrics"]
-    for name in (*_new_metrics(prefix), "setup_compile_s"):
+    window = [f"{prefix}.{base}" for base in WINDOW_METRICS]
+    for name in (*_new_metrics(prefix), "setup_compile_s", *window):
         assert name in metrics, name
         assert metrics[name]["value"] >= 0
+    assert metrics[f"{prefix}.window_waited_share"]["value"] <= 100
+    # the CPU's trace has no device plane: no share of the device's idle time
+    assert f"{prefix}.launch_idle_share" not in metrics
     assert metrics[f"{prefix}.compiles_in_window"]["value"] == 0
     assert 0 < metrics[f"{prefix}.host_cpu_share"]["value"]
     # the stages tile the request: what the server times from outside is
