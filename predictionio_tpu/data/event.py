@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import datetime as _dt
+import functools
 import uuid
 from typing import Any, Mapping
 
@@ -68,7 +69,10 @@ class Event:
         validate_event(self)
 
     def with_id(self, event_id: str | None = None) -> "Event":
-        """Return a copy carrying a concrete event id (UUID4 by default)."""
+        """Return a copy carrying a concrete event id (UUID4 by default);
+        the event itself (it is immutable) where it carries that id."""
+        if event_id is not None and event_id == self.event_id:
+            return self
         return dataclasses.replace(
             self, event_id=event_id or uuid.uuid4().hex
         )
@@ -134,6 +138,35 @@ class Event:
         )
 
 
+@functools.lru_cache(maxsize=4096)
+def _check_names(
+    event: str, entity_type: str, target_entity_type: str | None
+) -> None:
+    """Reserved prefixes (Event.scala:120-141). The names of a stream
+    repeat, so the verdict on a triple is kept (a refusal is raised anew
+    every time: an exception is never cached)."""
+    if event.startswith("$") and event not in SPECIAL_EVENTS:
+        raise EventValidationError(
+            f"{event} is not a supported reserved event name."
+        )
+    if event.startswith("pio_"):
+        raise EventValidationError(
+            f"{event} is not a supported reserved event name."
+        )
+    for who, etype in (
+        ("entityType", entity_type),
+        ("targetEntityType", target_entity_type),
+    ):
+        if (
+            etype is not None
+            and etype.startswith("pio_")
+            and etype not in BUILTIN_ENTITY_TYPES
+        ):
+            raise EventValidationError(
+                f"{etype} is not a supported reserved {who}."
+            )
+
+
 def validate_event(e: Event) -> None:
     """Enforce the reference's event rules (Event.scala:109-164)."""
     if not e.event:
@@ -153,27 +186,7 @@ def validate_event(e: Event) -> None:
             "targetEntityType and targetEntityId must be specified together."
         )
 
-    # Reserved prefixes (Event.scala:120-141)
-    if e.event.startswith("$") and e.event not in SPECIAL_EVENTS:
-        raise EventValidationError(
-            f"{e.event} is not a supported reserved event name."
-        )
-    if e.event.startswith("pio_"):
-        raise EventValidationError(
-            f"{e.event} is not a supported reserved event name."
-        )
-    for who, etype in (
-        ("entityType", e.entity_type),
-        ("targetEntityType", e.target_entity_type),
-    ):
-        if (
-            etype is not None
-            and etype.startswith("pio_")
-            and etype not in BUILTIN_ENTITY_TYPES
-        ):
-            raise EventValidationError(
-                f"{etype} is not a supported reserved {who}."
-            )
+    _check_names(e.event, e.entity_type, e.target_entity_type)
     for key in e.properties:
         if key.startswith("pio_"):
             raise EventValidationError(

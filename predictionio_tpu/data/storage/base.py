@@ -405,6 +405,21 @@ class EventsBackend(abc.ABC):
         must be absent, a string = must match.
         """
 
+    def entity_version(
+        self,
+        app_id: int,
+        channel_id: int | None,
+        entity_type: str,
+        entity_id: str,
+    ) -> int | None:
+        """A number that changes with every insert or delete of an event
+        of this entity that has returned, or ``None`` where the backend
+        keeps none. A serve-time reader may keep what it derived from an
+        entity's events beside the version it read and use it again only
+        while the version reads the same: no write is missed, no time
+        bound is involved. ``None`` means: read the events every time."""
+        return None
+
     def aggregate_properties(
         self,
         app_id: int,

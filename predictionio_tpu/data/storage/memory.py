@@ -10,6 +10,7 @@ from __future__ import annotations
 import dataclasses
 import datetime as _dt
 import itertools
+import operator
 import threading
 import uuid
 from typing import Iterator, Sequence
@@ -275,19 +276,52 @@ class MemoryModels(ModelsBackend):
         return sorted(self._models)
 
 
+_EVENT_TIME = operator.attrgetter("event_time")
+
+
+class _EventTable:
+    """One (app, channel): the events by id, and the same events by
+    entity, each entity's in insertion order, with the version of the
+    entity's last change. Mutated only under the backend's lock."""
+
+    __slots__ = ("events", "by_entity", "versions")
+
+    def __init__(self):
+        self.events: dict[str, Event] = {}
+        self.by_entity: dict[tuple[str, str], dict[str, Event]] = {}
+        self.versions: dict[tuple[str, str], int] = {}
+
+    def drop(self, event: Event, version: int) -> None:
+        del self.events[event.event_id]
+        key = (event.entity_type, event.entity_id)
+        bucket = self.by_entity[key]
+        del bucket[event.event_id]
+        if not bucket:
+            del self.by_entity[key]
+        self.versions[key] = version
+
+
 class MemoryEvents(EventsBackend):
-    """Per-(app, channel) ordered event lists behind one lock."""
+    """Per-(app, channel) events behind one lock, indexed by id and by
+    entity: a read that names an entity (`find(entity_type=, entity_id=)`,
+    the serve-time ``find_by_entity``) looks at that entity's events
+    only, and `insert` / `insert_batch` / `delete` keep the index, so a
+    write that has returned is in the next read."""
 
     def __init__(self, config=None):
         self._lock = threading.Lock()
-        self._store: dict[tuple[int, int | None], dict[str, Event]] = {}
+        self._store: dict[tuple[int, int | None], _EventTable] = {}
+        # versions never repeat, across entities and dropped tables
+        self._clock = itertools.count(1)
 
     def _key(self, app_id: int, channel_id: int | None):
         return (app_id, channel_id)
 
     def init(self, app_id: int, channel_id: int | None = None) -> bool:
         with self._lock:
-            self._store.setdefault(self._key(app_id, channel_id), {})
+            self._store.setdefault(
+                self._key(app_id, channel_id), _EventTable()
+            )
             return True
 
     def remove(self, app_id: int, channel_id: int | None = None) -> bool:
@@ -303,25 +337,71 @@ class MemoryEvents(EventsBackend):
     def insert(
         self, event: Event, app_id: int, channel_id: int | None = None
     ) -> str:
-        stamped = event.with_id(event.event_id)
+        return self.insert_batch([event], app_id, channel_id)[0]
+
+    def insert_batch(
+        self,
+        events: Sequence[Event],
+        app_id: int,
+        channel_id: int | None = None,
+    ) -> list[str]:
+        stamped = [e.with_id(e.event_id) for e in events]
         with self._lock:
-            table = self._store.setdefault(self._key(app_id, channel_id), {})
-            table[stamped.event_id] = stamped
-        return stamped.event_id
+            table = self._store.setdefault(
+                self._key(app_id, channel_id), _EventTable()
+            )
+            version = next(self._clock)
+            # a bulk load is millions of these, and a run of one entity's
+            # events shares its bucket
+            by_id, by_entity = table.events, table.by_entity
+            versions = table.versions
+            last_type = last_id = bucket = None
+            for e in stamped:
+                event_id = e.event_id
+                if event_id in by_id:
+                    table.drop(by_id[event_id], version)
+                    last_id = None  # the drop may have emptied the bucket
+                by_id[event_id] = e
+                # the same string objects again: the entity of the event
+                # before (anything else looks its bucket up)
+                if e.entity_id is not last_id or e.entity_type is not last_type:
+                    last_type, last_id = e.entity_type, e.entity_id
+                    entity = (last_type, last_id)
+                    bucket = by_entity.get(entity)
+                    if bucket is None:
+                        bucket = by_entity[entity] = {}
+                    versions[entity] = version
+                bucket[event_id] = e
+        return [e.event_id for e in stamped]
 
     def get(
         self, event_id: str, app_id: int, channel_id: int | None = None
     ) -> Event | None:
-        return self._store.get(self._key(app_id, channel_id), {}).get(
-            event_id
-        )
+        table = self._store.get(self._key(app_id, channel_id))
+        return table.events.get(event_id) if table else None
 
     def delete(
         self, event_id: str, app_id: int, channel_id: int | None = None
     ) -> bool:
         with self._lock:
-            table = self._store.get(self._key(app_id, channel_id), {})
-            return table.pop(event_id, None) is not None
+            table = self._store.get(self._key(app_id, channel_id))
+            event = table.events.get(event_id) if table else None
+            if event is None:
+                return False
+            table.drop(event, next(self._clock))
+            return True
+
+    def entity_version(
+        self,
+        app_id: int,
+        channel_id: int | None,
+        entity_type: str,
+        entity_id: str,
+    ) -> int:
+        table = self._store.get(self._key(app_id, channel_id))
+        if table is None:
+            return 0
+        return table.versions.get((entity_type, entity_id), 0)
 
     def find(
         self,
@@ -338,10 +418,18 @@ class MemoryEvents(EventsBackend):
         reversed: bool = False,
     ) -> Iterator[Event]:
         with self._lock:
-            events = list(
-                self._store.get(self._key(app_id, channel_id), {}).values()
-            )
-        events.sort(key=lambda e: e.event_time, reverse=reversed)
+            table = self._store.get(self._key(app_id, channel_id))
+            if table is None:
+                events = []
+            elif entity_type is not None and entity_id is not None:
+                events = list(
+                    table.by_entity.get((entity_type, entity_id), {}).values()
+                )
+                # the bucket's events are the entity's: nothing left to test
+                entity_type = entity_id = None
+            else:
+                events = list(table.events.values())
+        events.sort(key=_EVENT_TIME, reverse=reversed)
         # Naive bounds are UTC by convention (same rule as Event.__post_init__)
         if start_time is not None and start_time.tzinfo is None:
             start_time = start_time.replace(tzinfo=_dt.timezone.utc)

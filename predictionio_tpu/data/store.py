@@ -28,6 +28,48 @@ class EventStoreError(RuntimeError):
     pass
 
 
+class EntityReader:
+    """Serve-time reads of one app's entities, the app resolved once
+    (`EventStore.entity_reader`): what a predict path calls many times a
+    batch."""
+
+    __slots__ = ("_events", "_app_id", "_channel_id")
+
+    def __init__(self, events, app_id: int, channel_id: int | None):
+        self._events = events
+        self._app_id = app_id
+        self._channel_id = channel_id
+
+    def version(self, entity_type: str, entity_id: str) -> int | None:
+        """`EventsBackend.entity_version` of the entity: equal to an
+        earlier reading only if no write to the entity returned in
+        between; None where the backend keeps none."""
+        return self._events.entity_version(
+            self._app_id, self._channel_id, entity_type, entity_id
+        )
+
+    def find(
+        self,
+        entity_type: str,
+        entity_id: str,
+        event_names: Sequence[str] | None = None,
+        limit: int | None = None,
+        latest: bool = True,
+    ) -> list[Event]:
+        """The entity's events, latest first (`find_by_entity`)."""
+        return list(
+            self._events.find(
+                self._app_id,
+                self._channel_id,
+                entity_type=entity_type,
+                entity_id=entity_id,
+                event_names=event_names,
+                limit=limit,
+                reversed=latest,
+            )
+        )
+
+
 class EventStore:
     """App-name-addressed event reads over the configured storage."""
 
@@ -162,6 +204,14 @@ class EventStore:
         return EntityMap(props)
 
     # -- serve-time (reference LEventStore) -------------------------------
+    def entity_reader(
+        self, app_name: str, channel_name: str | None = None
+    ) -> EntityReader:
+        app_id, channel_id = self._resolve(app_name, channel_name)
+        return EntityReader(
+            self._storage.get_events(), app_id, channel_id
+        )
+
     def find_by_entity(
         self,
         app_name: str,

@@ -3,20 +3,47 @@
 Capability parity with the reference
 ``examples/scala-parallel-ecommercerecommendation`` (train-with-rate-event
 variant, ECommAlgorithm.scala): implicit ALS over view/buy events, and a
-predict path that applies live business rules — exclude items the user
-has already seen (read from the event store *at predict time*, the
-LEventStore pattern), exclude globally unavailable items (latest
-``$set`` of the ``constraint`` entity ``unavailableItems``), and apply
-category / whiteList / blackList filters. Unknown users fall back to
-popularity (interaction-count) ranking.
+predict path that applies live business rules, read from the event store
+*at predict time* (the LEventStore pattern): the user's seen items, the
+globally unavailable items (latest ``$set`` of the ``constraint`` entity
+``unavailableItems``), and the query's ``categories`` / ``whiteList`` /
+``blackList``.
+
+The rules act BEFORE the top-k, on the device. A query's candidates are
+the items that are not seen, not unavailable, not blacklisted, on the
+whiteList if it has one and in one of its categories if it names any;
+the answer is the top ``num`` of the candidates (the reference's
+``isCandidateItem`` + ``getTopN``), never a filter over a global top-k,
+which at a real catalog answers a one-category query short or empty.
+Three branches, mixed freely in one batch:
+
+* known user: ``U[u] . V[i]``, candidates with a score above 0;
+* unknown user with recent views (the latest 10 ``similar_events`` whose
+  items the model knows): the summed cosine to those items, above 0;
+* unknown user with none: the item's popularity (interaction count).
+
+Fewer candidates than ``num`` give a shorter answer. Serving is
+two-phase (`batch_predict_launch` / `batch_predict_collect`): the host
+resolves each query to compact operands (``predict.prep``, the store
+lookups inside it as ``predict.rules``), one jitted step per batch forms
+the mask, scores and takes the top-k
+(:func:`predictionio_tpu.ops.similarity.rules_top_k`), and the collect
+phase maps ids back. The event store answers the lookups from its entity
+index, and what was derived from a user's events is used again only
+while the store reports the entity unchanged
+(`EventsBackend.entity_version`), so a write that has returned is in the
+next answer.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import logging
 
 import jax
+import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from predictionio_tpu.core import (
     Algorithm,
@@ -30,11 +57,21 @@ from predictionio_tpu.core import (
 from predictionio_tpu.core.controller import SanityCheck
 from predictionio_tpu.data.eventframe import Interactions
 from predictionio_tpu.data.store import EventStore
+from predictionio_tpu.obs import tracing
 from predictionio_tpu.ops import similarity
 from predictionio_tpu.ops.als import train_als
 from predictionio_tpu.parallel import partition
 from predictionio_tpu.parallel.mesh import ComputeContext
 from predictionio_tpu.utils.bimap import BiMap
+
+logger = logging.getLogger(__name__)
+
+#: the unknown user's branch looks at this many of their latest views
+#: (reference ECommAlgorithm.predictSimilar: ``limit = Some(10)``)
+RECENT_VIEWS = 10
+#: users whose resolved seen items a tenant keeps beside the store's
+#: version of them; past it the oldest half goes
+_SEEN_CACHE_USERS = 1 << 18
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,6 +114,7 @@ class ECommDataSource(DataSource):
 class ECommAlgorithmParams(Params):
     app_name: str = "MyApp"          # for serve-time event reads
     seen_events: tuple[str, ...] = ("view", "buy")
+    similar_events: tuple[str, ...] = ("view",)
     unseen_only: bool = True
     rank: int = 16
     num_iterations: int = 10
@@ -88,6 +126,30 @@ class ECommAlgorithmParams(Params):
 
 
 @dataclasses.dataclass
+class StagedRules:
+    """What `stage_model` keeps on the device beside the factors, one
+    entry per row of the (padded) item table; charged to the tenant by
+    ``quantize.model_resident_bytes`` through its array fields."""
+
+    categories: jax.Array     # [C, rows] int32 category ids, -1 = none
+    unavailable: jax.Array    # [rows] bool: phantom rows + the constraint
+    inv_norm: jax.Array       # [rows] f32
+    popularity: jax.Array     # [rows] f32
+    category_ids: dict        # category name -> id
+    #: version and event id of the ``$set`` `unavailable` was built from
+    constraint: tuple = (None, None)
+    #: user -> (the store's version of the user, seen item rows)
+    seen: dict = dataclasses.field(default_factory=dict)
+
+    @property
+    def catalog(self) -> similarity.CatalogRules:
+        return similarity.CatalogRules(
+            self.categories, self.unavailable, self.inv_norm,
+            self.popularity,
+        )
+
+
+@dataclasses.dataclass
 class ECommModel:
     # host np.ndarray after train, device jax.Array after staging
     user_factors: np.ndarray | jax.Array
@@ -96,10 +158,109 @@ class ECommModel:
     item_map: BiMap
     item_categories: dict[str, list[str]]
     popularity: np.ndarray  # [I] interaction counts (cold-user fallback)
-    #: True on phantom padding rows of a model-sharded catalog (None
-    #: when unpadded) — excluded from the device top-k. Optional so
-    #: pre-sharding pickled models load unchanged.
+    #: True on phantom padding rows of the staged item table (None when
+    #: unpadded). Optional so pre-sharding pickled models load unchanged.
     item_phantom_mask: "jax.Array | None" = None
+    #: the app whose events the rules read at serve time; None = the
+    #: algorithm's ``app_name``. A pool's tenants share one set of engine
+    #: params, so each tenant's model names its own app.
+    app_name: "str | None" = None
+    #: categories already encoded, in place of ``item_categories``: names,
+    #: and a [C, I] int32 array of indices into them (-1 = none)
+    category_names: "tuple[str, ...] | None" = None
+    category_rows: "np.ndarray | jax.Array | None" = None
+    #: set by `stage_model` (or on first use of an unstaged model)
+    rules: "StagedRules | None" = None
+
+
+@jax.jit
+def _inverse_norms(items):
+    norm = jnp.linalg.norm(items, axis=1)
+    return jnp.where(norm > 0, 1.0 / norm, 0.0).astype(jnp.float32)
+
+
+def _pad_rows(x, rows: int, value=0):
+    """``x`` with its first axis padded to ``rows`` (host or device)."""
+    short = rows - x.shape[0]
+    if short <= 0:
+        return x
+    widths = [(0, short)] + [(0, 0)] * (x.ndim - 1)
+    if isinstance(x, jax.Array):
+        return jnp.pad(x, widths, constant_values=value)
+    return np.pad(np.asarray(x), widths, constant_values=value)
+
+
+def _encode_categories(model: ECommModel):
+    """``(name -> id, [C, I] int32 host or device array)``."""
+    if model.category_rows is not None:
+        names = model.category_names or ()
+        return {n: i for i, n in enumerate(names)}, model.category_rows
+    ids: dict[str, int] = {}
+    per_item = []
+    for item, cats in model.item_categories.items():
+        row = model.item_map.get(item, -1)
+        if row >= 0 and cats:
+            per_item.append(
+                (row, [ids.setdefault(c, len(ids)) for c in cats])
+            )
+    width = max((len(c) for _, c in per_item), default=1)
+    rows = np.full((width, len(model.item_map)), -1, np.int32)
+    for row, cats in per_item:
+        rows[: len(cats), row] = cats
+    return ids, rows
+
+
+def _bucket(n: int) -> int:
+    return 1 << max(0, n - 1).bit_length()
+
+
+class _Counters:
+    """The template's counters in one registry."""
+
+    _by_registry: dict = {}
+
+    def __init__(self, registry):
+        self.queries = registry.counter(
+            "pio_ecomm_queries_total",
+            "E-commerce queries by branch: known user, similar to the "
+            "unknown user's recent views, popular",
+            ("branch",),
+        )
+        self.filtered = registry.counter(
+            "pio_ecomm_filtered_queries_total",
+            "E-commerce queries that carried the rule",
+            ("rule",),
+        )
+        self.excluded = registry.counter(
+            "pio_ecomm_excluded_items_total",
+            "Item entries of the packed seen/black/white lists sent to "
+            "the device",
+        )
+        self.lookups = registry.counter(
+            "pio_ecomm_rule_lookups_total",
+            "Entities the predict path asked the event store about",
+        )
+        self.short = registry.counter(
+            "pio_ecomm_short_answers_total",
+            "E-commerce answers with fewer items than the query's num",
+        )
+        self.branch = {
+            similarity.KNOWN: self.queries.labels("known"),
+            similarity.SIMILAR: self.queries.labels("similar"),
+            similarity.POPULAR: self.queries.labels("popular"),
+        }
+        self.rule = {
+            r: self.filtered.labels(r)
+            for r in ("categories", "whiteList", "blackList")
+        }
+
+    @classmethod
+    def of(cls, registry) -> "_Counters":
+        found = cls._by_registry.get(id(registry))
+        if found is None or found[0] is not registry:
+            found = (registry, cls(registry))
+            cls._by_registry[id(registry)] = found
+        return found[1]
 
 
 class ECommAlgorithm(Algorithm):
@@ -134,112 +295,277 @@ class ECommAlgorithm(Algorithm):
             item_map=inter.target_map,
             item_categories=pd.item_categories,
             popularity=popularity,
+            app_name=p.app_name,
         )
 
     def stage_model(self, ctx, model: ECommModel) -> ECommModel:
         """Factors commit through the sharded-catalog machinery the
         other ALS templates use (row-sharded over a model mesh axis,
         phantom padding rows masked — the ``Algorithm.stage_model``
-        sharded-model contract); popularity stays host — the cold-user
-        fallback ranks on the CPU without a device trip and indexes
-        only real items."""
+        sharded-model contract), the item table padded to a whole number
+        of ``similarity.CATALOG_ROW_MULTIPLE`` rows. Beside them, one
+        entry per item row: category ids, 1/norm, popularity and the
+        unavailable bitmap (phantom rows now; the ``constraint`` entity's
+        items from the first predict on). ``model.popularity`` itself
+        stays as trained."""
+        n_items = len(model.item_map)
+        multiple = np.lcm(
+            similarity.CATALOG_ROW_MULTIPLE, max(ctx.model_parallelism, 1)
+        )
+        rows = -(-n_items // multiple) * multiple
         user_f, _ = partition.stage_factor_matrix(
             ctx, model.user_factors, n_real=len(model.user_map)
         )
         item_f, item_mask = partition.stage_factor_matrix(
-            ctx, model.item_factors, n_real=len(model.item_map)
+            ctx, _pad_rows(model.item_factors, rows), n_real=n_items
         )
         return dataclasses.replace(
             model,
             user_factors=user_f,
             item_factors=item_f,
             item_phantom_mask=item_mask,
+            rules=self._stage_rules(
+                model, item_f, NamedSharding(ctx.mesh, PartitionSpec())
+            ),
+        )
+
+    def _stage_rules(self, model, item_f, sharding=None) -> StagedRules:
+        rows, n_items = item_f.shape[0], len(model.item_map)
+        ids, categories = _encode_categories(model)
+        put = lambda x: jax.device_put(x, sharding)  # noqa: E731
+        return StagedRules(
+            categories=put(_pad_rows(categories.T, rows, -1).T),
+            unavailable=put(np.arange(rows) >= n_items),
+            inv_norm=put(_inverse_norms(item_f)),
+            popularity=put(
+                _pad_rows(model.popularity, rows).astype(np.float32)
+            ),
+            category_ids=ids,
         )
 
     # -- serve-time business rules (reference ECommAlgorithm.predict) -----
-    def _seen_items(self, user: str) -> set[str]:
-        if not self.params.unseen_only:
-            return set()
+    def _reader(self, model: ECommModel):
+        """The store's reader of this model's app, or None where the
+        store cannot be reached: the rules that need it are then left
+        out, as the reference serves on when its event read times out."""
         try:
-            events = EventStore().find_by_entity(
-                self.params.app_name,
-                entity_type="user",
-                entity_id=user,
-                event_names=list(self.params.seen_events),
+            return EventStore().entity_reader(
+                model.app_name or self.params.app_name
             )
-        except Exception:  # store unavailable → serve without the rule
-            return set()
-        return {
-            e.target_entity_id for e in events if e.target_entity_id
-        }
+        except Exception as e:  # noqa: BLE001 - serve without the rules
+            logger.debug("event store unavailable to the rules: %s", e)
+            return None
 
-    def _unavailable_items(self) -> set[str]:
-        """Latest ``$set`` of constraint entity ``unavailableItems``
-        (reference reads it per-predict so ops can update availability
-        without retraining)."""
-        try:
-            events = EventStore().find_by_entity(
-                self.params.app_name,
-                entity_type="constraint",
-                entity_id="unavailableItems",
-                event_names=["$set"],
-                limit=1,
-                latest=True,
+    def _refresh_unavailable(self, model, rules: StagedRules, reader):
+        """`rules.unavailable` from the latest ``$set`` of
+        ``constraint/unavailableItems``: rebuilt only when the store
+        reports the entity changed, and then only if the latest event is
+        another one."""
+        version = reader.version("constraint", "unavailableItems")
+        if version is not None and version == rules.constraint[0]:
+            return
+        events = reader.find(
+            "constraint", "unavailableItems", event_names=["$set"], limit=1
+        )
+        event_id = events[0].event_id if events else ""
+        if event_id != rules.constraint[1]:
+            rows = rules.unavailable.shape[0]
+            bitmap = np.arange(rows) >= len(model.item_map)
+            if events:
+                found = np.fromiter(
+                    (
+                        model.item_map.get(str(i), -1)
+                        for i in events[0].properties.get("items") or ()
+                    ),
+                    np.int64,
+                )
+                bitmap[found[found >= 0]] = True
+            rules.unavailable = jax.device_put(
+                bitmap, rules.unavailable.sharding
             )
-        except Exception:
-            return set()
-        if not events:
-            return set()
-        return {
-            str(i) for i in events[0].properties.get("items") or []
-        }
+        rules.constraint = (version, event_id)
+
+    def _seen_rows(self, model, rules: StagedRules, reader, user: str):
+        """Item rows of the user's ``seen_events``, as the store has them
+        now."""
+        version = reader.version("user", user)
+        kept = rules.seen.get(user)
+        if kept is not None and version is not None and kept[0] == version:
+            return kept[1]
+        get = model.item_map.getter()
+        found = np.asarray(
+            [
+                get(e.target_entity_id, -1)
+                for e in reader.find(
+                    "user", user, event_names=self.params.seen_events
+                )
+            ],
+            np.int32,
+        )
+        found = found[found >= 0]
+        if version is not None:
+            if len(rules.seen) >= _SEEN_CACHE_USERS:
+                for old in list(rules.seen)[: _SEEN_CACHE_USERS // 2]:
+                    del rules.seen[old]
+            rules.seen[user] = (version, found)
+        return found
+
+    def _recent_rows(self, model, reader, user: str) -> list[int]:
+        """Rows of the items of the user's latest views that the model
+        knows, newest first."""
+        events = reader.find(
+            "user", user, event_names=self.params.similar_events,
+            limit=RECENT_VIEWS,
+        )
+        rows = (
+            model.item_map.get(e.target_entity_id, -1)
+            for e in events if e.target_entity_id
+        )
+        return [r for r in rows if r >= 0]
+
+    def _read_rules(self, model, rules, reader, users, mode, recent, seen):
+        """The batch's store lookups: the constraint, every user's seen
+        items, and an unknown user's recent views (which make the query
+        SIMILAR)."""
+        self._refresh_unavailable(model, rules, reader)
+        for i, user in enumerate(users):
+            if mode[i] != similarity.KNOWN:
+                views = self._recent_rows(model, reader, user)
+                if views:
+                    mode[i] = similarity.SIMILAR
+                    recent[i, : len(views)] = views
+            if self.params.unseen_only:
+                seen[i] = self._seen_rows(model, rules, reader, user)
 
     def predict(self, model: ECommModel, query: dict) -> dict:
-        user = str(query.get("user", ""))
-        num = int(query.get("num", 10))
-        user_idx = model.user_map.get(user, -1)
-        # the REAL catalog size — a model-sharded factor matrix carries
-        # phantom padding rows, masked from the top-k below
-        n_items = len(model.item_map)
-        if user_idx >= 0:
-            k = min(1 << max(0, (4 * num - 1)).bit_length(), n_items)
-            # fused on-device gather + score + top-k: uploads one index
-            scores, cand = similarity.gather_top_k_dot(
-                model.user_factors,
-                np.asarray([user_idx], np.int32),
-                model.item_factors,
-                k,
-                mask=getattr(model, "item_phantom_mask", None),
-            )
-            scores, cand = jax.device_get((scores, cand))  # parallel fetch
-            scores, cand = scores[0], cand[0]
-        else:
-            # cold user: popularity ranking (reference falls back to
-            # popular-items scoring)
-            order = np.argsort(-model.popularity)
-            cand = order[: min(4 * num, n_items)]
-            scores = model.popularity[cand]
+        return self.batch_predict(model, [query])[0]
 
-        seen = self._seen_items(user)
-        unavailable = self._unavailable_items()
-        categories = set(query.get("categories") or [])
-        white = set(query.get("whiteList") or [])
-        black = set(query.get("blackList") or [])
-        out = []
-        for score, ci in zip(scores, cand):
-            item = model.item_map.inverse(int(ci))
-            if item in seen or item in unavailable or item in black:
-                continue
-            if white and item not in white:
-                continue
-            if categories and not (
-                categories & set(model.item_categories.get(item, []))
-            ):
-                continue
-            out.append({"item": item, "score": float(score)})
-            if len(out) >= num:
-                break
-        return {"itemScores": out}
+    def batch_predict(self, model: ECommModel, queries) -> list[dict]:
+        if not queries:
+            return []
+        return self.batch_predict_collect(
+            model, self.batch_predict_launch(model, queries), queries
+        )
+
+    def batch_predict_launch(self, model: ECommModel, queries):
+        """Host prep + device enqueue, no barrier. Every query of the
+        batch becomes one row of compact operands: its branch, its user
+        row or recent views, its category ids, and one packed list of
+        item rows that is either what to leave out (seen + blackList) or,
+        with a whiteList, what alone may come back. Shapes are bucketed
+        (batch rows, top-k size and category slots to powers of two, the
+        packed lists by `similarity.list_capacity`)."""
+        if not queries:
+            return None
+        with tracing.stage(tracing.PREDICT_PREP):
+            if model.rules is None:  # an unstaged model (evaluation)
+                model.rules = self._stage_rules(
+                    model, jnp.asarray(model.item_factors)
+                )
+            rules = model.rules
+            counters = _Counters.of(tracing.bound_registry())
+            n_items = len(model.item_map)
+            nums = [int(q.get("num", 10)) for q in queries]
+            num = min(max(1, max(nums)), n_items)
+            num_bucket = min(_bucket(num), n_items)
+            batch = _bucket(len(queries))
+            users = [str(q.get("user", "")) for q in queries]
+            user_idx = np.zeros(batch, np.int32)
+            mode = np.full(batch, similarity.POPULAR, np.int32)
+            recent = np.full((batch, similarity.RECENT_SLOTS), -1, np.int32)
+            seen: list = [()] * len(queries)
+            for i, user in enumerate(users):
+                row = model.user_map.get(user, -1)
+                if row >= 0:
+                    user_idx[i], mode[i] = row, similarity.KNOWN
+            with tracing.stage(tracing.PREDICT_RULES):
+                reader = self._reader(model)
+                try:
+                    if reader is not None:
+                        self._read_rules(
+                            model, rules, reader, users, mode, recent, seen
+                        )
+                        counters.lookups.inc(1 + len(queries))
+                except Exception as e:  # noqa: BLE001 - serve on
+                    # as the reference serves on when its read times out
+                    logger.warning(
+                        "event store failed during the rules' lookups "
+                        "(%s: %s): the batch goes without what was not "
+                        "read", type(e).__name__, e,
+                    )
+            get = model.item_map.getter()
+            lists, allow = [], np.zeros(batch, np.bool_)
+            wanted = [q.get("categories") or () for q in queries]
+            q_cats = np.full(
+                (batch, _bucket(max(1, max(map(len, wanted))))),
+                similarity.NO_CATEGORY, np.int32,
+            )
+            for i, q in enumerate(queries):
+                black = [get(str(x), -1) for x in q.get("blackList") or ()]
+                white = q.get("whiteList") or ()
+                if black:
+                    counters.rule["blackList"].inc()
+                if white:
+                    counters.rule["whiteList"].inc()
+                    allow[i] = True
+                    out = set(black).union(seen[i])
+                    rows = [
+                        r for r in {get(str(x), -1) for x in white}
+                        if r >= 0 and r not in out
+                    ]
+                else:
+                    rows = [r for r in black if r >= 0]
+                    rows = np.concatenate([seen[i], rows]) if rows else seen[i]
+                lists.append(np.asarray(rows, np.int32))
+                if wanted[i]:
+                    counters.rule["categories"].inc()
+                    # a category the model does not know matches nothing
+                    q_cats[i, : len(wanted[i])] = [
+                        rules.category_ids.get(str(c), similarity.NO_CATEGORY - 1)
+                        for c in wanted[i]
+                    ]
+                counters.branch[int(mode[i])].inc()
+            list_rows, list_cols = similarity.pack_lists(lists)
+            counters.excluded.inc(sum(len(x) for x in lists))
+            operands = similarity.QueryRules(
+                mode, recent, q_cats, list_rows, list_cols, allow
+            )
+        with tracing.stage(tracing.PREDICT_ENQUEUE):
+            scores, items = similarity.rules_top_k(
+                model.user_factors, user_idx, model.item_factors,
+                num_bucket, rules.catalog, operands,
+            )
+        return scores, items, nums, counters
+
+    def batch_predict_collect(
+        self, model: ECommModel, handle, queries
+    ) -> list[dict]:
+        """Device barrier + per-query JSON: the slots that hold a
+        candidate (score above -inf), at most ``num`` of them."""
+        if handle is None:
+            return []
+        scores, items, nums, counters = handle
+        with tracing.stage(tracing.PREDICT_DEVICE_GET):
+            scores, items = jax.device_get((scores, items))
+        with tracing.stage(tracing.PREDICT_MATERIALIZE):
+            out, short = [], 0
+            inverse = model.item_map.inverse
+            filled = (scores > -np.inf).sum(axis=1)
+            for i, num in enumerate(nums):
+                n = min(num, int(filled[i]))
+                short += n < num
+                out.append({
+                    "itemScores": [
+                        {
+                            "item": inverse(int(items[i, j])),
+                            "score": float(scores[i, j]),
+                        }
+                        for j in range(n)
+                    ]
+                })
+            if short:
+                counters.short.inc(short)
+        return out
 
 
 def ecommerce_engine() -> Engine:

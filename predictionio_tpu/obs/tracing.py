@@ -562,6 +562,14 @@ STAGES = (
     PREDICT_DEVICE_GET, PREDICT_MATERIALIZE, BATCH_SETTLE,
 )
 
+#: stages that run INSIDE a stage of :data:`STAGES` (``predict.rules``:
+#: the e-commerce template's event-store lookups, inside
+#: ``predict.prep``). Observed and annotated like the others, but no part
+#: of the partition: the sums over :data:`STAGES` and the idle states
+#: built on them already hold their time
+PREDICT_RULES = "predict.rules"
+NESTED_STAGES = (PREDICT_RULES,)
+
 #: ``factory(name, **keywords)`` -> context manager that writes a host
 #: event into a running profiler's trace, and ``active()`` -> whether a
 #: profiler runs (a flag test; a stage builds no annotation while none
@@ -592,6 +600,13 @@ _bound_stages: contextvars.ContextVar[tuple[dict, dict] | None] = (
 )
 
 
+class _StageChildren(dict):
+    """The children of ``pio_stage_seconds`` by stage, and the registry
+    they belong to (`bound_registry`)."""
+
+    __slots__ = ("registry",)
+
+
 class StageSink:
     """``pio_stage_seconds{stage}`` of one registry (``None``: the
     process's), every child resolved here. A server builds one at
@@ -610,7 +625,10 @@ class StageSink:
             ("stage",),
             buckets=STAGE_BUCKETS,
         )
-        self._children = {name: family.labels(name) for name in STAGES}
+        self._children = _StageChildren(
+            (name, family.labels(name)) for name in STAGES + NESTED_STAGES
+        )
+        self._children.registry = registry
 
     def bind(self, **keywords) -> None:
         """Stages on this context observe here from now on, and their
@@ -621,6 +639,13 @@ class StageSink:
 #: where a stage observes on a context no server has bound (a model's
 #: predict called from an evaluation, a batcher built with no registry)
 _default_sink = StageSink(None)
+
+
+def bound_registry() -> MetricRegistry:
+    """The registry of the server whose sink this context bound (its
+    batcher's thread inside a predict, a handler's thread), else the
+    process's: where a model's own counters belong."""
+    return (_bound_stages.get() or (_default_sink._children,))[0].registry
 
 
 class _Stage:
@@ -661,7 +686,8 @@ class _Stage:
 
 
 def stage(name: str) -> _Stage:
-    """Context manager around one stage (a name of :data:`STAGES`) of a
+    """Context manager around one stage (a name of :data:`STAGES` or
+    :data:`NESTED_STAGES`) of a
     request, a post or a batch — never of one query inside a post or
     of one item. Always observes ``pio_stage_seconds{stage}``; while a
     profiler runs (whoever started it) it is an annotation of the same

@@ -13,10 +13,17 @@ lowering): a ``while_loop`` of (row-max, first-argmax-by-iota,
 sorted-insert) that runs only while some row's remaining block scores
 beat that row's kth-best — a warm best-list absorbs a random-order
 block in ~1-2 iterations. Memory is O(B·num) instead of the [B, I]
-intermediate (4 GB at B=1024 × I=1M). How it compares in time with the
-XLA matmul+top_k path is not measured on the installed JAX (ROADMAP
-S4); the dispatcher in :mod:`predictionio_tpu.ops.similarity` hands it
-only shapes whose intermediate reaches 512 MiB.
+intermediate (4 GB at B=1024 × I=1M). The dispatcher in
+:mod:`predictionio_tpu.ops.similarity` hands it only shapes whose
+intermediate reaches 512 MiB; the unmasked kernel has not been timed
+against XLA on the installed JAX (ROADMAP S6, R2).
+
+:func:`fused_rules_top_k` is the e-commerce template's step on the same
+merge: each block's scores go through the business rules in VMEM, the
+rows' seen / black / white lists arrive as scalar-prefetched entries
+sorted by item, and the item table is read with the items along the
+lanes. At [64, 4,162,560] × rank 16 on a v5e it took 5.4–6.0 ms against
+17.0 ms for XLA's unmasked top-k alone (PERF.md §6, PR 28).
 
 Replaces the reference's per-query Spark job
 (examples/scala-parallel-recommendation/custom-query/src/main/scala/
@@ -33,6 +40,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from predictionio_tpu.ops.similarity import rule_scores
 
 # -inf, not finfo.min: unrankable slots (over-masked rows, NaN factors)
 # must come back with score -inf exactly like the XLA lax.top_k path
@@ -268,3 +277,167 @@ def _bind_optional_refs(
     i += 1 if has_scale else 0
     return kernel(q_ref, items_ref, mask_ref, scale_ref, *rest[i:],
                   **kwargs)
+
+
+# -- business rules before the top-k -----------------------------------------
+
+
+def _rules_kernel(
+    ptr_ref,      # SMEM [n_blocks + 1]: block j's list entries are
+                  # packed_ref[ptr[j]:ptr[j + 1]]
+    packed_ref,   # SMEM [N]: query row * block + column inside the block
+    q_ref,        # [B, k] VMEM
+    items_ref,    # [k, IB] VMEM: the item block, items along the lanes
+    mode_ref,     # [B, 1] int32   \
+    allow_ref,    # [B, 1] int32    > per query
+    qcats_ref,    # [B, QC] int32  /
+    cats_ref,     # [C, IB] int32  \
+    unavail_ref,  # [1, IB] int32   \ per item
+    inv_ref,      # [1, IB] f32     /
+    pop_ref,      # [1, IB] f32    /
+    out_s_ref,    # [B, num]
+    out_i_ref,    # [B, num]
+    listed_ref,   # scratch [B, IB] int32: this block's listed items
+    best_s_ref,   # scratch [B, num] f32
+    best_i_ref,   # scratch [B, num] i32
+    *,
+    num: int,
+    block: int,
+    n_blocks: int,
+):
+    j = pl.program_id(0)
+
+    @pl.when(j == 0)
+    def _init():
+        best_s_ref[:] = jnp.full_like(best_s_ref, _NEG)
+        best_i_ref[:] = jnp.zeros_like(best_i_ref)
+
+    # the rows' lists (seen + blackList, or a whiteList) reach the block
+    # as the few entries that fall into it: one row of the plane is
+    # rewritten per entry, so the cost follows the entries, and no
+    # [B, I] mask is ever built
+    listed_ref[:] = jnp.zeros_like(listed_ref)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, block), dimension=1)
+
+    def mark(e, carry):
+        packed = packed_ref[e]
+        row = packed // block
+        at = pl.ds(row, 1)
+        listed_ref[at, :] = jnp.where(
+            lane == packed - row * block, 1, listed_ref[at, :]
+        )
+        return carry
+
+    jax.lax.fori_loop(ptr_ref[j], ptr_ref[j + 1], mark, 0)
+
+    dots = jax.lax.dot_general(
+        q_ref[:],
+        items_ref[:],
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )  # [B, IB]
+    scores = rule_scores(
+        dots, listed_ref[:], mode_ref[:], allow_ref[:], qcats_ref[:],
+        cats_ref[:], unavail_ref[:], inv_ref[:], pop_ref[:],
+    )
+    # a NaN popularity would make the merge loop spin (NaN != NaN)
+    scores = jnp.where(jnp.isnan(scores), _NEG, scores)
+    b = scores.shape[0]
+    gcols = jax.lax.broadcasted_iota(jnp.int32, (b, block), 1) + j * block
+    best_s, best_i = _merge_block(
+        scores, gcols, num, best_s_ref[:], best_i_ref[:]
+    )
+    best_s_ref[:] = best_s
+    best_i_ref[:] = best_i
+
+    @pl.when(j == n_blocks - 1)
+    def _emit():
+        out_s_ref[:] = best_s_ref[:]
+        out_i_ref[:] = best_i_ref[:]
+
+
+def _rules_block(batch: int, rank: int, n_categories: int) -> int:
+    """Items per grid step of the rules kernel: the scores, the rules'
+    planes and the merge loop's copies of a [B, block] tile, and the
+    double-buffered item rows, inside ~10 MB of VMEM."""
+    per_col = 4 * (8 * batch + 2 * (rank + n_categories + 3))
+    fit = max(128, (10 * 1024 * 1024) // per_col)
+    return min(1024, 1 << (fit.bit_length() - 1))
+
+
+def fused_rules_top_k(
+    queries: jax.Array,    # [B, k] f32
+    items: jax.Array,      # [I, k] f32, I a multiple of the block
+    num: int,
+    per_query,             # mode [B, 1], allow [B, 1], categories [B, QC]
+    per_item,              # categories [C, I], unavailable, 1/norm,
+                           # popularity [1, I]
+    list_rows: jax.Array,  # [N] int32 query row of each list entry
+    list_cols: jax.Array,  # [N] int32 item row of it, ascending; the
+                           # unused tail holds INT32_MAX
+    interpret: bool = False,
+) -> tuple[jax.Array, jax.Array]:
+    """The e-commerce step in one kernel: item blocks stream through VMEM
+    once, each block's scores go through
+    :func:`predictionio_tpu.ops.similarity.rule_scores` there, and the
+    running best-lists take what is left. Called inside a jitted step
+    (``similarity._rules_top_k``)."""
+    b, k = queries.shape
+    n_items = items.shape[0]
+    block = _rules_block(b, k, per_item[0].shape[0])
+    if n_items % block:
+        raise ValueError(
+            f"{n_items} item rows are no whole number of blocks of {block}"
+        )
+    n_blocks = n_items // block
+    num = min(num, n_items)
+    with jax.named_scope("mask"):
+        # block j's entries start where the sorted columns reach j * block
+        # (all compared at once: a binary search is a serial loop here)
+        starts = jnp.arange(n_blocks + 1, dtype=jnp.int32) * block
+        ptr = jnp.sum(
+            list_cols[None, :] < starts[:, None], axis=1, dtype=jnp.int32
+        )
+        packed = list_rows * block + list_cols % block
+    whole = lambda x: pl.BlockSpec(x.shape, lambda j, *_: (0, 0))  # noqa: E731
+    along = lambda x: pl.BlockSpec(  # noqa: E731
+        (x.shape[0], block), lambda j, *_: (0, j)
+    )
+    # items along the lanes: a [I, k] table with k under 128 lies that
+    # way on the TPU already (XLA keeps the long dimension minor), so the
+    # transpose is a relabelling; a [block, k] block would be copied out
+    # k -> 128 lanes wide first
+    items_t = items.T
+    per_query = [x.astype(jnp.int32) for x in per_query]
+    per_item = [
+        x if x.dtype == jnp.float32 else x.astype(jnp.int32)
+        for x in per_item
+    ]
+    kernel = functools.partial(
+        _rules_kernel, num=num, block=block, n_blocks=n_blocks
+    )
+    with jax.named_scope("fused_top_k"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(n_blocks,),
+                in_specs=[whole(queries), along(items_t)]
+                + [whole(x) for x in per_query]
+                + [along(x) for x in per_item],
+                out_specs=[
+                    pl.BlockSpec((b, num), lambda j, *_: (0, 0)),
+                    pl.BlockSpec((b, num), lambda j, *_: (0, 0)),
+                ],
+                scratch_shapes=[
+                    pltpu.VMEM((b, block), jnp.int32),
+                    pltpu.VMEM((b, num), jnp.float32),
+                    pltpu.VMEM((b, num), jnp.int32),
+                ],
+            ),
+            out_shape=[
+                jax.ShapeDtypeStruct((b, num), jnp.float32),
+                jax.ShapeDtypeStruct((b, num), jnp.int32),
+            ],
+            interpret=interpret,
+        )(ptr, packed, queries, items_t, *per_query, *per_item)

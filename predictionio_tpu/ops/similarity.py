@@ -13,14 +13,17 @@ from __future__ import annotations
 
 import os
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # the fused pallas kernel is meant to win once XLA's [B, I] score
 # intermediate gets big enough to dominate HBM traffic; below that XLA's
-# fused top-k needs no kernel dispatch. Where the crossover lies on the
-# installed JAX is not measured (ROADMAP S4 sets this threshold)
+# fused top-k needs no kernel dispatch. Measured above the threshold only
+# (1.07 GB, `_use_pallas`); where the crossover lies below it is not
+# measured (ROADMAP S6, R2)
 _PALLAS_MIN_INTERMEDIATE_BYTES = 512 * 1024 * 1024
 
 
@@ -61,16 +64,22 @@ def _pallas_mask(mask, batch: int):
     return mask
 
 
-def _use_pallas(batch: int, n_items: int) -> bool:
+def _use_pallas(batch: int, n_items: int, listed: bool = False) -> bool:
+    """Whether a top-k step of this shape takes the fused kernel.
+    ``listed``: the step also masks per-query lists of items (the
+    e-commerce rules)."""
     override = os.environ.get("PIO_PALLAS_TOPK")
     if override is not None:
         return override.strip().lower() in {"1", "true", "yes", "on"}
     # compiled Mosaic kernels exist only for TPU; every other backend
     # would hit the (slow) interpreter, so never auto-select it there
-    return (
-        batch * n_items * 4 >= _PALLAS_MIN_INTERMEDIATE_BYTES
-        and jax.default_backend() == "tpu"
-    )
+    if jax.default_backend() != "tpu":
+        return False
+    # measured on a v5e at [64, 4162560] x rank 16 (PERF.md section 6, PR
+    # 28): XLA's top-k 17.0 ms, the fused kernel 5.4; and XLA forms a
+    # per-query mask from lists by a scatter that runs at 6 us an entry
+    # (50 ms for 64 users' 93 seen items), the kernel inside its stream
+    return listed or batch * n_items * 4 >= _PALLAS_MIN_INTERMEDIATE_BYTES
 
 
 def _quantized(x) -> bool:
@@ -204,6 +213,198 @@ def gather_top_k_dot(
             interpret=jax.default_backend() != "tpu",
         )
     return _gather_top_k_dot_xla(factors, idx, items, num, mask)
+
+
+# -- business rules before the top-k ------------------------------------------
+#
+# The e-commerce template's candidates differ per query: the user's seen
+# items, a blackList or whiteList, a category, the catalog's unavailable
+# items, and "score > 0". A [B, I] mask built on the host is 266 MB a
+# batch at 4M items; what crosses to the device instead is compact (a few
+# ids per query and one packed list of item indices) and the mask is
+# formed on the device against per-catalog resident arrays.
+
+#: a query's branch (reference ECommAlgorithm.predict / predictSimilar /
+#: predictDefault)
+KNOWN, SIMILAR, POPULAR = 0, 1, 2
+#: item rows gathered for the SIMILAR branch (the latest 10 views, padded)
+RECENT_SLOTS = 16
+#: padding of a query's category slots; an unknown category is any other
+#: negative id, which filters and matches nothing
+NO_CATEGORY = -2
+#: the smallest capacity of a batch's packed lists, and the factor between
+#: one capacity and the next (`list_capacity`)
+LIST_CAPACITY, LIST_CAPACITY_STEP = 16384, 4
+#: the fused kernel holds a batch's packed lists in scalar memory (1 MiB
+#: on a v5e, shared with the blocks' offsets): longer ones take the XLA side
+FUSED_LIST_CAPACITY = 65536
+#: the column of an unused slot of the packed lists: past every item
+NO_ITEM = np.iinfo(np.int32).max
+#: a staged rules catalog has a whole number of these rows (phantom rows
+#: unavailable): whole blocks for the fused kernel, whatever its block
+CATALOG_ROW_MULTIPLE = 1024
+
+
+class CatalogRules(NamedTuple):
+    """Per-catalog arrays, resident on the device (``rows`` = rows of the
+    item table, phantom padding rows included and marked unavailable)."""
+
+    categories: jax.Array    # [C, rows] int32, -1 = no category
+    unavailable: jax.Array   # [rows] bool
+    inv_norm: jax.Array      # [rows] f32, 1/|V[i]| (0 for a zero row)
+    popularity: jax.Array    # [rows] f32
+
+
+class QueryRules(NamedTuple):
+    """One batch's rules as compact host arrays. The rows' lists (seen +
+    blackList, or a whiteList) are packed into one pair of arrays, sorted
+    by item row so that a kernel streaming item blocks finds each block's
+    entries side by side."""
+
+    mode: np.ndarray         # [B] int32: KNOWN, SIMILAR or POPULAR
+    recent: np.ndarray       # [B, RECENT_SLOTS] int32 item rows, -1 = none
+    categories: np.ndarray   # [B, QC] int32 ids, NO_CATEGORY = padding
+    list_rows: np.ndarray    # [N] int32: the query row of each list entry
+    list_cols: np.ndarray    # [N] int32: its item row, ascending; NO_ITEM
+                             # in the unused tail
+    allow: np.ndarray        # [B] bool: the list is a whiteList, not exclusions
+
+
+def list_capacity(total: int) -> int:
+    """Length of the packed lists for ``total`` entries: a shape of the
+    compiled step, so it takes few values (16,384, which a batch of 64
+    users with 93 seen items each fills to a third, then four times that,
+    and so on)."""
+    capacity = LIST_CAPACITY
+    while capacity < total:
+        capacity *= LIST_CAPACITY_STEP
+    return capacity
+
+
+def pack_lists(lists) -> tuple[np.ndarray, np.ndarray]:
+    """``(list_rows, list_cols)`` of ``QueryRules`` from one int array of
+    item rows per query row."""
+    lengths = [len(x) for x in lists]
+    total = sum(lengths)
+    rows = np.zeros(list_capacity(total), np.int32)
+    cols = np.full(len(rows), NO_ITEM, np.int32)
+    if total:
+        found = np.concatenate(lists)
+        order = np.argsort(found, kind="stable")
+        cols[:total] = found[order]
+        rows[:total] = np.repeat(
+            np.arange(len(lists), dtype=np.int32), lengths
+        )[order]
+    return rows, cols
+
+
+def _listed_mask(list_rows, list_cols, batch: int, rows: int) -> jax.Array:
+    """[B, rows] int8, 1 where row b's list names the item (XLA side: a
+    scatter, which the TPU runs serially at about 6 us an entry, so
+    `_use_pallas` sends the rules step to the fused kernel there)."""
+    return jnp.zeros((batch, rows), jnp.int8).at[list_rows, list_cols].set(
+        1, mode="drop"
+    )
+
+
+def rule_scores(
+    dots,         # [B, n] f32 query . item
+    listed,       # [B, n] int8 / bool
+    mode,         # [B, 1] int32
+    allow,        # [B, 1] bool / int
+    q_cats,       # [B, QC] int32
+    categories,   # [C, n] int32
+    unavailable,  # [1, n] bool / int
+    inv_norm,     # [1, n] f32
+    popularity,   # [1, n] f32
+):
+    """Scores of one block of items with every rule applied: -inf where
+    the item is no candidate of the row's query, or scores <= 0 on a
+    branch that keeps positive scores only. 2-D broadcasts only, so the
+    Pallas kernel runs it on a VMEM block and XLA on the whole row."""
+    scores = jnp.where(mode == SIMILAR, dots * inv_norm, dots)
+    scores = jnp.where(mode == POPULAR, popularity, scores)
+    excluded = (listed != 0) != (allow != 0)
+    filtered = q_cats[:, 0:1] != NO_CATEGORY  # slots fill from the first
+    in_category = jnp.zeros(dots.shape, jnp.bool_)
+    for c in range(categories.shape[0]):
+        for k in range(q_cats.shape[1]):
+            in_category |= categories[c:c + 1, :] == q_cats[:, k:k + 1]
+    excluded |= unavailable != 0
+    excluded |= filtered & ~in_category
+    # NaN (corrupted factors) fails `> 0` and is excluded with the rest
+    excluded |= (mode != POPULAR) & ~(scores > 0)
+    return jnp.where(excluded, -jnp.inf, scores)
+
+
+@partial(jax.jit, static_argnames=("num", "fused", "interpret"))
+def _rules_top_k(
+    factors, idx, items, catalog: CatalogRules, rules: QueryRules,
+    num: int, fused: bool, interpret: bool,
+):
+    batch, rows = idx.shape[0], items.shape[0]
+    mode = rules.mode[:, None]
+    with jax.named_scope("gather"):
+        vecs = jnp.take(factors, idx, axis=0)
+        recent = jnp.clip(rules.recent, 0, None)
+        weight = jnp.where(
+            rules.recent >= 0, jnp.take(catalog.inv_norm, recent), 0.0
+        )
+        views = (jnp.take(items, recent, axis=0) * weight[:, :, None]).sum(1)
+        vecs = jnp.where(mode == SIMILAR, views, vecs)
+    per_query = (mode, rules.allow[:, None], rules.categories)
+    per_item = (
+        catalog.categories, catalog.unavailable[None, :],
+        catalog.inv_norm[None, :], catalog.popularity[None, :],
+    )
+    if fused:
+        from predictionio_tpu.ops.pallas_topk import fused_rules_top_k
+
+        return fused_rules_top_k(
+            vecs, items, num, per_query, per_item,
+            rules.list_rows, rules.list_cols, interpret=interpret,
+        )
+    with jax.named_scope("mask"):
+        listed = _listed_mask(rules.list_rows, rules.list_cols, batch, rows)
+    with jax.named_scope("score"):
+        dots = vecs @ items.T
+    with jax.named_scope("mask"):
+        scores = rule_scores(dots, listed, *per_query, *per_item)
+    with jax.named_scope("top_k"):
+        return jax.lax.top_k(scores, num)
+
+
+def rules_top_k(
+    factors, idx, items, num: int, catalog: CatalogRules, rules: QueryRules
+) -> tuple[jax.Array, jax.Array]:
+    """Gather + score + business rules + top-``num`` in one dispatch, the
+    mask formed on the device (``QueryRules`` is all that is uploaded).
+    Row b's candidates: not ``catalog.unavailable``; in one of the
+    query's categories if it names any; on its list if ``allow[b]``, off
+    it otherwise. Its scores: ``factors[idx[b]] . item`` (KNOWN), the
+    summed cosine to its ``recent`` items (SIMILAR), both kept only where
+    positive, or the item's popularity (POPULAR). Returns ([B, num]
+    scores, [B, num] item rows); a slot with no candidate left has score
+    -inf. Chosen by :func:`_use_pallas` like the unmasked step; the fused
+    side takes catalogs of whole blocks (`CATALOG_ROW_MULTIPLE`)."""
+    if _quantized(factors) or _quantized(items):
+        # a pool's int8/bf16 tables: the rules step has no quantized
+        # kernel yet, so the tables are widened for the call
+        from predictionio_tpu.ops import quantize
+
+        if _quantized(factors):
+            factors = quantize.dequantize(factors)
+        if _quantized(items):
+            items = quantize.dequantize(items)
+    batch, rows = len(idx), items.shape[0]
+    return _rules_top_k(
+        factors, jnp.asarray(idx, jnp.int32), items, catalog, rules,
+        num=min(num, rows),
+        fused=_use_pallas(batch, rows, listed=True)
+        and rows % CATALOG_ROW_MULTIPLE == 0
+        and len(rules.list_cols) <= FUSED_LIST_CAPACITY,
+        interpret=jax.default_backend() != "tpu",
+    )
 
 
 @partial(jax.jit, static_argnames=("num",))

@@ -56,6 +56,12 @@ class BiMap:
     def get(self, key: str, default: int | None = None) -> int | None:
         return self._index.get(str(key), default)
 
+    def getter(self):
+        """``get(key, default)`` of the underlying dict itself, for loops
+        that look up many ``str`` keys (no ``str()`` of the key, no Python
+        frame a call)."""
+        return self._index.get
+
     def inverse(self, idx: int) -> str:
         return str(self._keys[idx])
 
