@@ -468,11 +468,14 @@ class ECommAlgorithm(Algorithm):
             nums = [int(q.get("num", 10)) for q in queries]
             num = min(max(1, max(nums)), n_items)
             num_bucket = min(_bucket(num), n_items)
-            batch = _bucket(len(queries))
             users = [str(q.get("user", "")) for q in queries]
-            user_idx = np.zeros(batch, np.int32)
-            mode = np.full(batch, similarity.POPULAR, np.int32)
-            recent = np.full((batch, similarity.RECENT_SLOTS), -1, np.int32)
+            wanted = [q.get("categories") or () for q in queries]
+            # the batch's operands, filled in place through these views
+            operands = similarity.QueryRules.blank(
+                _bucket(len(queries)), _bucket(max(1, max(map(len, wanted))))
+            )
+            user_idx, mode, recent = operands.idx, operands.mode, operands.recent
+            allow, q_cats = operands.allow, operands.categories
             seen: list = [()] * len(queries)
             for i, user in enumerate(users):
                 row = model.user_map.get(user, -1)
@@ -494,12 +497,7 @@ class ECommAlgorithm(Algorithm):
                         "read", type(e).__name__, e,
                     )
             get = model.item_map.getter()
-            lists, allow = [], np.zeros(batch, np.bool_)
-            wanted = [q.get("categories") or () for q in queries]
-            q_cats = np.full(
-                (batch, _bucket(max(1, max(map(len, wanted))))),
-                similarity.NO_CATEGORY, np.int32,
-            )
+            lists = []
             for i, q in enumerate(queries):
                 black = [get(str(x), -1) for x in q.get("blackList") or ()]
                 white = q.get("whiteList") or ()
@@ -525,15 +523,12 @@ class ECommAlgorithm(Algorithm):
                         for c in wanted[i]
                     ]
                 counters.branch[int(mode[i])].inc()
-            list_rows, list_cols = similarity.pack_lists(lists)
+            operands = operands._replace(lists=similarity.pack_lists(lists))
             counters.excluded.inc(sum(len(x) for x in lists))
-            operands = similarity.QueryRules(
-                mode, recent, q_cats, list_rows, list_cols, allow
-            )
         with tracing.stage(tracing.PREDICT_ENQUEUE):
             scores, items = similarity.rules_top_k(
-                model.user_factors, user_idx, model.item_factors,
-                num_bucket, rules.catalog, operands,
+                model.user_factors, model.item_factors, num_bucket,
+                rules.catalog, operands,
             )
         return scores, items, nums, counters
 

@@ -287,9 +287,11 @@ class ALSAlgorithm(Algorithm[RecTrainingData, ALSRecModel, dict, dict]):
             batch_bucket = 1 << max(0, (len(idx) - 1)).bit_length()
             if batch_bucket > len(idx):
                 idx = np.pad(idx, (0, batch_bucket - len(idx)))
-        # fused gather + score + top-k on device: uploads only `idx`
-        # (factors are staged jax.Arrays after stage_model; the
-        # evaluation path passes host arrays and pays the upload there)
+        # fused gather + score + top-k on device, one call into the
+        # runtime: the jitted program takes the numpy `idx` and uploads
+        # it itself (factors are staged jax.Arrays after stage_model;
+        # the evaluation path passes host arrays and pays the upload
+        # there)
         with tracing.stage(tracing.PREDICT_ENQUEUE):
             scores, items = similarity.gather_top_k_dot(
                 model.user_factors, idx, model.item_factors, num_bucket,

@@ -601,10 +601,10 @@ _bound_stages: contextvars.ContextVar[tuple[dict, dict] | None] = (
 
 
 class _StageChildren(dict):
-    """The children of ``pio_stage_seconds`` by stage, and the registry
-    they belong to (`bound_registry`)."""
+    """The children of ``pio_stage_seconds`` by stage, the registry they
+    belong to (`bound_registry`) and its launch counter (`launch_call`)."""
 
-    __slots__ = ("registry",)
+    __slots__ = ("registry", "launch_calls")
 
 
 class StageSink:
@@ -629,6 +629,12 @@ class StageSink:
             (name, family.labels(name)) for name in STAGES + NESTED_STAGES
         )
         self._children.registry = registry
+        self._children.launch_calls = registry.counter(
+            "pio_device_launch_calls_total",
+            "Hand-overs to the runtime made by a predict launch: a "
+            "jitted program, or an upload by a call of its own (over "
+            "pio_batches_total: 1 where a launch is one call)",
+        )
 
     def bind(self, **keywords) -> None:
         """Stages on this context observe here from now on, and their
@@ -641,11 +647,23 @@ class StageSink:
 _default_sink = StageSink(None)
 
 
+def _bound_children() -> _StageChildren:
+    return (_bound_stages.get() or (_default_sink._children,))[0]
+
+
 def bound_registry() -> MetricRegistry:
     """The registry of the server whose sink this context bound (its
     batcher's thread inside a predict, a handler's thread), else the
     process's: where a model's own counters belong."""
-    return (_bound_stages.get() or (_default_sink._children,))[0].registry
+    return _bound_children().registry
+
+
+def launch_call(calls: int = 1) -> None:
+    """Count ``calls`` hand-overs to the runtime (a jitted program, or
+    an upload made by a call of its own) of a predict launch, beside the
+    call, on the registry this context is bound to. Each lets go of the
+    interpreter lock on the batcher's thread and waits to get it back."""
+    _bound_children().launch_calls.inc(calls)
 
 
 class _Stage:

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 from functools import partial
 
+from predictionio_tpu.obs import tracing
 from predictionio_tpu.ops import similarity
 
 MODES = ("int8", "bf16")
@@ -125,7 +126,11 @@ def stage_quantized(qf: QuantizedFactors) -> QuantizedFactors:
 
 
 @partial(jax.jit, static_argnames=("num",))
-def _top_k_dot_quant_xla(queries, data, scale, num, mask=None):
+def _top_k_dot_quant_xla(queries, data, scale, num, mask=None, table=None):
+    if table is not None:
+        # a launch's one program: `queries` are the [B] rows to gather
+        # of `table`, the (data, scale) of `_gather_rows_quant`
+        queries = _gather_rows_quant(*table, queries)
     with jax.named_scope("score_dequant"):
         scores = queries @ data.astype(jnp.float32).T  # dequant fuses in
         if scale is not None:
@@ -161,6 +166,27 @@ def top_k_dot_quantized(
         )
     return _top_k_dot_quant_xla(
         queries, items.data, items.scale, num, mask
+    )
+
+
+def gather_top_k_dot_quantized(
+    factors, idx, items: QuantizedFactors, num: int, mask=None
+) -> tuple[jax.Array, jax.Array]:
+    """Quantized side of :func:`similarity.gather_top_k_dot`: the rows
+    ``idx`` of ``factors`` (a quantized table or a float one) dequantized
+    and scored against ``items`` in one program, one call into the
+    runtime. Composes :func:`gather_rows` and
+    :func:`top_k_dot_quantized`, and equals them exactly."""
+    if similarity._use_pallas(len(idx), items.shape[0]):
+        tracing.launch_call(3)  # idx's upload, the gather, the kernel
+        return top_k_dot_quantized(gather_rows(factors, idx), items, num, mask)
+    if isinstance(factors, QuantizedFactors):
+        table = (factors.data, factors.scale)
+    else:
+        table = (similarity.host_operand(factors), None)
+    tracing.launch_call()
+    return _top_k_dot_quant_xla(
+        idx, items.data, items.scale, min(num, items.shape[0]), mask, table
     )
 
 

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from predictionio_tpu.obs import tracing
+
 # the fused pallas kernel is meant to win once XLA's [B, I] score
 # intermediate gets big enough to dominate HBM traffic; below that XLA's
 # fused top-k needs no kernel dispatch. Measured above the threshold only
@@ -171,6 +173,16 @@ def stage_factors(x) -> jax.Array:
     return jax.device_put(jnp.asarray(x))
 
 
+def host_operand(x, dtype=None):
+    """A launch's operand as the jitted program takes it: a device array
+    as it is, anything else as a host array, which the compiled call
+    uploads itself. An upload by a call of its own (``jnp.asarray``) is
+    a second hand-over to the runtime and costs a busy server's thread
+    as much as the program's call, whatever its size (PERF.md section
+    6, PR 29)."""
+    return x if isinstance(x, jax.Array) else np.asarray(x, dtype)
+
+
 @partial(jax.jit, static_argnames=("num",))
 def _gather_top_k_dot_xla(
     factors: jax.Array,   # [U, k] staged
@@ -187,31 +199,38 @@ def _gather_top_k_dot_xla(
 def gather_top_k_dot(
     factors, idx, items, num: int, mask=None
 ) -> tuple[jax.Array, jax.Array]:
-    """Fused row-gather + dot scores + top-``num``: one device dispatch,
-    uploading only ``idx``. ``factors``/``items`` may be host arrays
-    (evaluation path) — they are uploaded per call then; staged serving
-    passes resident ``jax.Array``s. Either side may also be a
-    quantized table: gathered user rows dequantize to f32 (a handful
-    of rows), the item catalog stays int8/bf16 end to end."""
+    """Fused row-gather + dot scores + top-``num``: one call into the
+    runtime, which uploads the host ``idx`` itself. ``factors``/``items``
+    may be host arrays (evaluation path) — the call uploads them too,
+    every time; staged serving passes resident ``jax.Array``s. Either
+    side may also be a quantized table: gathered user rows dequantize to
+    f32 (a handful of rows), the item catalog stays int8/bf16 end to
+    end."""
+    idx = host_operand(idx, np.int32)
     if _quantized(factors) or _quantized(items):
         from predictionio_tpu.ops import quantize
 
-        vecs = quantize.gather_rows(factors, idx)
         if _quantized(items):
-            return quantize.top_k_dot_quantized(vecs, items, num, mask)
-        return top_k_dot(vecs, jnp.asarray(items), num, mask)
-    factors, items = jnp.asarray(factors), jnp.asarray(items)
+            return quantize.gather_top_k_dot_quantized(
+                factors, idx, items, num, mask
+            )
+        tracing.launch_call(3)  # idx's upload, the gather, the top-k
+        return top_k_dot(
+            quantize.gather_rows(factors, idx), jnp.asarray(items), num, mask
+        )
+    factors, items = host_operand(factors), host_operand(items)
     num = min(num, items.shape[0])
-    idx = jnp.asarray(idx, jnp.int32)
     if _use_pallas(idx.shape[0], items.shape[0]):
         from predictionio_tpu.ops.pallas_topk import fused_top_k_dot
 
+        tracing.launch_call(3)  # idx's upload, the gather, the kernel
         with jax.named_scope("gather"):
-            vecs = jnp.take(factors, idx, axis=0)
+            vecs = jnp.take(factors, jnp.asarray(idx), axis=0)
         return fused_top_k_dot(
             vecs, items, num, _pallas_mask(mask, idx.shape[0]),
             interpret=jax.default_backend() != "tpu",
         )
+    tracing.launch_call()
     return _gather_top_k_dot_xla(factors, idx, items, num, mask)
 
 
@@ -256,18 +275,43 @@ class CatalogRules(NamedTuple):
 
 
 class QueryRules(NamedTuple):
-    """One batch's rules as compact host arrays. The rows' lists (seen +
-    blackList, or a whiteList) are packed into one pair of arrays, sorted
-    by item row so that a kernel streaming item blocks finds each block's
-    entries side by side."""
+    """One batch's rules as two compact host arrays, which the jitted step
+    uploads itself and slices apart on the device: each host operand of a
+    call costs the launching thread 0.7-0.9 ms on a busy server, whatever
+    its size (PERF.md section 6, PR 29). The rows' lists (seen + blackList,
+    or a whiteList) are packed together, sorted by item row so that a
+    kernel streaming item blocks finds each block's entries side by side."""
 
-    mode: np.ndarray         # [B] int32: KNOWN, SIMILAR or POPULAR
-    recent: np.ndarray       # [B, RECENT_SLOTS] int32 item rows, -1 = none
-    categories: np.ndarray   # [B, QC] int32 ids, NO_CATEGORY = padding
-    list_rows: np.ndarray    # [N] int32: the query row of each list entry
-    list_cols: np.ndarray    # [N] int32: its item row, ascending; NO_ITEM
-                             # in the unused tail
-    allow: np.ndarray        # [B] bool: the list is a whiteList, not exclusions
+    per_query: np.ndarray    # [B, 3 + RECENT_SLOTS + QC] int32, by column:
+                             # idx, mode, allow, recent, categories
+    lists: np.ndarray        # [2, N] int32: list_rows, list_cols (`pack_lists`)
+
+    @classmethod
+    def blank(cls, batch: int, slots: int) -> "QueryRules":
+        """``batch`` rows that ask user row 0 for the popular items of the
+        whole catalog, with ``slots`` category slots and no lists yet: a
+        launch fills the views in place and adds ``lists`` last
+        (``_replace``), so nothing is copied together."""
+        per_query = np.zeros((batch, 3 + RECENT_SLOTS + slots), np.int32)
+        rules = cls(per_query, None)
+        rules.mode[:] = POPULAR
+        rules.recent[:] = -1
+        rules.categories[:] = NO_CATEGORY
+        return rules
+
+    # views, of the host arrays here and of the traced ones in the step
+    idx = property(lambda r: r.per_query[:, 0])     # [B] user rows
+    mode = property(lambda r: r.per_query[:, 1])    # [B] KNOWN, SIMILAR, POPULAR
+    allow = property(lambda r: r.per_query[:, 2])   # [B] 1: a whiteList
+    recent = property(                              # [B, RECENT_SLOTS], -1 = none
+        lambda r: r.per_query[:, 3:3 + RECENT_SLOTS]
+    )
+    categories = property(                          # [B, QC], NO_CATEGORY = padding
+        lambda r: r.per_query[:, 3 + RECENT_SLOTS:]
+    )
+    list_rows = property(lambda r: r.lists[0])      # [N] each entry's query row
+    list_cols = property(lambda r: r.lists[1])      # [N] its item row, ascending;
+                                                    # NO_ITEM in the unused tail
 
 
 def list_capacity(total: int) -> int:
@@ -281,13 +325,14 @@ def list_capacity(total: int) -> int:
     return capacity
 
 
-def pack_lists(lists) -> tuple[np.ndarray, np.ndarray]:
-    """``(list_rows, list_cols)`` of ``QueryRules`` from one int array of
-    item rows per query row."""
+def pack_lists(lists) -> np.ndarray:
+    """``lists`` of ``QueryRules`` ([2, N]: list_rows, list_cols) from one
+    int array of item rows per query row."""
     lengths = [len(x) for x in lists]
     total = sum(lengths)
-    rows = np.zeros(list_capacity(total), np.int32)
-    cols = np.full(len(rows), NO_ITEM, np.int32)
+    packed = np.zeros((2, list_capacity(total)), np.int32)
+    rows, cols = packed
+    cols[total:] = NO_ITEM
     if total:
         found = np.concatenate(lists)
         order = np.argsort(found, kind="stable")
@@ -295,7 +340,7 @@ def pack_lists(lists) -> tuple[np.ndarray, np.ndarray]:
         rows[:total] = np.repeat(
             np.arange(len(lists), dtype=np.int32), lengths
         )[order]
-    return rows, cols
+    return packed
 
 
 def _listed_mask(list_rows, list_cols, batch: int, rows: int) -> jax.Array:
@@ -339,13 +384,13 @@ def rule_scores(
 
 @partial(jax.jit, static_argnames=("num", "fused", "interpret"))
 def _rules_top_k(
-    factors, idx, items, catalog: CatalogRules, rules: QueryRules,
+    factors, items, catalog: CatalogRules, rules: QueryRules,
     num: int, fused: bool, interpret: bool,
 ):
-    batch, rows = idx.shape[0], items.shape[0]
+    batch, rows = rules.per_query.shape[0], items.shape[0]
     mode = rules.mode[:, None]
     with jax.named_scope("gather"):
-        vecs = jnp.take(factors, idx, axis=0)
+        vecs = jnp.take(factors, rules.idx, axis=0)
         recent = jnp.clip(rules.recent, 0, None)
         weight = jnp.where(
             rules.recent >= 0, jnp.take(catalog.inv_norm, recent), 0.0
@@ -375,10 +420,11 @@ def _rules_top_k(
 
 
 def rules_top_k(
-    factors, idx, items, num: int, catalog: CatalogRules, rules: QueryRules
+    factors, items, num: int, catalog: CatalogRules, rules: QueryRules
 ) -> tuple[jax.Array, jax.Array]:
-    """Gather + score + business rules + top-``num`` in one dispatch, the
-    mask formed on the device (``QueryRules`` is all that is uploaded).
+    """Gather + score + business rules + top-``num`` in one call into the
+    runtime, the mask formed on the device (the call uploads the two
+    host arrays of ``QueryRules`` itself, and nothing else).
     Row b's candidates: not ``catalog.unavailable``; in one of the
     query's categories if it names any; on its list if ``allow[b]``, off
     it otherwise. Its scores: ``factors[idx[b]] . item`` (KNOWN), the
@@ -396,9 +442,10 @@ def rules_top_k(
             factors = quantize.dequantize(factors)
         if _quantized(items):
             items = quantize.dequantize(items)
-    batch, rows = len(idx), items.shape[0]
+    batch, rows = len(rules.per_query), items.shape[0]
+    tracing.launch_call()
     return _rules_top_k(
-        factors, jnp.asarray(idx, jnp.int32), items, catalog, rules,
+        factors, items, catalog, rules,
         num=min(num, rows),
         fused=_use_pallas(batch, rows, listed=True)
         and rows % CATALOG_ROW_MULTIPLE == 0

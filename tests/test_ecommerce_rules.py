@@ -73,7 +73,10 @@ def _dense_case(seed, n_users=50, n_items=3072, rank=16, batch=8, cats_per_item=
         length = int(rng.choice([0, 5, 100, long_list]))
         lists.append(rng.choice(n_items, length, replace=False).astype(np.int32))
         allow[b] = length > 0 and rng.random() < 0.3
-    rules = S.QueryRules(mode, recent, q_cats, *S.pack_lists(lists), allow)
+    rules = S.QueryRules.blank(batch, slots)
+    rules.idx[:], rules.mode[:], rules.allow[:] = idx, mode, allow
+    rules.recent[:], rules.categories[:] = recent, q_cats
+    rules = rules._replace(lists=S.pack_lists(lists))
     # the same, densely
     q = users[idx].astype(np.float64)
     for b in range(batch):
@@ -94,7 +97,7 @@ def _dense_case(seed, n_users=50, n_items=3072, rank=16, batch=8, cats_per_item=
     excluded |= unavailable[None, :]
     excluded |= (q_cats[:, :1] != S.NO_CATEGORY) & ~in_category
     excluded |= (mode[:, None] != S.POPULAR) & ~(scores > 0)
-    return users, idx, items, catalog, rules, np.where(excluded, -np.inf, scores)
+    return users, items, catalog, rules, np.where(excluded, -np.inf, scores)
 
 
 @pytest.mark.parametrize("fused", [False, True], ids=["xla", "pallas"])
@@ -104,10 +107,10 @@ def _dense_case(seed, n_users=50, n_items=3072, rank=16, batch=8, cats_per_item=
 ], ids=["plain", "two_categories", "one_row", "past_one_capacity"])
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_masked_top_k_equals_a_dense_numpy_mask(seed, shape, fused):
-    users, idx, items, catalog, rules, dense = _dense_case(seed, **shape)
+    users, items, catalog, rules, dense = _dense_case(seed, **shape)
     num = 16
     got_s, got_i = S._rules_top_k(
-        jnp.asarray(users), jnp.asarray(idx), jnp.asarray(items), catalog, rules,
+        jnp.asarray(users), jnp.asarray(items), catalog, rules,
         num=num, fused=fused, interpret=True,
     )
     got_s, got_i = np.asarray(got_s), np.asarray(got_i)
