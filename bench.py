@@ -76,14 +76,12 @@ def serving_bench_summary() -> dict | None:
     extra = last.get("extra") or {}
     summary = {
         "recordedAtUtc": last.get("recordedAtUtc"),
-        "pipeline_speedup": last.get("value"),
         "runs_recorded": len(runs),
     }
     open_loop = extra.get("open_loop")
-    if isinstance(open_loop, dict) and open_loop.get("pipelined"):
-        piped = open_loop["pipelined"]
+    if isinstance(open_loop, dict):
         summary["open_loop"] = {
-            k: piped.get(k)
+            k: open_loop.get(k)
             for k in ("offered_qps", "achieved_qps", "p99_ms")
         }
     overload = extra.get("overload")

@@ -142,7 +142,6 @@ class Smoke:
         env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
         env["PYTHONUNBUFFERED"] = "1"
         env["PIO_FS_BASEDIR"] = os.path.join(self.workdir, "store")
-        env.pop("PIO_PALLAS_TOPK", None)  # the dispatcher's own choice
         if self.rehearsal:
             env["JAX_PLATFORMS"] = "cpu"
             n = math.prod(

@@ -1,5 +1,5 @@
 """Baseline file for ``pio-tpu lint`` — the accepted pre-existing
-finding set, à la ``scripts/known_failures.txt``.
+finding set.
 
 Format (one finding per line, ``|``-separated; ``#`` comments and blank
 lines ignored)::

@@ -1315,6 +1315,7 @@ def cmd_eval(args) -> int:
 
 
 def cmd_deploy(args) -> int:
+    from predictionio_tpu.serving.batching import DEPTH_ZERO_GONE
     from predictionio_tpu.serving.engine_server import EngineServer
 
     _store_urls_from_args(args)
@@ -1333,10 +1334,10 @@ def cmd_deploy(args) -> int:
             file=sys.stderr,
         )
         return 1
-    if args.pipeline_depth < 0:
+    if args.pipeline_depth < 1:
         print(
-            f"error: --pipeline-depth must be >= 0, "
-            f"got {args.pipeline_depth}",
+            "error: --pipeline-depth: "
+            + DEPTH_ZERO_GONE.format(args.pipeline_depth),
             file=sys.stderr,
         )
         return 1
@@ -2434,7 +2435,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument(
         "--pipeline-depth", dest="pipeline_depth", type=int, default=2,
         help="batches in flight between device enqueue and collected "
-             "results (2 = double buffering; 0 = serial dispatch)",
+             "results (2 = double buffering; at least 1)",
     )
     p.add_argument(
         "--no-adaptive-wait", dest="no_adaptive_wait",
@@ -2468,7 +2469,7 @@ def build_parser() -> argparse.ArgumentParser:
     )
     p.add_argument(
         "--quantize", choices=("int8", "bf16"), default=None,
-        help="quantize pooled factor tables (overrides PIO_POOL_QUANT)",
+        help="quantize pooled factor tables (default: f32)",
     )
     p.add_argument(
         "--workers", type=int, default=1,

@@ -24,9 +24,14 @@ from __future__ import annotations
 import abc
 import dataclasses
 import enum
+import functools
+import logging
 from typing import Any, Generic, Sequence, TypeVar
 
 from predictionio_tpu.parallel.mesh import ComputeContext
+from predictionio_tpu.utils import profiling
+
+logger = logging.getLogger(__name__)
 
 TD = TypeVar("TD")  # training data
 PD = TypeVar("PD")  # prepared data
@@ -186,16 +191,17 @@ class Algorithm(_Controller, Generic[PD, M, Q, P], abc.ABC):
         Default loops; algorithms override with a vmapped/jitted path."""
         return [self.predict(model, q) for q in queries]
 
-    # -- two-phase serving hooks (pipelined micro-batching) --------------
+    # -- serving hooks (pipelined micro-batching) -------------------------
     def batch_predict_launch(self, model: M, queries: Sequence[Q]) -> Any:
         """Enqueue the device work for ``queries`` and return an opaque
         handle WITHOUT blocking on the device (JAX async dispatch: run
         the jitted program, return the un-fetched device arrays plus
         whatever host metadata the decode needs). Pairs with
-        :meth:`batch_predict_collect`; the serving micro-batcher uses
-        the pair to overlap batch N+1's enqueue with batch N's barrier
-        (docs/serving.md "Pipelined dispatch"). Algorithms that don't
-        override this serve single-phase through ``batch_predict``.
+        :meth:`batch_predict_collect`; the serving micro-batcher calls
+        the pair on its two threads to overlap batch N+1's enqueue with
+        batch N's barrier (docs/serving.md "Pipelined dispatch").
+        Default: nothing is launched, the queries are the handle, and
+        :meth:`batch_predict_collect` does all the work.
 
         Sharded-model contract: implementations must accept model
         state whose arrays are mesh-sharded ``jax.Array``s (e.g. ALS
@@ -205,17 +211,41 @@ class Algorithm(_Controller, Generic[PD, M, Q, P], abc.ABC):
         arrays and let GSPMD insert the collectives. A host gather
         here would both serialize serving and cap the catalog at one
         chip's HBM."""
-        raise NotImplementedError(
-            f"{type(self).__name__} does not implement two-phase predict"
-        )
+        return queries
 
     def batch_predict_collect(
         self, model: M, handle: Any, queries: Sequence[Q]
     ) -> list[P]:
         """Pay the device barrier for a :meth:`batch_predict_launch`
-        handle and materialize one result per query, in order."""
-        raise NotImplementedError(
-            f"{type(self).__name__} does not implement two-phase predict"
+        handle and materialize one result per query, in order.
+        Default: :meth:`batch_predict`, then a barrier on whatever
+        device arrays its predictions still hold — the batcher stops
+        its sync clock when this returns, and async dispatch would
+        otherwise make ``pio_device_sync_seconds`` measure the enqueue,
+        not the work."""
+        out = self.batch_predict(model, queries)
+        profiling.sync(out)
+        return out
+
+    def serving_hooks(self):
+        """``(launch, collect)`` as a server wires them: the
+        algorithm's own pair. One that overrides exactly one of the two
+        would meet the other's default with a handle it does not know,
+        so it is served through the default pair, and told so."""
+        cls = type(self)
+        if (cls.batch_predict_launch is Algorithm.batch_predict_launch) == (
+            cls.batch_predict_collect is Algorithm.batch_predict_collect
+        ):
+            return self.batch_predict_launch, self.batch_predict_collect
+        logger.warning(
+            "%s overrides only one of batch_predict_launch/"
+            "batch_predict_collect — serving single-phase through "
+            "batch_predict",
+            cls.__name__,
+        )
+        return (
+            functools.partial(Algorithm.batch_predict_launch, self),
+            functools.partial(Algorithm.batch_predict_collect, self),
         )
 
     def stage_model(self, ctx: ComputeContext, model: M) -> M:

@@ -148,9 +148,9 @@ def top_k_dot_quantized(
     num: int,
     mask=None,
 ) -> tuple[jax.Array, jax.Array]:
-    """Quantized twin of :func:`similarity.top_k_dot`; same dispatcher
-    (``PIO_PALLAS_TOPK`` / intermediate-bytes threshold) decides
-    between the dequantizing Pallas kernel and the XLA fallback."""
+    """Quantized twin of :func:`similarity.top_k_dot`; the same
+    ``similarity._use_pallas`` decides between the dequantizing Pallas
+    kernel and the XLA fallback."""
     queries = jnp.asarray(queries, jnp.float32)
     num = min(num, items.shape[0])
     if similarity._use_pallas(queries.shape[0], items.shape[0]):

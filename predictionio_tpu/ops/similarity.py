@@ -11,7 +11,6 @@ examples/scala-parallel-similarproduct/multi/.../ALSAlgorithm.scala).
 
 from __future__ import annotations
 
-import os
 from functools import partial
 from typing import NamedTuple
 
@@ -70,9 +69,6 @@ def _use_pallas(batch: int, n_items: int, listed: bool = False) -> bool:
     """Whether a top-k step of this shape takes the fused kernel.
     ``listed``: the step also masks per-query lists of items (the
     e-commerce rules)."""
-    override = os.environ.get("PIO_PALLAS_TOPK")
-    if override is not None:
-        return override.strip().lower() in {"1", "true", "yes", "on"}
     # compiled Mosaic kernels exist only for TPU; every other backend
     # would hit the (slow) interpreter, so never auto-select it there
     if jax.default_backend() != "tpu":
@@ -103,7 +99,8 @@ def top_k_dot(
     Large batch×catalog products on TPU take the fused Pallas path
     (:func:`predictionio_tpu.ops.pallas_topk.fused_top_k_dot`), which
     streams item blocks through VMEM instead of writing the [B, I]
-    score matrix to HBM. ``PIO_PALLAS_TOPK=0/1`` overrides the choice.
+    score matrix to HBM (:func:`_use_pallas` chooses from the platform
+    and the shape).
 
     ``items`` may be a quantized table
     (:class:`predictionio_tpu.ops.quantize.QuantizedFactors`): the
@@ -117,8 +114,8 @@ def top_k_dot(
     if _use_pallas(queries.shape[0], items.shape[0]):
         from predictionio_tpu.ops.pallas_topk import fused_top_k_dot
 
-        # a forced override off-TPU runs the interpreter (slow but
-        # correct); Mosaic kernels only compile for TPU
+        # Mosaic kernels compile only for the TPU: a test that patches
+        # the choice elsewhere gets the interpreter
         return fused_top_k_dot(
             queries, items, num, _pallas_mask(mask, queries.shape[0]),
             interpret=jax.default_backend() != "tpu",

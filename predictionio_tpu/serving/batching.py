@@ -12,23 +12,20 @@ move from the Podracer line of work — never let the accelerator wait
 on host bookkeeping). A **collector** thread assembles batches
 (max_batch/max_wait coalescing, cancellation, deadline drops) and
 *enqueues* them to the device; a **completer** thread syncs the device
-barrier and materializes results. ``pipeline_depth`` bounds how many
-batches may be in flight past their enqueue (default 2 = double
-buffering): batch N+1 is assembled and enqueued while batch N is still
-computing, so the device never idles on host-side assembly/JSON work
-and the host never idles on device compute.
+barrier and materializes results. ``pipeline_depth`` (1 or more) bounds
+how many batches may be in flight past their enqueue (default 2 =
+double buffering): batch N+1 is assembled and enqueued while batch N
+is still computing, so the device never idles on host-side
+assembly/JSON work and the host never idles on device compute.
 
-``batch_fn`` comes in two shapes:
-
-* a plain callable ``(items) -> results`` — the single-phase form.
-  It runs exactly once per batch, in the completer stage, with no
-  extra device barriers added around it; assembly of the next batch
-  still overlaps its compute.
-* a two-phase object with ``dispatch(items) -> handle`` (enqueue
-  device work, return immediately — lean on JAX async dispatch) and
-  ``collect(handle) -> results`` (device barrier + host decode) —
-  see :class:`TwoPhaseBatchFn`. This is the form that overlaps the
-  *enqueue* of batch N+1 with the *barrier* of batch N.
+``batch_fn`` is a pair: ``dispatch(items) -> handle`` (enqueue device
+work, return immediately — lean on JAX async dispatch) runs on the
+collector and ``collect(handle) -> results`` (device barrier + host
+decode) on the completer, so the *enqueue* of batch N+1 overlaps the
+*barrier* of batch N — see :class:`TwoPhaseBatchFn`. A plain callable
+``(items) -> results`` is taken as the pair whose dispatch hands the
+items on: it runs exactly once per batch, as the collect, with no
+extra device barriers added around it.
 
 Overload discipline (docs/robustness.md "Overload & backpressure"):
 the wait queue is criticality- and deadline-aware. When backlog
@@ -47,7 +44,8 @@ the batcher records batch occupancy, queue depth, device-dispatch time
 ``pio_device_sync_seconds`` around the end-to-end
 ``pio_device_dispatch_seconds``), dispatched/shed/cancelled counts —
 the queue instrumentation the Podracer line of work treats as a
-prerequisite for scaling. Each slot carries the submitting request's
+prerequisite for scaling; built without a registry it counts into a
+private one. Each slot carries the submitting request's
 ID (from the obs contextvar), so a slow or failing dispatch logs
 exactly which requests rode in it.
 """
@@ -88,6 +86,15 @@ _GAP_WEIGHT = 0.5
 _GAP_CAP_WINDOWS = 3.0
 
 
+#: what a ``pipeline_depth`` below 1 is answered with, wherever it is
+#: given (here, ``EngineServer``, ``pio-tpu deploy --pipeline-depth``)
+DEPTH_ZERO_GONE = (
+    "pipeline_depth must be 1 or more, got {}: the serial batcher "
+    "(depth 0, dispatch and collect on one thread) is gone — on the "
+    "chip it lost or tied in every cell (PERF.md section 6, PR 30)"
+)
+
+
 class BatcherOverloaded(Exception):
     """Queue depth bound hit — shed the request instead of queuing it.
 
@@ -98,7 +105,7 @@ class BatcherOverloaded(Exception):
 
 
 class TwoPhaseBatchFn:
-    """The pipelined ``batch_fn`` protocol: enqueue now, sync later.
+    """The ``batch_fn`` protocol: enqueue now, sync later.
 
     ``dispatch(items) -> handle`` must enqueue the device work and
     return without blocking on it (JAX async dispatch makes this the
@@ -149,44 +156,6 @@ class _Inflight(NamedTuple):
     enqueue_s: float
     traced: bool
     seq: int  # the batch's number, for the completer's stage keywords
-
-
-class _NullMetrics:
-    """Registry-free fast path: every hook is a no-op."""
-
-    __slots__ = ()
-
-    def queue_depth(self, n: int) -> None:
-        pass
-
-    def shed(self, criticality: str) -> None:
-        pass
-
-    def dispatched(self, occupancy: int, seconds: float) -> None:
-        pass
-
-    def window_waited(self) -> None:
-        pass
-
-    def enqueued(self, seconds: float) -> None:
-        pass
-
-    def synced(self, seconds: float) -> None:
-        pass
-
-    def cancelled(self, n: int) -> None:
-        pass
-
-    def expired(self, n: int) -> None:
-        pass
-
-    def leaked(self) -> None:
-        pass
-
-    def attributed(
-        self, tenant: str, device_s: float, wait_s: float, status: str
-    ) -> None:
-        pass
 
 
 #: queue-wait budget a tenant's requests must beat for the tenant to
@@ -323,16 +292,16 @@ class _BatcherMetrics:
         ).labels(name)
         self._enqueue = registry.histogram(
             "pio_device_enqueue_seconds",
-            "Host time enqueuing one batch to the device (two-phase "
-            "dispatch(); ~0 for single-phase batch_fns)",
+            "Host time enqueuing one batch to the device (dispatch(); "
+            "~0 where it only hands the items on)",
             ("batcher",),
             buckets=LATENCY_BUCKETS,
         ).labels(name)
         self._sync = registry.histogram(
             "pio_device_sync_seconds",
             "Device barrier, transfer to the host and result "
-            "materialization of one batch (two-phase collect(), or the "
-            "whole single-phase batch_fn)",
+            "materialization of one batch (collect(); a batch_fn that "
+            "is a plain callable runs here whole)",
             ("batcher",),
             buckets=LATENCY_BUCKETS,
         ).labels(name)
@@ -438,9 +407,9 @@ class _BatcherMetrics:
         self._tenant_device.labels(tenant).inc(device_s)
         self._tenant_wait.labels(tenant).observe(wait_s)
         self._tenant_requests.labels(tenant, status).inc()
-        # settlement runs on the completer AND the collector (serial /
-        # dispatch-failure paths); the rollup's read-modify-write needs
-        # its own tiny guard
+        # settlement runs on the completer AND the collector (a failed
+        # dispatch); the rollup's read-modify-write needs its own tiny
+        # guard
         with self._attr_lock:
             self._noisy.observe(tenant, device_s, wait_s)
 
@@ -466,9 +435,8 @@ class MicroBatcher:
     shedding rather than client-side timeout hangs.
 
     ``pipeline_depth`` bounds batches in flight between device enqueue
-    and collected results (default 2 = double buffering; 0 = the
-    pre-pipeline serial behavior, everything inline on one thread —
-    the baseline ``scripts/serving_bench.py`` measures against).
+    and collected results (default 2 = double buffering; at least 1:
+    dispatch and collect always run on two threads).
 
     Returned futures support ``cancel()`` up to the moment their batch
     is dispatched: a cancelled slot is dropped from the batch (its
@@ -500,16 +468,17 @@ class MicroBatcher:
         pipeline_depth: int = 2,
         adaptive_wait: bool = True,
     ):
-        if hasattr(batch_fn, "dispatch") and hasattr(batch_fn, "collect"):
-            self._dispatch_fn = batch_fn.dispatch
-            self._collect_fn = batch_fn.collect
-        else:
-            # single-phase compatibility: the whole batch_fn runs as
-            # the collect stage (so next-batch assembly still overlaps
-            # its compute) and is called exactly once per batch — no
-            # wrapper barriers
-            self._dispatch_fn = None
-            self._collect_fn = batch_fn
+        if pipeline_depth < 1:
+            raise ValueError(DEPTH_ZERO_GONE.format(pipeline_depth))
+        if not (
+            hasattr(batch_fn, "dispatch") and hasattr(batch_fn, "collect")
+        ):
+            # a plain callable is the pair whose dispatch hands the
+            # items on: it runs once a batch, as the collect stage, so
+            # next-batch assembly still overlaps its compute
+            batch_fn = TwoPhaseBatchFn(lambda items: items, batch_fn)
+        self._dispatch_fn = batch_fn.dispatch
+        self._collect_fn = batch_fn.collect
         self._max_batch = max_batch
         self._max_wait = max_wait_ms / 1000.0
         self._adaptive = adaptive_wait
@@ -524,10 +493,9 @@ class MicroBatcher:
             max_queue if max_queue is not None else 8 * max_batch
         )
         self.name = name
-        self._metrics = (
-            _BatcherMetrics(registry, name)
-            if registry is not None
-            else _NullMetrics()
+        # a batcher built without a registry counts into one of its own
+        self._metrics = _BatcherMetrics(
+            registry if registry is not None else MetricRegistry(), name
         )
         #: where both worker threads time their stages (and the model's
         #: predict.* stages that run on them)
@@ -541,18 +509,15 @@ class MicroBatcher:
         #: EWMA of end-to-end batch seconds — feeds retry_after_s().
         #: Guarded by the cv: the settle path runs on BOTH worker
         #: threads (completer normally, collector for dispatch-phase
-        #: failures and the serial fallback), so the read-modify-write
-        #: would otherwise lose updates between them
+        #: failures), so the read-modify-write would otherwise lose
+        #: updates between them
         self._batch_ewma_s = 0.0
-        self._pipeline_depth = max(0, pipeline_depth)
-        self._completer: threading.Thread | None = None
-        if self._pipeline_depth > 0:
-            self._pending: queue.Queue = queue.Queue()
-            self._inflight = threading.Semaphore(self._pipeline_depth)
-            self._completer = threading.Thread(
-                target=self._complete_loop, daemon=True
-            )
-            self._completer.start()
+        self._pending: queue.Queue = queue.Queue()
+        self._inflight = threading.Semaphore(pipeline_depth)
+        self._completer = threading.Thread(
+            target=self._complete_loop, daemon=True
+        )
+        self._completer.start()
         self._thread = threading.Thread(target=self._loop, daemon=True)
         self._thread.start()
 
@@ -695,21 +660,17 @@ class MicroBatcher:
             self._cv.notify_all()  # wake the collector to drain
         join_deadline = time.monotonic() + self._close_join_timeout_s
         self._thread.join(timeout=self._close_join_timeout_s)
-        leaked = self._thread.is_alive()
-        if self._completer is not None:
-            # the completer sentinel is sent by the collector alone
-            # (end of its drain loop). If the collector is hung we do
-            # NOT inject one here: it could overtake a batch the stuck
-            # collector is still about to hand off, and an exited
-            # completer would strand that batch's futures forever. Both
-            # threads are daemons — if the collector ever unblocks it
-            # drains, sends the real sentinel, and the futures resolve
-            # late instead of never.
-            self._completer.join(
-                timeout=max(0.1, join_deadline - time.monotonic())
-            )
-            leaked = leaked or self._completer.is_alive()
-        if leaked:
+        # the completer sentinel is sent by the collector alone (end of
+        # its drain loop). If the collector is hung we do NOT inject
+        # one here: it could overtake a batch the stuck collector is
+        # still about to hand off, and an exited completer would strand
+        # that batch's futures forever. Both threads are daemons — if
+        # the collector ever unblocks it drains, sends the real
+        # sentinel, and the futures resolve late instead of never.
+        self._completer.join(
+            timeout=max(0.1, join_deadline - time.monotonic())
+        )
+        if self._thread.is_alive() or self._completer.is_alive():
             self._metrics.leaked()
             log_json(
                 logger, logging.WARNING, "batcher_thread_leaked",
@@ -785,8 +746,7 @@ class MicroBatcher:
                     batch = self._select_batch()
             self._stages.bind(batch=seq, n=len(batch))
             self._dispatch_batch(batch, seq)
-        if self._completer is not None:
-            self._pending.put(None)  # completer drains in order, then exits
+        self._pending.put(None)  # completer drains in order, then exits
 
     def _dispatch_batch(self, batch, seq: int) -> None:
         # backpressure BEFORE the cancellation/deadline cutoff: while
@@ -794,9 +754,8 @@ class MicroBatcher:
         # exhausted) waiters can still cancel and budgets can still
         # expire — the cutoff below must be the last word before the
         # device sees the work
-        if self._completer is not None:
-            with tracing.stage(tracing.BATCH_BACKPRESSURE):
-                self._inflight.acquire()
+        with tracing.stage(tracing.BATCH_BACKPRESSURE):
+            self._inflight.acquire()
         # transition every slot to running; cancelled slots drop out
         # HERE, before the device sees them — cancellation is how an
         # abandoning caller turns wasted dispatch into avoided dispatch.
@@ -827,8 +786,7 @@ class MicroBatcher:
                 batcher=self.name, expired=expired,
             )
         if not live:
-            if self._completer is not None:
-                self._inflight.release()
+            self._inflight.release()
             return
         # dispatch-span bookkeeping only when at least one slot was
         # submitted under an open trace — untraced traffic pays nothing
@@ -837,30 +795,22 @@ class MicroBatcher:
         # dispatch-start is stamped unconditionally: queue-wait
         # attribution (submit -> dispatch) covers untraced traffic too
         start_mono = time.monotonic()
-        if self._completer is None:
-            self._flush_serial(live, start_wall, start_mono, traced)
-            return
         items = [slot.item for slot in live]
         t0 = time.perf_counter()
-        if self._dispatch_fn is None:
-            # single-phase: the handle is the items; batch_fn runs once
-            # in the completer
-            handle, enqueue_s = items, 0.0
-        else:
-            try:
-                handle = self._dispatch_fn(items)
-            except Exception as e:  # noqa: BLE001 - propagate to waiters
-                self._inflight.release()
-                enqueue_s = time.perf_counter() - t0
-                self._metrics.enqueued(enqueue_s)
-                self._settle_failure(
-                    live, e, time.perf_counter() - t0,
-                    start_wall, start_mono, traced,
-                    enqueue_s=enqueue_s, sync_s=0.0, phase="dispatch",
-                )
-                return
+        try:
+            handle = self._dispatch_fn(items)
+        except Exception as e:  # noqa: BLE001 - propagate to waiters
+            self._inflight.release()
             enqueue_s = time.perf_counter() - t0
             self._metrics.enqueued(enqueue_s)
+            self._settle(
+                live, e, time.perf_counter() - t0,
+                start_wall, start_mono, traced,
+                enqueue_s=enqueue_s, sync_s=0.0, phase="dispatch",
+            )
+            return
+        enqueue_s = time.perf_counter() - t0
+        self._metrics.enqueued(enqueue_s)
         self._pending.put(
             _Inflight(
                 live, handle, start_wall, start_mono, t0, enqueue_s,
@@ -884,76 +834,32 @@ class MicroBatcher:
                     # — attribution charges exactly what was observed,
                     # success or failure (conservation)
                     try:
-                        results = self._collect_fn(rec.handle)
+                        outcome = self._collect_fn(rec.handle)
                     finally:
                         sync_s = time.perf_counter() - t1
                         self._metrics.synced(sync_s)
-                    if len(results) != len(rec.live):
+                    if len(outcome) != len(rec.live):
                         raise RuntimeError(
-                            f"batch_fn returned {len(results)} results "
+                            f"batch_fn returned {len(outcome)} results "
                             f"for {len(rec.live)} items"
                         )
                 except Exception as e:  # noqa: BLE001 - to every waiter
-                    self._settle_failure(
-                        rec.live, e, time.perf_counter() - rec.t0,
-                        rec.start_wall, rec.start_mono, rec.traced,
-                        enqueue_s=rec.enqueue_s,
-                        sync_s=sync_s,
-                        phase="collect",
-                    )
-                    continue
-                self._settle_success(
-                    rec.live, results, time.perf_counter() - rec.t0,
+                    outcome = e
+                self._settle(
+                    rec.live, outcome, time.perf_counter() - rec.t0,
                     rec.start_wall, rec.start_mono, rec.traced,
                     enqueue_s=rec.enqueue_s, sync_s=sync_s,
+                    phase="collect",
                 )
             finally:
                 self._inflight.release()
 
-    # -- serial fallback (pipeline_depth=0) --------------------------------
-    def _flush_serial(
-        self, live, start_wall: float, start_mono: float, traced: bool
-    ) -> None:
-        """The pre-pipeline inline path: enqueue + sync back to back on
-        the collector thread. Kept for apples-to-apples benchmarking
-        and as an escape hatch (``pipeline_depth=0``)."""
-        items = [slot.item for slot in live]
-        t0 = time.perf_counter()
-        enqueue_s = 0.0
-        try:
-            if self._dispatch_fn is None:
-                handle = items
-            else:
-                handle = self._dispatch_fn(items)
-                enqueue_s = time.perf_counter() - t0
-                self._metrics.enqueued(enqueue_s)
-            t1 = time.perf_counter()
-            results = self._collect_fn(handle)
-            sync_s = time.perf_counter() - t1
-            self._metrics.synced(sync_s)
-            if len(results) != len(items):
-                raise RuntimeError(
-                    f"batch_fn returned {len(results)} results for "
-                    f"{len(items)} items"
-                )
-        except Exception as e:  # noqa: BLE001 - propagate to every waiter
-            self._settle_failure(
-                live, e, time.perf_counter() - t0, start_wall,
-                start_mono, traced, enqueue_s=enqueue_s, sync_s=0.0,
-                phase="serial",
-            )
-            return
-        self._settle_success(
-            live, results, time.perf_counter() - t0, start_wall,
-            start_mono, traced, enqueue_s=enqueue_s, sync_s=sync_s,
-        )
-
     # -- shared settlement -------------------------------------------------
     def _observe_batch_time(self, elapsed: float) -> None:
         # feeds retry_after_s(). Settlement runs on the completer OR
-        # the collector (dispatch-phase failure, serial fallback), so
-        # the EWMA fold takes the cv — both writers and the
-        # retry_after_s() reader agree on one guard
+        # the collector (dispatch-phase failure), so the EWMA fold
+        # takes the cv — both writers and the retry_after_s() reader
+        # agree on one guard
         with self._cv:
             self._batch_ewma_s = (
                 elapsed
@@ -977,54 +883,48 @@ class MicroBatcher:
                 status,
             )
 
-    def _settle_success(
-        self, live, results, elapsed: float, start_wall: float,
+    def _settle(
+        self, live, outcome, elapsed: float, start_wall: float,
         start_mono: float, traced: bool, enqueue_s: float, sync_s: float,
+        phase: str,
     ) -> None:
+        """Resolve a batch's futures: ``outcome`` is its results, one a
+        slot, or the exception every waiter gets (raised in ``phase``)."""
+        failed = isinstance(outcome, Exception)
+        error = f"{type(outcome).__name__}: {outcome}" if failed else None
         with tracing.stage(tracing.BATCH_SETTLE):
             self._observe_batch_time(elapsed)
             self._metrics.dispatched(len(live), elapsed)
-            self._attribute(live, start_mono, enqueue_s, sync_s, "ok")
+            self._attribute(
+                live, start_mono, enqueue_s, sync_s,
+                "error" if failed else "ok",
+            )
             if traced:
                 self._record_dispatch_spans(
                     live, start_wall, start_mono, elapsed,
-                    enqueue_s=enqueue_s, sync_s=sync_s,
+                    enqueue_s=enqueue_s, sync_s=sync_s, error=error,
                 )
+            request_ids = [s.request_id for s in live if s.request_id]
+            if failed:
+                log_json(
+                    logger, logging.WARNING, "batch_dispatch_failed",
+                    batcher=self.name, occupancy=len(live), phase=phase,
+                    ms=round(elapsed * 1000, 3), error=error,
+                    requestIds=request_ids,
+                )
+                for slot in live:
+                    if not slot.future.done():
+                        slot.future.set_exception(outcome)
+                return
             log_json(
                 logger, logging.DEBUG, "batch_dispatch",
                 batcher=self.name, occupancy=len(live),
                 ms=round(elapsed * 1000, 3),
                 enqueueMs=round(enqueue_s * 1000, 3),
-                requestIds=[s.request_id for s in live if s.request_id],
+                requestIds=request_ids,
             )
-            for slot, result in zip(live, results):
+            for slot, result in zip(live, outcome):
                 slot.future.set_result(result)
-
-    def _settle_failure(
-        self, live, exc: Exception, elapsed: float, start_wall: float,
-        start_mono: float, traced: bool, enqueue_s: float, sync_s: float,
-        phase: str,
-    ) -> None:
-        with tracing.stage(tracing.BATCH_SETTLE):
-            self._observe_batch_time(elapsed)
-            self._metrics.dispatched(len(live), elapsed)
-            self._attribute(live, start_mono, enqueue_s, sync_s, "error")
-            if traced:
-                self._record_dispatch_spans(
-                    live, start_wall, start_mono, elapsed,
-                    enqueue_s=enqueue_s, sync_s=sync_s,
-                    error=f"{type(exc).__name__}: {exc}",
-                )
-            log_json(
-                logger, logging.WARNING, "batch_dispatch_failed",
-                batcher=self.name, occupancy=len(live), phase=phase,
-                ms=round(elapsed * 1000, 3),
-                error=f"{type(exc).__name__}: {exc}",
-                requestIds=[s.request_id for s in live if s.request_id],
-            )
-            for slot in live:
-                if not slot.future.done():
-                    slot.future.set_exception(exc)
 
     def _record_dispatch_spans(
         self, live, start_wall: float, start_mono: float,

@@ -48,7 +48,6 @@ import urllib.request
 from concurrent.futures import TimeoutError as FuturesTimeout
 from typing import Any
 
-from predictionio_tpu.core.controller import Algorithm
 from predictionio_tpu.core.engine import Engine, EngineParams
 from predictionio_tpu.core.workflow import load_deployment
 from predictionio_tpu.data.datamap import DataMap
@@ -69,6 +68,7 @@ from predictionio_tpu.serving import modelpool as modelpool_mod
 from predictionio_tpu.serving import querycache as querycache_mod
 from predictionio_tpu.serving import resilience
 from predictionio_tpu.serving.batching import (
+    DEPTH_ZERO_GONE,
     BatcherOverloaded,
     MicroBatcher,
     TwoPhaseBatchFn,
@@ -152,6 +152,10 @@ class EngineServer:
         self._max_batch = max_batch
         self._max_wait_ms = max_wait_ms
         self._max_queue = max_queue
+        if pipeline_depth < 1:
+            # here and not at the first batcher: a pool builds its
+            # batchers when a tenant loads
+            raise ValueError(DEPTH_ZERO_GONE.format(pipeline_depth))
         self._pipeline_depth = pipeline_depth
         self._adaptive_wait = adaptive_wait
         self._predict_timeout_s = predict_timeout_s
@@ -224,14 +228,10 @@ class EngineServer:
         # multi-tenant mode (docs/serving.md "Multi-tenant serving"):
         # one process serves N engine variants through a byte-budgeted
         # device model pool keyed by accessKey/X-PIO-Tenant. Tables
-        # quantize per PIO_POOL_QUANT (int8|bf16|"" = off) so many
-        # catalogs fit one chip's HBM.
+        # quantize per ``quantize`` (int8|bf16; None or "" = f32) so
+        # many catalogs fit one chip's HBM.
         self._tenants = dict(tenants) if tenants else None
-        self._quantize = (
-            quantize
-            if quantize is not None
-            else os.environ.get("PIO_POOL_QUANT", "").strip()
-        )
+        self._quantize = quantize or ""
         if self._quantize and self._quantize not in ("int8", "bf16"):
             raise ValueError(
                 f"unknown quantize mode {self._quantize!r} "
@@ -372,7 +372,7 @@ class EngineServer:
                 # the limit must never starve the device: one full
                 # pipeline of batches stays admissible
                 min_limit=float(
-                    self._max_batch * (max(0, self._pipeline_depth) + 1)
+                    self._max_batch * (self._pipeline_depth + 1)
                 ),
             )
         elif isinstance(admission, admission_mod.AdmissionController):
@@ -663,45 +663,14 @@ class EngineServer:
         )
 
         def batch_fn(a, m):
-            has_launch = (
-                type(a).batch_predict_launch
-                is not Algorithm.batch_predict_launch
+            # the collector enqueues batch N+1's device work while the
+            # completer is still inside batch N's barrier + per-query
+            # JSON materialization
+            launch, collect = a.serving_hooks()
+            return TwoPhaseBatchFn(
+                lambda qs: (launch(m, qs), qs),
+                lambda state: collect(m, *state),
             )
-            has_collect = (
-                type(a).batch_predict_collect
-                is not Algorithm.batch_predict_collect
-            )
-            if has_launch != has_collect:
-                # wiring half a protocol into the pipeline would fail
-                # every request at serve time with NotImplementedError;
-                # fall back to single-phase and say so at load
-                logger.warning(
-                    "%s overrides only one of batch_predict_launch/"
-                    "batch_predict_collect — serving single-phase",
-                    type(a).__name__,
-                )
-            if has_launch and has_collect:
-                # two-phase: the collector enqueues batch N+1's device
-                # work while the completer is still inside batch N's
-                # barrier + per-query JSON materialization
-                def dispatch(qs):
-                    return a.batch_predict_launch(m, qs), qs
-
-                def collect(state):
-                    handle, qs = state
-                    return a.batch_predict_collect(m, handle, qs)
-
-                return TwoPhaseBatchFn(dispatch, collect)
-
-            def single(qs):
-                out = a.batch_predict(m, qs)
-                # device barrier before the batcher stops its sync
-                # clock: async dispatch would otherwise make
-                # pio_device_sync_seconds measure enqueue, not work
-                profiling.sync(out)
-                return out
-
-            return single
 
         batchers = [
             MicroBatcher(

@@ -2,12 +2,6 @@
 # Repo check gate: the ROADMAP.md tier-1 pytest run plus a live
 # /metrics scrape smoke test, so telemetry regressions fail fast.
 # Usage: scripts/check.sh [--smoke-only]
-#
-# PIO_SKIP_KNOWN_FAILURES=1 deselects the tests listed in
-# scripts/known_failures.txt (the repo's accepted pre-existing failure
-# set — see CHANGES.md "identical failure set"). CI sets it so the
-# gate is green on a healthy tree and red only on NEW breakage;
-# local runs keep reporting the full picture by default.
 set -u -o pipefail
 
 cd "$(dirname "$0")/.."
@@ -60,24 +54,11 @@ fi
 
 if [ "${1:-}" != "--smoke-only" ]; then
     echo "== tier-1 pytest (ROADMAP.md) =="
-    skip_args=()
-    if [ "${PIO_SKIP_KNOWN_FAILURES:-}" = "1" ] \
-        && [ -f scripts/known_failures.txt ]; then
-        while IFS= read -r entry; do
-            case "$entry" in
-                ''|'#'*) ;;
-                *::*) skip_args+=("--deselect=$entry") ;;
-                *)     skip_args+=("--ignore=$entry") ;;  # whole file
-            esac
-        done < scripts/known_failures.txt
-        echo "(skipping ${#skip_args[@]} known-failing entries)"
-    fi
     rm -f /tmp/_t1.log
     timeout -k 10 870 env JAX_PLATFORMS=cpu \
         python -m pytest tests/ -q -m 'not slow' \
         --continue-on-collection-errors -p no:cacheprovider \
-        -p no:xdist -p no:randomly \
-        ${skip_args[@]+"${skip_args[@]}"} 2>&1 | tee /tmp/_t1.log
+        -p no:xdist -p no:randomly 2>&1 | tee /tmp/_t1.log
     t1_rc=${PIPESTATUS[0]}
     echo "DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log | tr -cd . | wc -c)"
     if [ "$t1_rc" -ne 0 ]; then

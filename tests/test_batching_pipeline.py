@@ -1,5 +1,5 @@
 """Pipelined micro-batching: two-phase dispatch, failure modes, the
-adaptive coalescing window, and the single-phase compatibility path
+adaptive coalescing window, and a plain callable taken as the pair
 (docs/serving.md "Pipelined dispatch").
 
 The pipeline's invariants under failure matter more than its happy
@@ -319,24 +319,12 @@ class TestSinglePhaseCompat:
         assert len(calls) == batches
         assert sum(len(c) for c in calls) == 24
 
-    def test_serial_depth_zero_still_works(self):
-        calls = []
-
-        def batch_fn(items):
-            calls.append(list(items))
-            return [i + 1 for i in items]
-
-        b = MicroBatcher(
-            batch_fn, max_batch=4, max_wait_ms=1, pipeline_depth=0,
-        )
-        try:
-            futures = [b.submit(i) for i in range(9)]
-            assert [f.result(5) for f in futures] == [
-                i + 1 for i in range(9)
-            ]
-            assert sum(len(c) for c in calls) == 9
-        finally:
-            b.close()
+    @pytest.mark.parametrize("depth", [0, -1])
+    def test_depth_below_one_is_refused(self, depth):
+        """The serial mode is gone: dispatch and collect always have a
+        thread each, and asking for none says why."""
+        with pytest.raises(ValueError, match="serial batcher .* is gone"):
+            MicroBatcher(lambda items: items, pipeline_depth=depth)
 
 
 #: the window and the two gaps of the arrival processes below: generous

@@ -612,6 +612,13 @@ class TestDeployFlags:
         )
         assert code != 0 and "max-batch" in err
 
+    def test_pipeline_depth_zero_rejected_with_the_reason(self, cli):
+        code, _out, err = cli(
+            "deploy", "--variant", "nope.json", "--pipeline-depth", "0"
+        )
+        assert code != 0 and "--pipeline-depth" in err
+        assert "serial batcher" in err and "is gone" in err
+
     def test_variant_mesh_conf_used_and_recorded(
         self, cli, memory_storage, tmp_path
     ):

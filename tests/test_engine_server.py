@@ -815,6 +815,15 @@ class TestTwoPhaseServing:
             es.close()
 
 
+def test_engine_server_refuses_pipeline_depth_zero(ctx, memory_storage):
+    """At construction, not at a pool's first tenant load."""
+    with pytest.raises(ValueError, match="serial batcher .* is gone"):
+        EngineServer(
+            _engine(), _params(), engine_id="srv-d0",
+            storage=memory_storage, ctx=ctx, pipeline_depth=0,
+        )
+
+
 class TestWarmupFailureGauge:
     def test_all_failed_warmup_reports_cold(self, ctx, memory_storage):
         """pio_warmup_complete must stay 0 when every bucket compile

@@ -11,6 +11,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from predictionio_tpu.ops import similarity
 from predictionio_tpu.ops.pallas_topk import fused_top_k_dot
 from predictionio_tpu.ops.similarity import _top_k_dot_xla, top_k_dot
 
@@ -114,17 +115,17 @@ class TestFusedTopK:
 
 
 class TestDispatch:
-    def test_env_override_off_forces_xla(self, monkeypatch):
-        monkeypatch.setenv("PIO_PALLAS_TOPK", "0")
+    def test_xla_side_of_the_choice(self, monkeypatch):
+        monkeypatch.setattr(similarity, "_use_pallas", lambda *a, **k: False)
         q, items = _random(2, 50, 4)
         s, i = top_k_dot(q, items, 3)
         xs, xi = _top_k_dot_xla(q, items, 3)
         assert (np.asarray(i) == np.asarray(xi)).all()
 
-    def test_env_override_on_forces_pallas_interpreter(self, monkeypatch):
-        # on the CPU backend a forced override must route through the
+    def test_kernel_side_runs_the_interpreter_off_tpu(self, monkeypatch):
+        # on the CPU backend the kernel's side must route through the
         # Pallas interpreter, not try to compile Mosaic
-        monkeypatch.setenv("PIO_PALLAS_TOPK", "1")
+        monkeypatch.setattr(similarity, "_use_pallas", lambda *a, **k: True)
         q, items = _random(2, 300, 4, seed=7)
         s, i = top_k_dot(q, items, 3)
         xs, xi = _top_k_dot_xla(q, items, 3)

@@ -118,10 +118,10 @@ class TestQuantizedTopK:
         )
         assert (np.asarray(pi) >= 250).all()
 
-    def test_env_override_routes_quantized_through_interpreter(
+    def test_kernel_side_routes_quantized_through_interpreter(
         self, monkeypatch
     ):
-        monkeypatch.setenv("PIO_PALLAS_TOPK", "1")
+        monkeypatch.setattr(similarity, "_use_pallas", lambda *a, **k: True)
         x = _tables(300, 8, seed=8)
         qf = quantize.quantize_factors(x, "int8")
         q = jnp.asarray(_tables(3, 8, seed=9))
@@ -258,14 +258,16 @@ class TestDispatcherThreshold:
         assert jax.default_backend() == "cpu"
         assert not _use_pallas(4096, 10_000_000)
 
-    def test_env_override_beats_threshold(self, monkeypatch):
+    def test_no_environment_variable_steers_the_choice(self, monkeypatch):
+        """The platform and the shape choose; the variable that once
+        overrode them is read by nothing."""
         monkeypatch.setenv("PIO_PALLAS_TOPK", "0")
         monkeypatch.setattr(
             jax, "default_backend", lambda: "tpu"
         )
-        assert not _use_pallas(4096, 10_000_000)
+        assert _use_pallas(4096, 10_000_000)
         monkeypatch.setenv("PIO_PALLAS_TOPK", "1")
-        assert _use_pallas(1, 1)
+        assert not _use_pallas(1, 1)
 
 
 class TestNestedResidentBytes:
