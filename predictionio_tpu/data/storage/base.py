@@ -420,6 +420,30 @@ class EventsBackend(abc.ABC):
         bound is involved. ``None`` means: read the events every time."""
         return None
 
+    def entity_targets(
+        self,
+        app_id: int,
+        channel_id: int | None,
+        entity_type: str,
+        entity_id: str,
+        event_names: Iterable[str],
+    ) -> list[str]:
+        """The ``target_entity_id`` of every event of the entity whose
+        name is in ``event_names`` and that has a target, in no promised
+        order: what a serve-time rule needs of "the items this user has
+        seen". A backend with an entity index answers from it."""
+        return [
+            e.target_entity_id
+            for e in self.find(
+                app_id,
+                channel_id,
+                entity_type=entity_type,
+                entity_id=entity_id,
+                event_names=list(event_names),
+            )
+            if e.target_entity_id is not None
+        ]
+
     def aggregate_properties(
         self,
         app_id: int,

@@ -13,7 +13,7 @@ import itertools
 import operator
 import threading
 import uuid
-from typing import Iterator, Sequence
+from typing import Iterable, Iterator, Sequence
 
 from predictionio_tpu.data.event import Event
 from predictionio_tpu.data.storage.base import (
@@ -402,6 +402,27 @@ class MemoryEvents(EventsBackend):
         if table is None:
             return 0
         return table.versions.get((entity_type, entity_id), 0)
+
+    def entity_targets(
+        self,
+        app_id: int,
+        channel_id: int | None,
+        entity_type: str,
+        entity_id: str,
+        event_names: Iterable[str],
+    ) -> list[str]:
+        names = frozenset(event_names)
+        with self._lock:
+            table = self._store.get(self._key(app_id, channel_id))
+            bucket = table and table.by_entity.get((entity_type, entity_id))
+            if not bucket:
+                return []
+            # the index's own bucket: no copy, no order, no `Event` kept
+            return [
+                e.target_entity_id
+                for e in bucket.values()
+                if e.event in names and e.target_entity_id is not None
+            ]
 
     def find(
         self,

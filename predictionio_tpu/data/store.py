@@ -16,7 +16,7 @@ Counterpart of the reference's ``data/.../store`` package:
 from __future__ import annotations
 
 import datetime as _dt
-from typing import Iterator, Sequence
+from typing import Iterable, Iterator, Sequence
 
 from predictionio_tpu.data.datamap import PropertyMap
 from predictionio_tpu.data.event import Event
@@ -46,6 +46,16 @@ class EntityReader:
         between; None where the backend keeps none."""
         return self._events.entity_version(
             self._app_id, self._channel_id, entity_type, entity_id
+        )
+
+    def targets(
+        self, entity_type: str, entity_id: str, event_names: Iterable[str]
+    ) -> list[str]:
+        """`EventsBackend.entity_targets` of the entity: the targets of
+        its events of these names, in no promised order."""
+        return self._events.entity_targets(
+            self._app_id, self._channel_id, entity_type, entity_id,
+            event_names,
         )
 
     def find(

@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+from itertools import repeat
 
 import jax
 import jax.numpy as jnp
@@ -72,6 +73,9 @@ RECENT_VIEWS = 10
 #: users whose resolved seen items a tenant keeps beside the store's
 #: version of them; past it the oldest half goes
 _SEEN_CACHE_USERS = 1 << 18
+#: the item rows of a query that names none and whose user has seen none
+_NO_ROWS = np.empty(0, np.int32)
+_NO_ROWS.setflags(write=False)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -214,6 +218,21 @@ def _bucket(n: int) -> int:
     return 1 << max(0, n - 1).bit_length()
 
 
+def _listed_rows(get, black, white, seen: np.ndarray) -> np.ndarray:
+    """The item rows of one query's list: what to leave out (``seen`` and
+    the blackList), or with a whiteList what alone may come back (white -
+    black - seen). ``get`` maps an item id to its row; an id the model
+    does not know is dropped."""
+    out = [get(str(x), -1) for x in black]
+    if not white:
+        return np.concatenate(
+            [seen, np.array([r for r in out if r >= 0], np.int32)]
+        )
+    rows = {get(str(x), -1) for x in white}
+    rows.difference_update(out, seen.tolist(), (-1,))
+    return np.fromiter(rows, np.int32, len(rows))
+
+
 class _Counters:
     """The template's counters in one registry."""
 
@@ -240,6 +259,13 @@ class _Counters:
             "pio_ecomm_rule_lookups_total",
             "Entities the predict path asked the event store about",
         )
+        self.seen_lookups = registry.counter(
+            "pio_ecomm_seen_lookups_total",
+            "Queries whose user's seen item rows were kept from an "
+            "earlier read at the same entity version (hit), or read "
+            "from the store's entity index (miss)",
+            ("result",),
+        )
         self.short = registry.counter(
             "pio_ecomm_short_answers_total",
             "E-commerce answers with fewer items than the query's num",
@@ -252,6 +278,9 @@ class _Counters:
         self.rule = {
             r: self.filtered.labels(r)
             for r in ("categories", "whiteList", "blackList")
+        }
+        self.seen = {
+            r: self.seen_lookups.labels(r) for r in ("hit", "miss")
         }
 
     @classmethod
@@ -385,30 +414,38 @@ class ECommAlgorithm(Algorithm):
             )
         rules.constraint = (version, event_id)
 
-    def _seen_rows(self, model, rules: StagedRules, reader, user: str):
-        """Item rows of the user's ``seen_events``, as the store has them
-        now."""
-        version = reader.version("user", user)
-        kept = rules.seen.get(user)
-        if kept is not None and version is not None and kept[0] == version:
-            return kept[1]
+    def _seen_rows(
+        self, model, rules: StagedRules, reader, users, seen, counters
+    ) -> None:
+        """``seen[i]``: item rows of the ``seen_events`` of ``users[i]``,
+        as the store has them now. Rows kept from an earlier read are
+        used again only while the store's version of the user reads the
+        same; otherwise the targets come straight from the store's
+        entity index."""
+        kept_rows, names = rules.seen, frozenset(self.params.seen_events)
         get = model.item_map.getter()
-        found = np.asarray(
-            [
-                get(e.target_entity_id, -1)
-                for e in reader.find(
-                    "user", user, event_names=self.params.seen_events
-                )
-            ],
-            np.int32,
-        )
-        found = found[found >= 0]
-        if version is not None:
-            if len(rules.seen) >= _SEEN_CACHE_USERS:
-                for old in list(rules.seen)[: _SEEN_CACHE_USERS // 2]:
-                    del rules.seen[old]
-            rules.seen[user] = (version, found)
-        return found
+        misses = 0
+        for i, user in enumerate(users):
+            version = reader.version("user", user)
+            kept = kept_rows.get(user)
+            if kept is not None and version is not None and kept[0] == version:
+                seen[i] = kept[1]
+                continue
+            misses += 1
+            # the rows of the ids the model knows, with no numpy call over
+            # a long array (`similarity._HELD`)
+            known = [
+                r for r in map(get, reader.targets("user", user, names))
+                if r is not None
+            ]
+            seen[i] = found = np.fromiter(known, np.int32, len(known))
+            if version is not None:
+                if len(kept_rows) >= _SEEN_CACHE_USERS:
+                    for old in list(kept_rows)[: _SEEN_CACHE_USERS // 2]:
+                        del kept_rows[old]
+                kept_rows[user] = (version, found)
+        counters.seen["miss"].inc(misses)
+        counters.seen["hit"].inc(len(users) - misses)
 
     def _recent_rows(self, model, reader, user: str) -> list[int]:
         """Rows of the items of the user's latest views that the model
@@ -423,19 +460,20 @@ class ECommAlgorithm(Algorithm):
         )
         return [r for r in rows if r >= 0]
 
-    def _read_rules(self, model, rules, reader, users, mode, recent, seen):
-        """The batch's store lookups: the constraint, every user's seen
-        items, and an unknown user's recent views (which make the query
-        SIMILAR)."""
+    def _read_rules(
+        self, model, rules, reader, users, mode, recent, seen, counters
+    ) -> None:
+        """The batch's store lookups: the constraint, an unknown user's
+        recent views (which make the query SIMILAR), and every user's
+        seen items."""
         self._refresh_unavailable(model, rules, reader)
-        for i, user in enumerate(users):
-            if mode[i] != similarity.KNOWN:
-                views = self._recent_rows(model, reader, user)
-                if views:
-                    mode[i] = similarity.SIMILAR
-                    recent[i, : len(views)] = views
-            if self.params.unseen_only:
-                seen[i] = self._seen_rows(model, rules, reader, user)
+        for i in np.flatnonzero(mode != similarity.KNOWN).tolist():
+            views = self._recent_rows(model, reader, users[i])
+            if views:
+                mode[i] = similarity.SIMILAR
+                recent[i, : len(views)] = views
+        if self.params.unseen_only:
+            self._seen_rows(model, rules, reader, users, seen, counters)
 
     def predict(self, model: ECommModel, query: dict) -> dict:
         return self.batch_predict(model, [query])[0]
@@ -464,31 +502,39 @@ class ECommAlgorithm(Algorithm):
                 )
             rules = model.rules
             counters = _Counters.of(tracing.bound_registry())
-            n_items = len(model.item_map)
-            nums = [int(q.get("num", 10)) for q in queries]
+            n, n_items = len(queries), len(model.item_map)
+            # one pass over the queries, then the batch by arrays
+            users, nums, wanted, black, white = zip(*[
+                (
+                    str(q.get("user", "")), int(q.get("num", 10)),
+                    q.get("categories") or (), q.get("blackList") or (),
+                    q.get("whiteList") or (),
+                )
+                for q in queries
+            ])
             num = min(max(1, max(nums)), n_items)
             num_bucket = min(_bucket(num), n_items)
-            users = [str(q.get("user", "")) for q in queries]
-            wanted = [q.get("categories") or () for q in queries]
             # the batch's operands, filled in place through these views
             operands = similarity.QueryRules.blank(
-                _bucket(len(queries)), _bucket(max(1, max(map(len, wanted))))
+                _bucket(n), _bucket(max(1, max(map(len, wanted))))
             )
-            user_idx, mode, recent = operands.idx, operands.mode, operands.recent
-            allow, q_cats = operands.allow, operands.categories
-            seen: list = [()] * len(queries)
-            for i, user in enumerate(users):
-                row = model.user_map.get(user, -1)
-                if row >= 0:
-                    user_idx[i], mode[i] = row, similarity.KNOWN
+            per_query, q_cats = operands.per_query, operands.categories
+            user_rows = np.fromiter(
+                map(model.user_map.getter(), users, repeat(-1)), np.int32, n
+            )
+            mode = np.where(
+                user_rows >= 0, similarity.KNOWN, similarity.POPULAR
+            )
+            lists = [_NO_ROWS] * n  # each user's seen rows, then the rest
             with tracing.stage(tracing.PREDICT_RULES):
                 reader = self._reader(model)
                 try:
                     if reader is not None:
                         self._read_rules(
-                            model, rules, reader, users, mode, recent, seen
+                            model, rules, reader, users, mode,
+                            operands.recent, lists, counters,
                         )
-                        counters.lookups.inc(1 + len(queries))
+                        counters.lookups.inc(1 + n)
                 except Exception as e:  # noqa: BLE001 - serve on
                     # as the reference serves on when its read times out
                     logger.warning(
@@ -496,35 +542,29 @@ class ECommAlgorithm(Algorithm):
                         "(%s: %s): the batch goes without what was not "
                         "read", type(e).__name__, e,
                     )
+            per_query[:n, 0] = np.maximum(user_rows, 0)
+            per_query[:n, 1] = mode
             get = model.item_map.getter()
-            lists = []
-            for i, q in enumerate(queries):
-                black = [get(str(x), -1) for x in q.get("blackList") or ()]
-                white = q.get("whiteList") or ()
-                if black:
-                    counters.rule["blackList"].inc()
-                if white:
-                    counters.rule["whiteList"].inc()
-                    allow[i] = True
-                    out = set(black).union(seen[i])
-                    rows = [
-                        r for r in {get(str(x), -1) for x in white}
-                        if r >= 0 and r not in out
-                    ]
-                else:
-                    rows = [r for r in black if r >= 0]
-                    rows = np.concatenate([seen[i], rows]) if rows else seen[i]
-                lists.append(np.asarray(rows, np.int32))
-                if wanted[i]:
-                    counters.rule["categories"].inc()
-                    # a category the model does not know matches nothing
-                    q_cats[i, : len(wanted[i])] = [
-                        rules.category_ids.get(str(c), similarity.NO_CATEGORY - 1)
-                        for c in wanted[i]
-                    ]
-                counters.branch[int(mode[i])].inc()
+            for i in range(n):
+                if black[i] or white[i]:
+                    lists[i] = _listed_rows(get, black[i], white[i], lists[i])
+                    operands.allow[i] = bool(white[i])
+            filtered = [i for i in range(n) if wanted[i]]
+            category_id = rules.category_ids.get
+            for i in filtered:
+                # a category the model does not know matches nothing
+                q_cats[i, : len(wanted[i])] = [
+                    category_id(str(c), similarity.NO_CATEGORY - 1)
+                    for c in wanted[i]
+                ]
             operands = operands._replace(lists=similarity.pack_lists(lists))
-            counters.excluded.inc(sum(len(x) for x in lists))
+            # the batch's counts, each counter once
+            counters.rule["blackList"].inc(sum(map(bool, black)))
+            counters.rule["whiteList"].inc(sum(map(bool, white)))
+            counters.rule["categories"].inc(len(filtered))
+            for branch, child in counters.branch.items():
+                child.inc(int((mode == branch).sum()))
+            counters.excluded.inc(sum(map(len, lists)))
         with tracing.stage(tracing.PREDICT_ENQUEUE):
             scores, items = similarity.rules_top_k(
                 model.user_factors, model.item_factors, num_bucket,

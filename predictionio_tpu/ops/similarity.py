@@ -11,7 +11,7 @@ examples/scala-parallel-similarproduct/multi/.../ALSAlgorithm.scala).
 
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 from typing import NamedTuple
 
 import jax
@@ -289,12 +289,7 @@ class QueryRules(NamedTuple):
         whole catalog, with ``slots`` category slots and no lists yet: a
         launch fills the views in place and adds ``lists`` last
         (``_replace``), so nothing is copied together."""
-        per_query = np.zeros((batch, 3 + RECENT_SLOTS + slots), np.int32)
-        rules = cls(per_query, None)
-        rules.mode[:] = POPULAR
-        rules.recent[:] = -1
-        rules.categories[:] = NO_CATEGORY
-        return rules
+        return cls(_fresh(_blank_per_query(batch, slots)), None)
 
     # views, of the host arrays here and of the traced ones in the step
     idx = property(lambda r: r.per_query[:, 0])     # [B] user rows
@@ -322,21 +317,77 @@ def list_capacity(total: int) -> int:
     return capacity
 
 
+#: numpy keeps the interpreter lock around a copy, fill or gather of at
+#: most this many elements and lets go of it around a longer one; on a
+#: busy server the launching thread then waits its turn to get the lock
+#: back, about a millisecond each time (PERF.md section 6, PR 31)
+_HELD = 500
+
+
+def _fresh(kept: np.ndarray) -> np.ndarray:
+    """A writable copy of ``kept`` (C-contiguous): the copy is
+    `bytearray`'s, made under the interpreter lock (`_HELD`)."""
+    return np.frombuffer(bytearray(kept), kept.dtype).reshape(kept.shape)
+
+
+def _copy_held(dst: np.ndarray, src) -> None:
+    """``dst[:] = src`` for one-dimensional operands of one length, in
+    steps short enough that the interpreter lock is kept (`_HELD`)."""
+    for at in range(0, len(src), _HELD):
+        dst[at:at + _HELD] = src[at:at + _HELD]
+
+
+@lru_cache(maxsize=64)
+def _blank_per_query(batch: int, slots: int) -> np.ndarray:
+    per_query = np.zeros((batch, 3 + RECENT_SLOTS + slots), np.int32)
+    rules = QueryRules(per_query, None)
+    rules.mode[:] = POPULAR
+    rules.recent[:] = -1
+    rules.categories[:] = NO_CATEGORY
+    per_query.setflags(write=False)
+    return per_query
+
+
+@lru_cache(maxsize=8)
+def _blank_lists(capacity: int) -> np.ndarray:
+    packed = np.zeros((2, capacity), np.int32)
+    packed[1] = NO_ITEM
+    packed.setflags(write=False)
+    return packed
+
+
+@lru_cache(maxsize=64)
+def _row_numbers(rows: int) -> np.ndarray:
+    """0 .. rows-1 as 64-bit little-endian numbers, kept (`np.arange`
+    lets go of the interpreter lock at any length)."""
+    numbers = np.arange(rows, dtype="<i8")
+    numbers.setflags(write=False)
+    return numbers
+
+
 def pack_lists(lists) -> np.ndarray:
     """``lists`` of ``QueryRules`` ([2, N]: list_rows, list_cols) from one
-    int array of item rows per query row."""
+    int array of item rows per query row: every entry as (item row, query
+    row), ascending. Each pair is sorted as one 64-bit number, the item
+    row in its high half: one vectorized sort in place of a stable
+    argsort and two gathers, and the only call here that lets go of the
+    interpreter lock (`_HELD`)."""
     lengths = [len(x) for x in lists]
     total = sum(lengths)
-    packed = np.zeros((2, list_capacity(total)), np.int32)
-    rows, cols = packed
-    cols[total:] = NO_ITEM
+    packed = _fresh(_blank_lists(list_capacity(total)))
     if total:
-        found = np.concatenate(lists)
-        order = np.argsort(found, kind="stable")
-        cols[:total] = found[order]
-        rows[:total] = np.repeat(
-            np.arange(len(lists), dtype=np.int32), lengths
-        )[order]
+        # little-endian by dtype, so the low half is the first of a pair
+        pairs = np.repeat(_row_numbers(len(lists)), lengths)
+        halves = pairs.view("<i4").reshape(total, 2)
+        if max(lengths) > _HELD:  # `np.concatenate` copies piece by piece
+            lists = [
+                x[at:at + _HELD] for x in lists
+                for at in range(0, len(x), _HELD)
+            ]
+        _copy_held(halves[:, 1], np.concatenate(lists))
+        pairs.sort()
+        _copy_held(packed[0, :total], halves[:, 0])
+        _copy_held(packed[1, :total], halves[:, 1])
     return packed
 
 

@@ -1,11 +1,13 @@
 """The e-commerce template's rules before the top-k: the masked top-k
 against a dense numpy mask (XLA side and Pallas side in the interpreter),
 the served answers of mixed batches against the benchmark's plain
-reference item for item, and a write seen by the next query through
-`EngineServer`."""
+reference item for item, the launch's two host operands against the
+straightforward query-by-query construction byte for byte, and a write
+seen by the next query through `EngineServer`."""
 
 from __future__ import annotations
 
+import collections
 import datetime as _dt
 import json
 import os
@@ -282,6 +284,153 @@ def test_mixed_batch_equals_the_reference_item_for_item(shop):
     assert stages["predict.rules"] == stages["predict.prep"] == 1
 
 
+# -- the launch's operands against the query-by-query construction -------------
+
+
+def _per_query_operands(algo, model, queries):
+    """``(per_query, lists, counts)`` built the straightforward way, one
+    query at a time over the store's events (the form the template's prep
+    had before it went by arrays), the packed lists by a plain sort of
+    (item row, query row) pairs."""
+    reader = algo._reader(model)
+    item_row, counts = model.item_map.get, collections.Counter()
+    wanted = [q.get("categories") or () for q in queries]
+    batch = 1 << max(0, len(queries) - 1).bit_length()
+    slots = 1 << max(0, max(1, max(map(len, wanted))) - 1).bit_length()
+    recent_at, cats_at = 3, 3 + S.RECENT_SLOTS
+    per_query = np.zeros((batch, cats_at + slots), np.int32)
+    per_query[:, 1] = S.POPULAR
+    per_query[:, recent_at:cats_at] = -1
+    per_query[:, cats_at:] = S.NO_CATEGORY
+    lists = []
+    for i, q in enumerate(queries):
+        user = str(q.get("user", ""))
+        row = model.user_map.get(user, -1)
+        if row >= 0:
+            per_query[i, 0], per_query[i, 1] = row, S.KNOWN
+            counts["known"] += 1
+        else:
+            views = [
+                item_row(e.target_entity_id, -1)
+                for e in reader.find("user", user, event_names=("view",), limit=10)
+            ]
+            views = [r for r in views if r >= 0]
+            if views:
+                per_query[i, 1] = S.SIMILAR
+                per_query[i, recent_at:recent_at + len(views)] = views
+            counts["similar" if views else "popular"] += 1
+        seen = [
+            item_row(e.target_entity_id, -1)
+            for e in reader.find("user", user, event_names=("view", "buy"))
+        ]
+        seen = [r for r in seen if r >= 0]
+        black = [item_row(str(x), -1) for x in q.get("blackList") or ()]
+        white = q.get("whiteList") or ()
+        counts["blackList"] += bool(black)
+        counts["whiteList"] += bool(white)
+        if white:
+            per_query[i, 2] = 1
+            rows = [
+                r for r in {item_row(str(x), -1) for x in white}
+                if r >= 0 and r not in black and r not in seen
+            ]
+        else:
+            rows = seen + [r for r in black if r >= 0]
+        lists.append(rows)
+        if wanted[i]:
+            counts["categories"] += 1
+            per_query[i, cats_at:cats_at + len(wanted[i])] = [
+                model.rules.category_ids.get(str(c), S.NO_CATEGORY - 1)
+                for c in wanted[i]
+            ]
+    pairs = sorted((col, row) for row, x in enumerate(lists) for col in x)
+    packed = np.zeros((2, S.list_capacity(len(pairs))), np.int32)
+    packed[1] = S.NO_ITEM
+    if pairs:
+        packed[1, :len(pairs)], packed[0, :len(pairs)] = zip(*pairs)
+    counts["excluded"] = len(pairs)
+    counts["lookups"] = 1 + len(queries)
+    return per_query, packed, counts
+
+
+_BLACK = [f"i{i}" for i in range(0, 400, 7)]
+_WHITE = [f"i{i}" for i in range(100, 160)]
+_OPERAND_CASES = {
+    "known_users": [{"user": "u0", "num": 10}, {"user": "u8", "num": 3}],
+    "unknown_users_with_views": [{"user": "x0"}, {"user": "x2", "num": 4}, {"user": "x3"}],
+    "unknown_user_without_views": [{"user": "nobody", "num": 10}],
+    "categories_known_and_unknown": [
+        {"user": "u1", "categories": ["c2"]}, {"user": "u6", "categories": ["c99"]},
+        {"user": "x2", "categories": ["c1", "c99", "c3"]},
+    ],
+    "blacklist_with_unknown_ids": [
+        {"user": "u2", "blackList": _BLACK + ["no-such-item"]},
+        {"user": "nobody", "blackList": ["no-such-item", "neither"]},
+    ],
+    "whitelist_overlapping_the_seen_and_the_blacklist": [
+        {"user": "u4", "whiteList": _WHITE},
+        {"user": "u5", "whiteList": _WHITE + ["no-such-item"], "blackList": _WHITE[:30]},
+        {"user": "u3", "whiteList": [f"i{i}" for i in range(0, 21000, 13)]},
+    ],
+    "a_repeated_item": [
+        {"user": "u7", "blackList": ["i5", "i5", "i6"]},
+        {"user": "u7", "whiteList": ["i7", "i7"]},
+        {"user": "x1", "blackList": ["i5"]},
+    ],
+    "an_empty_post_slot": [{}, {"user": "u0"}, {}],
+    "one_user_past_list_capacity": [{"user": "u3", "num": 10}, {"user": "u0"}],
+}
+
+
+@pytest.mark.parametrize("case", sorted(_OPERAND_CASES) + ["mixed_batch"])
+def test_launch_operands_equal_the_per_query_construction(shop, monkeypatch, case):
+    """What reaches the device is byte for byte what the query-by-query
+    construction gives, and the template's counters move by the
+    per-query counts, once a batch."""
+    algo, model, shop_ref, _ = shop
+    queries = _OPERAND_CASES.get(case) or _mixed_queries() + sum(_OPERAND_CASES.values(), [])
+    if case == "a_repeated_item":
+        # an item the user has seen, on the blackList too, and twice
+        seen = [f"i{i}" for i in shop_ref.seen["u7"][:2]]
+        queries = queries + [{"user": "u7", "blackList": seen + seen}]
+    sent = []
+    step = S.rules_top_k
+    monkeypatch.setattr(
+        S, "rules_top_k", lambda *a: sent.append(a[4]) or step(*a)
+    )
+    registry = MetricRegistry()
+    tracing.StageSink(registry).bind()
+    try:
+        algo.batch_predict_collect(
+            model, algo.batch_predict_launch(model, queries), queries
+        )
+        want_per_query, want_lists, want = _per_query_operands(algo, model, queries)
+    finally:
+        tracing._bound_stages.set(None)
+    (operands,) = sent
+    for got, expected in ((operands.per_query, want_per_query), (operands.lists, want_lists)):
+        assert isinstance(got, np.ndarray) and got.dtype == np.int32
+        assert got.shape == expected.shape
+        assert got.tobytes() == expected.tobytes()
+    if case in ("one_user_past_list_capacity", "mixed_batch"):
+        assert operands.lists.shape[1] > S.LIST_CAPACITY
+    got = registry.to_dict()
+    value = lambda family, **labels: sum(  # noqa: E731
+        s["value"] for s in got[family]["samples"] if s["labels"] == labels
+    )
+    for branch in ("known", "similar", "popular"):
+        assert value("pio_ecomm_queries_total", branch=branch) == want[branch], branch
+    for rule in ("categories", "whiteList", "blackList"):
+        assert value("pio_ecomm_filtered_queries_total", rule=rule) == want[rule], rule
+    assert value("pio_ecomm_excluded_items_total") == want["excluded"]
+    assert value("pio_ecomm_rule_lookups_total") == want["lookups"]
+    seen_lookups = {
+        r: value("pio_ecomm_seen_lookups_total", result=r) for r in ("hit", "miss")
+    }
+    assert sum(seen_lookups.values()) == len(queries)
+    assert seen_lookups["miss"] == len({str(q.get("user", "")) for q in queries})
+
+
 def test_one_category_query_is_answered_in_full(shop):
     """The parent filtered the global top 64 on the host: a one-category
     query found a few of its items there, or none."""
@@ -333,6 +482,41 @@ def test_a_write_is_in_the_next_answer(shop):
     # and a deleted $set gives way to the one before it
     assert storage.get_events().delete(event_id, app_id)
     assert [s["item"] for s in algo.predict(model, query)["itemScores"]] == second
+
+
+def test_seen_rows_are_read_once_a_version_of_the_user(shop):
+    """`pio_ecomm_seen_lookups_total`: a repeated user is one miss, then
+    hits; a write to that user is a miss again, and the written item
+    leaves the answer."""
+    algo, model, _, (storage, app_id) = shop
+    registry = MetricRegistry()
+    tracing.StageSink(registry).bind()
+
+    def lookups():
+        samples = registry.to_dict()["pio_ecomm_seen_lookups_total"]["samples"]
+        return {s["labels"]["result"]: s["value"] for s in samples}
+
+    try:
+        query = {"user": "u9", "num": 5}
+        first = [s["item"] for s in algo.predict(model, query)["itemScores"]]
+        assert lookups() == {"hit": 0, "miss": 1}
+        for _ in range(2):
+            assert [s["item"] for s in algo.predict(model, query)["itemScores"]] == first
+        assert lookups() == {"hit": 2, "miss": 1}
+        # twice in one batch, beside a user never asked about
+        algo.batch_predict(model, [query, {"user": "u10"}, query])
+        assert lookups() == {"hit": 4, "miss": 2}
+        storage.get_events().insert(Event(
+            event="buy", entity_type="user", entity_id="u9",
+            target_entity_type="item", target_entity_id=first[0],
+        ), app_id)
+        second = [s["item"] for s in algo.predict(model, query)["itemScores"]]
+        assert first[0] not in second and second[:4] == first[1:]
+        assert lookups() == {"hit": 4, "miss": 3}
+        assert [s["item"] for s in algo.predict(model, query)["itemScores"]] == second
+        assert lookups() == {"hit": 5, "miss": 3}
+    finally:
+        tracing._bound_stages.set(None)
 
 
 def test_a_write_is_seen_by_the_next_query_through_engine_server(ctx, memory_storage):

@@ -1,12 +1,15 @@
 """Serve-time reads by entity (`EventStore.find_by_entity`,
 `EventStore.entity_reader`) equal a linear scan of everything written,
 after interleaved inserts, batch inserts, re-inserts under an old id and
-deletes, on the `memory` backend (which keeps an entity index of its own)
-and on `sqlite` (whose table is indexed by entity)."""
+deletes, on the `memory` backend (which keeps an entity index of its own
+and answers `entity_targets` from it) and on `sqlite` (whose table is
+indexed by entity; `entity_targets` there is the base class's walk over
+`find`)."""
 
 from __future__ import annotations
 
 import datetime as _dt
+import threading
 
 import numpy as np
 import pytest
@@ -50,6 +53,16 @@ def _scan(mirror, entity_type, entity_id, names, limit=None):
         key=lambda e: e.event_time, reverse=True,
     )
     return [e.event_id for e in found[:limit]]
+
+
+def _scan_targets(mirror, entity_type, entity_id, names):
+    """The targets a linear scan of everything written finds, sorted (the
+    read promises no order)."""
+    return sorted(
+        e.target_entity_id for e in mirror.values()
+        if e.entity_type == entity_type and e.entity_id == entity_id
+        and e.event in names and e.target_entity_id is not None
+    )
 
 
 @pytest.mark.parametrize("seed", [0, 1])
@@ -96,6 +109,9 @@ def test_entity_reads_equal_a_linear_scan_after_interleaved_writes(storage, seed
             assert [e.event_id for e in reader.find(
                 "user", f"u{u}", event_names=["view"], limit=10
             )] == _scan(mirror, "user", f"u{u}", {"view"}, 10)
+            assert sorted(
+                reader.targets("user", f"u{u}", ("view", "buy"))
+            ) == _scan_targets(mirror, "user", f"u{u}", {"view", "buy"}), (step, u)
         latest = reader.find("constraint", "unavailableItems", event_names=["$set"], limit=1)
         assert [e.event_id for e in latest] == _scan(
             mirror, "constraint", "unavailableItems", {"$set"}, 1
@@ -116,6 +132,89 @@ def test_entity_reads_equal_a_linear_scan_after_interleaved_writes(storage, seed
         assert reader.version("user", "nobody") == 0
         assert backend.remove(app_id)
         assert reader.version("user", "u0") == 0 and reader.find("user", "u0") == []
+
+
+def _view(n, user, item, name="view"):
+    return Event(
+        event=name, entity_type="user", entity_id=user,
+        target_entity_type=None if item is None else "item",
+        target_entity_id=item, event_time=T0 + _dt.timedelta(seconds=n),
+        event_id=f"e{n}",
+    )
+
+
+def _insert(backend, app_id, mirror, event):
+    backend.insert(event, app_id)
+    mirror[event.event_id] = event
+
+
+def _insert_batch_replacing_an_id(backend, app_id, mirror):
+    # e1 comes again, under another user and with another item
+    batch = [_view(1, "u2", "i9", "buy"), _view(10, "u1", "i10"), _view(11, "u2", "i1")]
+    backend.insert_batch(batch, app_id)
+    mirror.update((e.event_id, e) for e in batch)
+
+
+def _delete(backend, app_id, mirror):
+    assert backend.delete("e0", app_id)
+    del mirror["e0"]
+    assert backend.delete("e3", app_id)  # u3's only event
+    del mirror["e3"]
+
+
+def _write_from_another_thread(backend, app_id, mirror):
+    event = _view(20, "u1", "i20", "buy")
+    writer = threading.Thread(target=_insert, args=(backend, app_id, mirror, event))
+    writer.start()
+    writer.join(timeout=30)
+    assert not writer.is_alive()
+
+
+_TARGET_CASES = {
+    "insert": lambda *a: _insert(*a, _view(12, "u1", "i12")),
+    "insert_batch_with_a_replaced_event_id": _insert_batch_replacing_an_id,
+    "delete": _delete,
+    "event_name_outside_event_names": lambda *a: _insert(*a, _view(13, "u1", "i13", "cart")),
+    "event_without_a_target": lambda *a: _insert(*a, _view(14, "u1", None)),
+    "entity_with_no_events": lambda *a: None,
+    "write_from_another_thread_that_has_returned": _write_from_another_thread,
+}
+
+
+@pytest.mark.parametrize("case", sorted(_TARGET_CASES))
+def test_entity_targets_equal_a_linear_scan(storage, case):
+    """`EventsBackend.entity_targets` and `EntityReader.targets` after each
+    kind of write: the targets of the entity's events of the named kinds,
+    in any order, nothing kept from before the write."""
+    app_id = storage.get_meta_data_apps().insert(App(id=0, name="shop"))
+    backend = storage.get_events()
+    backend.init(app_id)
+    reader = EventStore(storage).entity_reader("shop")
+    mirror: dict[str, Event] = {}
+    for n, (user, item, name) in enumerate([
+        ("u1", "i0", "view"), ("u1", "i1", "buy"), ("u2", "i1", "view"),
+        ("u3", "i3", "view"), ("u1", "i0", "view"), ("u1", "i5", "cart"),
+    ]):
+        _insert(backend, app_id, mirror, _view(n, user, item, name))
+    names = ("view", "buy")
+
+    def check():
+        for user in ("u1", "u2", "u3", "nobody"):
+            want = _scan_targets(mirror, "user", user, set(names))
+            assert sorted(reader.targets("user", user, names)) == want, user
+            assert sorted(backend.entity_targets(
+                app_id, None, "user", user, iter(names)
+            )) == want, user
+        assert reader.targets("user", "u1", ()) == []
+        assert reader.targets("item", "u1", names) == []  # another entity type
+
+    check()  # read once before the write, so anything kept would show
+    _TARGET_CASES[case](backend, app_id, mirror)
+    check()
+    assert sorted(reader.targets("user", "u1", ("cart",))) == _scan_targets(
+        mirror, "user", "u1", {"cart"}
+    )
+    assert backend.entity_targets(app_id + 1, None, "user", "u1", names) == []
 
 
 def test_memory_reads_of_an_entity_touch_its_events_only(memory_storage):
