@@ -452,6 +452,12 @@ def run(cell, bench, config, traffic, args, t_process_start, device) -> dict:
             "this program's ecommerce template has no rules before the "
             "top-k (no two-phase predict): the cell cannot run on it"
         )
+    named = [k for k in loadgen.OPTIONAL_PARAMETERS if k in traffic]
+    if named:
+        raise SystemExit(
+            f"traffic names {named}: `loadgen_ecomm.py` draws its own users "
+            "on a closed loop and follows neither"
+        )
     import jax
 
     from predictionio_tpu.utils.compile_cache import configure_compile_cache

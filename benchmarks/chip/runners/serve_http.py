@@ -89,6 +89,18 @@ def start_generators(traffic: dict):
     ]
 
 
+def generator_parameters(traffic: dict) -> dict:
+    """The generator's optional parameters that the traffic file names
+    (`loadgen.OPTIONAL_PARAMETERS`); one the loop cannot follow ends the
+    run with a message."""
+    if traffic["loop"] == "closed" and "burst" in traffic:
+        raise SystemExit(
+            "traffic names 'burst' on a closed loop: a client sends when its "
+            "reply has come, so there is no schedule to burst; open loop only"
+        )
+    return {k: traffic[k] for k in loadgen.OPTIONAL_PARAMETERS if k in traffic}
+
+
 def send_plans(procs, traffic, config, port, seed, seconds, cores) -> dict:
     t_start = time.monotonic() + 0.3
     times = {
@@ -110,6 +122,7 @@ def send_plans(procs, traffic, config, port, seed, seconds, cores) -> dict:
             "batch": traffic.get("batch", 1),
             "rate": traffic.get("rate", 0.0),
             "keep": traffic["keep"], "cores": cores,
+            **generator_parameters(traffic),
         }
         proc.stdin.write(json.dumps(plan) + "\n")
         proc.stdin.close()
@@ -273,6 +286,7 @@ def run(cell, bench, config, traffic, args, t_process_start, device) -> dict:
     from predictionio_tpu.utils.compile_cache import configure_compile_cache
 
     configure_compile_cache()
+    generator_parameters(traffic)  # a mix the generator cannot follow ends here
     server_cores, generator_cores = split_cores(traffic["generator_cores"])
     if server_cores:
         os.sched_setaffinity(0, server_cores)

@@ -1,10 +1,17 @@
 """What the harness's tests ask of `BENCHMARK.json` and of the files it
 names, as functions of ``(bench, root)``: the manifest as a dictionary and
-the checkout it lies in. The tests run them over the repository; the
-fixture test runs them over a copy with a made-up configuration, cell and
-metrics, so that what a later PR may add as files is shown, not promised.
+the checkout it lies in. The tests run them over the repository and over a
+copy grown by a made-up configuration, cell, traffic file, runner,
+reference and metrics (`made_up.py`; the ``manifest`` fixture of
+`conftest.py`), so that what a later PR may add as files is shown, not
+promised, and a test that pins the end of a list fails in the PR that
+writes it.
 
-Each rule raises `AssertionError` with a message of its own. The accepted
+Each rule raises `AssertionError` with a message of its own. One rule for
+the three lists a PR appends to (`check_accepted_prefix`): the names
+accepted so far, `data/accepted.json`, are a prefix in their order, and
+what follows is free. A PR's own test asserts on what that PR owns
+(`check_owned_block`) and nothing about what follows it. The two KDD Cup
 configurations stay pinned by name (`check_accepted_config`); every
 configuration, those included, meets the general rule
 (`check_config_entry`).
@@ -25,24 +32,38 @@ TESTS = os.path.join("tests", "chip_benchmark")
 #: driver's two floors for a new cell's size
 CHIP_MEMORY_BYTES = 2**34
 LOWER_FLOOR = 0.125
-#: what `pio-tpu deploy` gives a server that is told nothing
+#: what `pio-tpu deploy` gives a server that is told nothing, for the keys a
+#: configuration's `server` block states (a test holds them to
+#: `EngineServer.__init__`'s own defaults)
 DEPLOY_DEFAULTS = {
-    "max_batch": 64, "max_wait_ms": 2.0, "pipeline_depth": 2,
-    "adaptive_wait": True, "admission": True, "warmup": True,
+    "max_batch": 64, "max_wait_ms": 2.0, "admission": True, "warmup": True,
 }
 #: what every configuration's file has, whatever its runner reads
 CONFIG_KEYS = (
     "source", "deployment", "published", "assumed", "server", "limits",
     "control", "rehearse", "resident_table_bytes", "floor_note",
 )
-#: the configurations and cells accepted up to PR 26
-ACCEPTED_CONFIGS = ("rec-pool-kddcup11", "rec-pool-kddcup11-int8")
-ACCEPTED_CELLS = ("serve-pool-batch", "serve-pool-single", "serve-pool-int8-batch")
+#: the two configurations pinned to KDD Cup 2011 Track 1 (PR 24), and their cells
+KDDCUP_CONFIGS = ("rec-pool-kddcup11", "rec-pool-kddcup11-int8")
+KDDCUP_CELLS = ("serve-pool-batch", "serve-pool-single", "serve-pool-int8-batch")
+#: the lists of the manifest that a PR appends to
+LISTS = ("configs", "workloads", "per_layer")
 
 
 def load_bench(root: str) -> dict:
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         return json.load(f)
+
+
+def load_accepted(root: str) -> dict:
+    """``{list: names}`` of `data/accepted.json`: what the manifest held at
+    the end of the last `benchmark` PR, in its order."""
+    with open(os.path.join(root, TESTS, "data", "accepted.json")) as f:
+        return json.load(f)
+
+
+def entry(bench: dict, kind: str, name: str) -> dict:
+    return next(e for e in bench[kind] if e["name"] == name)
 
 
 def chip_dir(bench: dict, root: str) -> str:
@@ -51,8 +72,7 @@ def chip_dir(bench: dict, root: str) -> str:
 
 
 def config_body(bench: dict, root: str, name: str) -> dict:
-    entry = next(c for c in bench["configs"] if c["name"] == name)
-    with open(os.path.join(root, entry["file"])) as f:
+    with open(os.path.join(root, entry(bench, "configs", name)["file"])) as f:
         return json.load(f)
 
 
@@ -86,6 +106,27 @@ def runner_config_keys(bench: dict, root: str, runner: str) -> tuple:
     raise AssertionError(f"runner {runner!r} declares no CONFIG_KEYS")
 
 
+def signature_defaults(path: str, cls: str, func: str = "__init__") -> dict:
+    """``{parameter: default}`` of ``cls.func`` in the file at ``path``, for
+    the defaults that are literals; read from its text, as a runner's keys
+    are, so that no JAX starts."""
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    owner = next(
+        n for n in tree.body if isinstance(n, ast.ClassDef) and n.name == cls
+    )
+    args = next(
+        n for n in owner.body if isinstance(n, ast.FunctionDef) and n.name == func
+    ).args
+    found = {}
+    for arg, default in zip(args.args[len(args.args) - len(args.defaults):], args.defaults):
+        try:
+            found[arg.arg] = ast.literal_eval(default)
+        except ValueError:
+            pass
+    return found
+
+
 def check_config_entry(bench: dict, root: str, entry: dict) -> None:
     """The general rule of a configuration: its file says where its widths
     come from and repeats them, says what it assumed and what of the server
@@ -106,11 +147,11 @@ def check_config_entry(bench: dict, root: str, entry: dict) -> None:
         assert body[key] == value or key in entry["reduced"], (
             f"width {key!r} differs from the published one and is not under reduced"
         )
-    # a deployment may BE a server setting, but says so; a key beyond
-    # deploy's six is one deploy leaves to the environment (the query cache)
-    server = body["server"]
-    assert set(server) >= set(DEPLOY_DEFAULTS), "server has deploy's six keys"
-    for key, value in server.items():
+    # the block says only what the deployment sets: a key that restates
+    # deploy's default may stand, any other says so under `assumed` (a
+    # deployment may BE a server setting; the query cache is a key deploy
+    # leaves to the environment)
+    for key, value in body["server"].items():
         default = key in DEPLOY_DEFAULTS and DEPLOY_DEFAULTS[key] == value
         assert default or "server." + key in body["assumed"], (
             f"server setting {key!r} differs from deploy's default and is not under assumed"
@@ -129,7 +170,7 @@ def check_accepted_config(bench: dict, root: str, entry: dict) -> None:
     """The pin of the two configurations accepted with PR 24: KDD Cup 2011
     Track 1's widths uncut, a quarter of 16 GiB resident, deploy's
     defaults. A new configuration is not held to it."""
-    assert entry["name"] in ACCEPTED_CONFIGS
+    assert entry["name"] in KDDCUP_CONFIGS
     body = config_body(bench, root, entry["name"])
     assert (body["n_users"], body["n_items"], body["rank"]) == (1000990, 624961, 32), (
         "the widths of KDD Cup 2011 Track 1, uncut"
@@ -177,18 +218,58 @@ def check_per_layer_metric(bench: dict, root: str, metric: dict) -> None:
         assert metric["unit"] == "%"
 
 
-def check_per_layer_order(bench: dict, root: str, accepted: list[str]) -> None:
-    """`per_layer` may grow at its end only: the accepted names, in their
-    order, are its prefix; what follows is free."""
-    names = [m["name"] for m in bench["per_layer"]]
-    assert names[:len(accepted)] == accepted, (
-        "the accepted per-layer entries come first, in their order"
-    )
-    assert len(set(names)) == len(names)
-    setup = next(m for m in bench["per_layer"] if m["name"] == "setup_compile_s")
+def check_accepted_prefix(bench: dict, root: str, accepted: dict) -> None:
+    """`configs`, `workloads` and `per_layer` grow at their ends only: the
+    accepted names of each, in their order, are its prefix; what follows is
+    free."""
+    for kind in LISTS:
+        names = [e["name"] for e in bench[kind]]
+        assert names[:len(accepted[kind])] == accepted[kind], (
+            f"the accepted {kind} names come first, in their order"
+        )
+        assert len(set(names)) == len(names), f"a name stands twice in {kind}"
+    setup = entry(bench, "per_layer", "setup_compile_s")
     assert setup["moves"] == "setup_s" and setup["unit"] == "s"
     cells = {w["name"] for w in bench["workloads"]}
-    assert set(ACCEPTED_CELLS) <= set(setup["workloads"]) <= cells
+    assert set(KDDCUP_CELLS) <= set(setup["workloads"]) <= cells
+
+
+def check_owned_block(
+    bench: dict, root: str, cell: str, names: list[str], after: list[str]
+) -> list[dict]:
+    """What one PR owns of the manifest, and nothing about what follows it:
+    its per-layer entries ``names`` stand in `per_layer` together and in
+    their order, behind the entries ``after`` that were accepted before
+    them; each is read in ``cell`` alone; the cell stands in `workloads`
+    behind the cells those earlier entries are read in, and meets
+    `check_cell`. Returns the block's entries, for what else its PR pins
+    (their `moves`, the cell's metrics, its reference). A PR's own test
+    calls this for its own block and asserts nothing on the lists' ends."""
+    listed = [m["name"] for m in bench["per_layer"]]
+    assert names and names[0] in listed, f"{names[:1]} is not in per_layer"
+    at = listed.index(names[0])
+    assert listed[at:at + len(names)] == list(names), (
+        f"the entries of {cell!r} stand together and in their order"
+    )
+    assert set(after) <= set(listed[:at]), (
+        f"the entries of {cell!r} come after the ones accepted before them"
+    )
+    block = bench["per_layer"][at:at + len(names)]
+    for metric in block:
+        assert metric["workloads"] == [cell], (
+            f"{metric['name']!r} is read in {cell!r} alone"
+        )
+    cells = [w["name"] for w in bench["workloads"]]
+    assert cell in cells, f"no cell {cell!r} in workloads"
+    earlier = {
+        c for m in bench["per_layer"][:at] if m["name"] in after
+        for c in m["workloads"]
+    }
+    assert all(cells.index(c) < cells.index(cell) for c in earlier), (
+        f"{cell!r} comes after the cells accepted before it"
+    )
+    check_cell(bench, root, bench["workloads"][cells.index(cell)])
+    return block
 
 
 def reference_module(bench: dict, root: str, config: str) -> str:

@@ -27,6 +27,17 @@ import run as chip_run  # noqa: E402
 BENCH = rules.load_bench(ROOT)
 CONFIG = "ecomm-pool-taobao-ub"
 CELL = "serve-ecomm-batch"
+#: PR 28's per-layer entries, by name and in their order; a later PR's
+#: `ecomm.*` entry (PR 32's `ecomm.launch_calls_share`) is none of them
+PER_LAYER = [
+    "ecomm." + base for base in (
+        "http_request_ms", "queue_wait_ms", "batch_occupancy", "device_dispatch_ms",
+        "topk_device_ms", "device_idle_share", "launch_idle_share", "hbm_peak_gib",
+        "host_cpu_share", "compiles_in_window", "serve_mfu", "setup_compile_s",
+        "rules_lookup_ms", "excluded_per_query", "filtered_share", "cold_share",
+        "masked_topk_roofline",
+    )
+]
 
 
 def _shop(seed, n_users=300, n_items=6000, n_categories=40, rank=16):
@@ -163,8 +174,7 @@ def test_seeded_data_has_the_shape_the_configuration_states():
     assert 0.035 < share("whiteList") < 0.065
     assert 0.03 < np.mean([q["user"][0] != "u" for q in queries]) < 0.07
     # what the manifest says of the cut matches the file
-    entry = next(c for c in BENCH["configs"] if c["name"] == CONFIG)
-    assert entry["reduced"] == ["n_seen_events"]
+    assert rules.entry(BENCH, "configs", CONFIG)["reduced"] == ["n_seen_events"]
     assert cfg["n_seen_events"] == cfg["tenants"] * cfg["active_users"] * 93
     assert cfg["resident_table_bytes"] == cfg["tenants"] * (
         cfg["n_users"] + cfg["n_items"]
@@ -241,20 +251,63 @@ def test_ecomm_readers_on_hand_made_runs():
         assert layer_metrics.read("ecomm." + name, bare) is None, name
 
 
-def test_new_cell_and_its_entries_come_after_the_accepted():
-    import json
+def _check_the_block(bench, root):
+    """What PR 28 owns of the manifest, and nothing about what follows it."""
+    accepted_before = rules.load_accepted(root)["per_layer"][:50]
+    block = rules.check_owned_block(bench, root, CELL, PER_LAYER, accepted_before)
+    assert len(block) == 17
+    assert {m["moves"] for m in block} == {"queries_per_s", "setup_s"}
+    assert rules.reports(bench, "end_to_end", CELL) == ["setup_s", "queries_per_s"]
+    assert rules.entry(bench, "workloads", CELL)["config"] == CONFIG
+    assert rules.reference_module(bench, root, CONFIG) == "reference_ecomm"
 
-    with open(os.path.join(os.path.dirname(__file__), "data", "accepted_per_layer.json")) as f:
-        accepted = json.load(f)["names"]
-    names = [m["name"] for m in BENCH["per_layer"]]
-    assert names[:len(accepted)] == accepted
-    new = [m for m in BENCH["per_layer"] if m["name"].startswith("ecomm.")]
-    assert names[len(accepted):] == [m["name"] for m in new] and len(new) == 17
-    assert all(m["workloads"] == [CELL] for m in new)
-    assert {m["moves"] for m in new} == {"queries_per_s", "setup_s"}
-    assert [w["name"] for w in BENCH["workloads"]][3:] == [CELL]
-    assert rules.reports(BENCH, "end_to_end", CELL) == ["setup_s", "queries_per_s"]
-    assert rules.reference_module(BENCH, ROOT, CONFIG) == "reference_ecomm"
+
+def test_new_cell_and_its_entries_come_after_the_accepted(manifest):
+    bench, root = manifest
+    _check_the_block(bench, root)
+    listed = [m["name"] for m in bench["per_layer"]]
+    assert listed.index("ecomm.launch_calls_share") > listed.index(PER_LAYER[-1])
+
+
+def _an_entry_between(bench):
+    bench["per_layer"].insert(60, bench["per_layer"].pop())
+
+
+def _the_block_s_order_changed(bench):
+    bench["per_layer"][52], bench["per_layer"][53] = bench["per_layer"][53], bench["per_layer"][52]
+
+
+def _an_entry_read_in_another_cell_too(bench):
+    rules.entry(bench, "per_layer", "ecomm.queue_wait_ms")["workloads"].append("serve-pool-batch")
+
+
+def _the_block_before_an_accepted_entry(bench):
+    bench["per_layer"].append(bench["per_layer"].pop(49))
+
+
+def _the_cell_before_an_accepted_one(bench):
+    bench["workloads"].insert(1, bench["workloads"].pop(3))
+
+
+def _the_cell_gone(bench):
+    bench["workloads"] = [w for w in bench["workloads"] if w["name"] != CELL]
+
+
+@pytest.mark.parametrize("fault,message", [
+    (_an_entry_between, "stand together and in their order"),
+    (_the_block_s_order_changed, "stand together and in their order"),
+    (_an_entry_read_in_another_cell_too, "'ecomm.queue_wait_ms' is read in 'serve-ecomm-batch' alone"),
+    (_the_block_before_an_accepted_entry, "come after the ones accepted before them"),
+    (_the_cell_before_an_accepted_one, "'serve-ecomm-batch' comes after the cells accepted before it"),
+    (_the_cell_gone, "no cell 'serve-ecomm-batch' in workloads"),
+], ids=lambda v: getattr(v, "__name__", "message"))
+def test_each_fault_in_the_block_fails_on_its_own_assertion(manifest, fault, message):
+    import re
+
+    bench, root = manifest
+    fault(bench)
+    with pytest.raises(AssertionError, match=re.escape(message)):
+        _check_the_block(bench, root)
 
 
 def _rehearse(seed=11, seconds=1.5, trace=0, control=""):
@@ -266,18 +319,21 @@ def _rehearse(seed=11, seconds=1.5, trace=0, control=""):
     return serve_ecomm.run(cell, bench, config, traffic, args, time.monotonic(), device)
 
 
-def test_sound_rehearsal_is_correct_and_reports_the_rules():
+def test_sound_rehearsal_is_correct_and_reports_the_rules(servers_built):
     result = _rehearse(seed=2**31 + 4321, trace=1, control="filter_after_top64")
     assert result["correct"] is True, result["compared"]
+    assert servers_built == [(2, True)]
     assert result["failed"] == 0 and result["attempted"] > 500
     for name in ("rule_violations", "short_answers", "stale_answers", "evictions"):
         assert result["compared"][name][0] == 0, name
     assert result["sampled"]["probes"] == 128 and result["sampled"]["queries"] == 96
     for name in ("ecomm.rules_lookup_ms", "ecomm.excluded_per_query",
                  "ecomm.filtered_share", "ecomm.cold_share", "ecomm.queue_wait_ms",
-                 "ecomm.compiles_in_window", "ecomm.batch_occupancy"):
+                 "ecomm.compiles_in_window", "ecomm.batch_occupancy",
+                 "ecomm.launch_calls_share"):
         assert name in result["metrics"], name
     assert result["metrics"]["ecomm.compiles_in_window"]["value"] == 0
+    assert 95 < result["metrics"]["ecomm.launch_calls_share"]["value"] < 105
     assert 40 < result["metrics"]["ecomm.filtered_share"]["value"] < 60
     # no device plane on the CPU: a share of a peak is left out, not 0
     assert "ecomm.masked_topk_roofline" not in result["metrics"]

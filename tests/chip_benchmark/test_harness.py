@@ -1,12 +1,18 @@
 """The chip benchmark's harness, on the CPU at tiny size: the manifest and
 its data files (the rules are `manifest_rules`, functions of the manifest
-and its checkout, shown on a made-up addition too), the arithmetic of the
-yardstick, the trace reduction on small recorded traces, the command's
-contract, the control, and a run with the timed path broken underneath."""
+and its checkout; every test of the manifest's shape takes the ``manifest``
+fixture and runs over the repository and over a copy grown by `made_up`'s
+addition, which is also rehearsed from there), the generator's draws
+against the digests of the commit before its optional parameters came, the
+arithmetic of the yardstick, the trace reduction on small recorded traces,
+the command's contract, the control, and a run with the timed path broken
+underneath."""
 
 from __future__ import annotations
 
 import argparse
+import ast
+import hashlib
 import json
 import os
 import re
@@ -24,6 +30,7 @@ sys.path[:0] = [CHIP, ROOT, os.path.dirname(os.path.abspath(__file__))]
 
 import layer_metrics  # noqa: E402
 import loadgen  # noqa: E402
+import made_up  # noqa: E402
 import manifest_rules as rules  # noqa: E402
 import reference  # noqa: E402
 import roofline  # noqa: E402
@@ -31,10 +38,19 @@ import run as chip_run  # noqa: E402
 import trace_reduce  # noqa: E402
 
 NAME, UNIT = rules.NAME, rules.UNIT
+#: the manifest for what reads values (limits, widths, the rehearsals); a
+#: test of its shape takes the `manifest` fixture, or `_over` for its entries
 BENCH = rules.load_bench(ROOT)
+BOTH = made_up.manifests(ROOT)
 CONFIGS = [c["name"] for c in BENCH["configs"]]
-with open(os.path.join(os.path.dirname(__file__), "data", "accepted_per_layer.json")) as _f:
-    ACCEPTED_PER_LAYER = json.load(_f)["names"]
+ACCEPTED = rules.load_accepted(ROOT)
+MADE_UP = made_up.names()
+
+
+def _over(*kinds):
+    return pytest.mark.parametrize(
+        "manifest,entry", made_up.over(BOTH, *kinds), indirect=["manifest"]
+    )
 
 
 def _config(name):
@@ -44,26 +60,23 @@ def _config(name):
 # -- the manifest and its data files ----------------------------------------
 
 
-def test_manifest_keys_and_limits():
-    assert set(BENCH) == {
+def test_manifest_keys_and_limits(manifest):
+    bench, _root = manifest
+    assert set(bench) == {
         "command", "paths", "run_seconds", "configs", "workloads",
         "end_to_end", "per_layer",
     }
-    assert 1 <= BENCH["run_seconds"] <= 51
+    assert 1 <= bench["run_seconds"] <= 51
     runs = 2 + 14 * 24
-    assert runs * (BENCH["run_seconds"] + 60) + 24 * 180 + 1200 <= 43200
-    four = sum(1 for w in BENCH["workloads"] if w["chips"] == 4)
-    assert four <= max(1, len(BENCH["workloads"]) // 4)
-    assert len(json.dumps(BENCH)) <= 64 * 1024
-    assert any(m["name"] == "setup_s" for m in BENCH["end_to_end"])
+    assert runs * (bench["run_seconds"] + 60) + 24 * 180 + 1200 <= 43200
+    four = sum(1 for w in bench["workloads"] if w["chips"] == 4)
+    assert four <= max(1, len(bench["workloads"]) // 4)
+    assert len(json.dumps(bench)) <= 64 * 1024
+    assert any(m["name"] == "setup_s" for m in bench["end_to_end"])
 
 
-@pytest.mark.parametrize(
-    "entry",
-    BENCH["configs"] + BENCH["workloads"] + BENCH["end_to_end"] + BENCH["per_layer"],
-    ids=lambda e: e["name"],
-)
-def test_names_units_and_lines(entry):
+@_over("configs", "workloads", "end_to_end", "per_layer")
+def test_names_units_and_lines(manifest, entry):
     assert NAME.match(entry["name"])
     for key in ("config", "traffic", "moves"):
         if key in entry:
@@ -82,13 +95,14 @@ def test_names_units_and_lines(entry):
         assert 0.01 <= entry["bound"] <= 0.1
 
 
-@pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
-def test_cell_finds_its_files_and_metrics(cell):
-    bench, found, config, traffic = chip_run.load_cell(cell["name"], False)
-    assert (bench, found) == (BENCH, cell)
-    assert config == _config(cell["config"])
-    assert traffic == rules.traffic_body(BENCH, ROOT, cell["traffic"])
-    rules.check_cell(BENCH, ROOT, cell)
+@_over("workloads")
+def test_cell_finds_its_files_and_metrics(manifest, entry):
+    bench, root = manifest
+    found_bench, found, config, traffic = chip_run.load_cell(entry["name"], False, root)
+    assert (found_bench, found) == (bench, entry)
+    assert config == rules.config_body(bench, root, entry["config"])
+    assert traffic == rules.traffic_body(bench, root, entry["traffic"])
+    rules.check_cell(bench, root, entry)
 
 
 def test_runner_declares_what_it_reads_of_a_configuration():
@@ -103,36 +117,91 @@ def test_runner_declares_what_it_reads_of_a_configuration():
     assert read - set(rules.CONFIG_KEYS) == set(serve_http.CONFIG_KEYS)
 
 
-@pytest.mark.parametrize("metric", BENCH["per_layer"], ids=lambda m: m["name"])
-def test_per_layer_metric_has_reader_and_target(metric):
-    rules.check_per_layer_metric(BENCH, ROOT, metric)
+@_over("per_layer")
+def test_per_layer_metric_has_reader_and_target(manifest, entry):
+    rules.check_per_layer_metric(*manifest, entry)
 
 
-@pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
-def test_config_entry(config):
-    rules.check_config_entry(BENCH, ROOT, config)
-    if config["name"] in rules.ACCEPTED_CONFIGS:
-        rules.check_accepted_config(BENCH, ROOT, config)
+@_over("configs")
+def test_config_entry(manifest, entry):
+    rules.check_config_entry(*manifest, entry)
+    if entry["name"] in rules.KDDCUP_CONFIGS:
+        rules.check_accepted_config(*manifest, entry)
 
 
-def test_accepted_configurations_and_cells_are_all_there():
-    assert CONFIGS[:2] == list(rules.ACCEPTED_CONFIGS)
-    assert [w["name"] for w in BENCH["workloads"]][:3] == list(rules.ACCEPTED_CELLS)
+def test_accepted_configurations_and_cells_are_all_there(manifest):
+    bench, root = manifest
+    accepted = rules.load_accepted(root)
+    rules.check_accepted_prefix(bench, root, accepted)
+    assert tuple(accepted["configs"][:2]) == rules.KDDCUP_CONFIGS
+    assert tuple(accepted["workloads"][:3]) == rules.KDDCUP_CELLS
+    assert [len(accepted[kind]) for kind in rules.LISTS] == [3, 4, 71]
 
 
-@pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
-def test_every_control_is_held_to_the_limits_by_some_test(config):
-    rules.check_control_is_tested(BENCH, ROOT, config)
+@_over("configs")
+def test_every_control_is_held_to_the_limits_by_some_test(manifest, entry):
+    rules.check_control_is_tested(*manifest, entry)
 
 
-def test_files_under_paths_have_plain_names():
-    for path in BENCH["paths"]:
-        for folder, dirs, files in os.walk(os.path.join(ROOT, path)):
+def test_files_under_paths_have_plain_names(manifest):
+    bench, root = manifest
+    for path in bench["paths"]:
+        for folder, dirs, files in os.walk(os.path.join(root, path)):
             dirs[:] = [d for d in dirs if d != "__pycache__"]
             for name in files:
                 assert re.match(r"^[A-Za-z0-9_.\-]+$", name), name
-    for word in BENCH["command"]:
+    for word in bench["command"]:
         assert not word.startswith("/") and ".." not in word
+
+
+#: the lists of the manifest a PR appends to, and the one its cells join
+_LIST_KEYS = (*rules.LISTS, "end_to_end")
+
+
+def _reads_of_the_module_s_manifest(path):
+    """``(function, key)`` for every ``<NAME>["<list>"]`` inside a function of
+    the test file at ``path``, ``<NAME>`` any name the module binds at its
+    top level to what `load_bench` returns (`BENCH`, by convention)."""
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    module_names = {
+        target.id for node in tree.body
+        if isinstance(node, ast.Assign) and isinstance(node.value, ast.Call)
+        and getattr(node.value.func, "attr", getattr(node.value.func, "id", "")) == "load_bench"
+        for target in node.targets if isinstance(target, ast.Name)
+    }
+    found = []
+    for func in ast.walk(tree):
+        if not isinstance(func, (ast.FunctionDef, ast.Lambda)):
+            continue
+        for node in ast.walk(func):
+            if (
+                isinstance(node, ast.Subscript)
+                and isinstance(node.value, ast.Name) and node.value.id in module_names
+                and isinstance(node.slice, ast.Constant) and node.slice.value in _LIST_KEYS
+            ):
+                found.append((getattr(func, "name", "lambda"), node.slice.value))
+    return found
+
+
+def test_no_test_reads_the_manifest_s_lists_but_through_the_fixture(manifest, tmp_path):
+    """A test file under `tests/chip_benchmark`, a later PR's too, reads the
+    lists of a manifest it loads at module level only while it is imported (to
+    parametrize, through `made_up.over`): inside a function their shape
+    comes from the ``manifest`` fixture, which also hands it the grown
+    copy. So a pin on the end of a list cannot pass unseen."""
+    _bench, root = manifest
+    folder = os.path.join(root, rules.TESTS)
+    files = sorted(f for f in os.listdir(folder) if f.startswith("test_") and f.endswith(".py"))
+    assert len(files) >= 3
+    for name in files:
+        assert _reads_of_the_module_s_manifest(os.path.join(folder, name)) == [], name
+    planted = tmp_path / "test_planted.py"
+    planted.write_text(
+        "MANIFEST = rules.load_bench('.')\nCELLS = MANIFEST['workloads']\n"
+        "def test_tail(bench):\n    assert bench['configs'] and MANIFEST['per_layer'][50:] == []\n"
+    )
+    assert _reads_of_the_module_s_manifest(str(planted)) == [("test_tail", "per_layer")]
 
 
 # -- what a later PR may add as files, shown on a made-up addition ------------
@@ -143,24 +212,13 @@ def _all_rules(bench, root, accepted):
     for entry in bench["configs"]:
         rules.check_config_entry(bench, root, entry)
         rules.check_control_is_tested(bench, root, entry)
-        if entry["name"] in rules.ACCEPTED_CONFIGS:
+        if entry["name"] in rules.KDDCUP_CONFIGS:
             rules.check_accepted_config(bench, root, entry)
     for cell in bench["workloads"]:
         rules.check_cell(bench, root, cell)
     for metric in bench["per_layer"]:
         rules.check_per_layer_metric(bench, root, metric)
-    rules.check_per_layer_order(bench, root, accepted)
-
-
-def _copy_of_the_benchmark(tmp_path):
-    root = str(tmp_path)
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
-    for path in BENCH["paths"]:
-        shutil.copytree(
-            os.path.join(ROOT, path), os.path.join(root, path),
-            ignore=shutil.ignore_patterns("__pycache__"),
-        )
-    return rules.load_bench(root), root
+    rules.check_accepted_prefix(bench, root, accepted)
 
 
 def _write(root, path, body):
@@ -168,49 +226,14 @@ def _write(root, path, body):
         json.dump(body, f)
 
 
-ML20M = "benchmarks/chip/configs/rec-pool-ml20m.json"
+ML20M = MADE_UP["config_file"]
 
 
 def _made_up_addition(tmp_path):
-    """A copy of the benchmark with what a `model_config` PR would bring
-    as files and entries: a configuration of another source's widths on
-    another server setting, one cell, two per-layer metrics with their
-    readers. Nothing of it is measured; the names are made up."""
-    bench, root = _copy_of_the_benchmark(tmp_path)
-    tenants, n_users, n_items, rank = 400, 138493, 26744, 10
-    body = {
-        **_config("rec-pool-kddcup11"),
-        "source": "MovieLens 20M: 138,493 users x 26,744 movies (Harper and Konstan, ACM TiiS 5(4), 2015)",
-        "deployment": "a pool of small tenants on one chip, posts of 256",
-        "published": {"n_users": n_users, "n_items": n_items, "rank": rank},
-        "n_users": n_users, "n_items": n_items, "rank": rank, "tenants": tenants,
-        "server": {**rules.DEPLOY_DEFAULTS, "max_batch": 256},
-        "resident_table_bytes": tenants * (n_users + n_items) * rank * 4,
-        "floor_note": "400 tenants x 6.61 MB are 2.64 GB, 15.4% of 16 GiB: over the "
-        "12.5% floor, which holds where the device is busy 75% of the traced window",
-        "assumed": {"tenants": tenants, "server.max_batch": 256, "zipf_exponent": 1.0, "num": 10},
-    }
-    _write(root, ML20M, body)
-    bench["configs"].append({
-        "name": "rec-pool-ml20m", "source": body["source"], "file": ML20M,
-        "reduced": [], "why": "hundreds of small tenants",
-    })
-    cell = "serve-pool-ml20m-batch256"
-    bench["workloads"].append({
-        "name": cell, "config": "rec-pool-ml20m", "traffic": "batch_closed_loop",
-        "chips": 1, "why": "posts of 256 over 400 small tenants",
-    })
-    next(m for m in bench["end_to_end"] if m["name"] == "queries_per_s")["workloads"].append(cell)
-    for name, spec in (
-        ("ml20m.evictions_per_s", {"reader": "counter_share", "families": ["pio_pool_evictions_total"], "over": "pio_process_clock_seconds_total"}),
-        ("ml20m.restage_ms", {"reader": "histogram_mean", "families": ["pio_pool_stage_seconds"], "scale": 1000.0}),
-    ):
-        bench["per_layer"].append({
-            "name": name, "unit": "1", "better": "lower", "source": "program_counter",
-            "layer": "tenant pool", "moves": "queries_per_s", "workloads": [cell],
-        })
-        _write(root, f"benchmarks/chip/metrics/{name.split('.')[1]}.json", spec)
-    return bench, root
+    """A copy of the benchmark with everything a `model_config` PR may
+    bring as files and entries (`made_up.addition`), for one test to
+    break."""
+    return made_up.grown_copy(ROOT, str(tmp_path))
 
 
 def _cut_a_width(bench, root):
@@ -235,6 +258,29 @@ def _shrink_the_pool(bench, root):
 
 def _insert_before_the_accepted(bench, root):
     bench["per_layer"].insert(3, bench["per_layer"].pop())
+
+
+def _put_the_new_cell_first(bench, root):
+    bench["workloads"].insert(0, bench["workloads"].pop())
+
+
+def _put_the_new_configuration_second(bench, root):
+    bench["configs"].insert(1, bench["configs"].pop())
+
+
+def _name_an_entry_twice(bench, root):
+    bench["per_layer"].append(dict(bench["per_layer"][-1]))
+
+
+def _restate_a_default_that_is_not_deploy_s(bench, root):
+    body = rules.config_body(bench, root, "rec-pool-ml20m")
+    _write(root, ML20M, {**body, "server": {**body["server"], "max_wait_ms": 5.0}})
+
+
+def _name_a_runner_that_is_not_there(bench, root):
+    body = rules.traffic_body(bench, root, MADE_UP["traffic"])
+    path = os.path.join(os.path.dirname(bench["command"][1]), "traffic", MADE_UP["traffic"] + ".json")
+    _write(root, path, {**body, "runner": "serve_elsewhere"})
 
 
 def _name_a_reference_that_is_not_there(bench, root):
@@ -262,27 +308,112 @@ def _leave_out_what_the_runner_reads(bench, root):
     (_set_the_server, "server setting 'pipeline_depth' differs from deploy's default and is not under assumed"),
     (_turn_the_cache_on, "server setting 'cache' differs from deploy's default and is not under assumed"),
     (_shrink_the_pool, "resident_table_bytes is under 12.5% of the memory"),
-    (_insert_before_the_accepted, "the accepted per-layer entries come first"),
+    (_insert_before_the_accepted, "the accepted per_layer names come first, in their order"),
+    (_put_the_new_cell_first, "the accepted workloads names come first, in their order"),
+    (_put_the_new_configuration_second, "the accepted configs names come first, in their order"),
+    (_name_an_entry_twice, "a name stands twice in per_layer"),
+    (_restate_a_default_that_is_not_deploy_s, "server setting 'max_wait_ms' differs from deploy's default and is not under assumed"),
+    (_name_a_runner_that_is_not_there, "no runner 'serve_elsewhere' under runners/"),
     (_name_a_reference_that_is_not_there, "names a reference 'reference_sequences' that is not there"),
     (_bring_a_reference_with_no_test, "no test_control_reference_sequences.py holds"),
-    (_leave_out_what_the_runner_reads, "runner 'serve_http' reads 'table_format'"),
+    (_leave_out_what_the_runner_reads, "runner 'serve_ml20m' reads 'table_format'"),
 ], ids=lambda v: getattr(v, "__name__", None) or ("sound" if v is None else "message"))
 def test_made_up_addition_meets_the_rules_and_each_fault_its_own(tmp_path, fault, message):
-    """A configuration of other widths, another server setting, a cell and
-    two per-layer entries land as files and entries with no test edited;
-    each planted fault fails on the assertion meant for it."""
+    """A configuration of other widths on another server setting with a
+    reference of its own, a cell, a traffic file, a runner and three
+    per-layer entries land as files and entries with no test edited; each
+    planted fault fails on the assertion meant for it."""
     bench, root = _made_up_addition(tmp_path)
     if fault is None:
-        _all_rules(bench, root, ACCEPTED_PER_LAYER)
-        _write(root, "BENCHMARK.json", bench)
+        _all_rules(bench, root, ACCEPTED)
         # and the harness itself finds the cell's files there
-        _b, cell, config, traffic = chip_run.load_cell("serve-pool-ml20m-batch256", True, root)
-        assert (config["rank"], config["server"]["max_batch"]) == (10, 256)
-        assert config["n_users"] == 3000 and traffic["runner"] == "serve_http"
+        _b, cell, config, traffic = chip_run.load_cell(MADE_UP["cell"], True, root)
+        assert (config["rank"], config["server"]) == (10, {"max_batch": 256})
+        assert config["n_users"] == 3000 and traffic["runner"] == MADE_UP["runner"]
+        assert traffic["burst"] == {"period_s": 0.5, "on_share": 0.5}
+        assert traffic["user_zipf_exponent"] == 1.0
         return
     fault(bench, root)
     with pytest.raises(AssertionError, match=re.escape(message)):
-        _all_rules(bench, root, ACCEPTED_PER_LAYER)
+        _all_rules(bench, root, ACCEPTED)
+
+
+def _block_check_with_a_tail_pin(bench, block, after):
+    """A block check as PR 28 wrote its own: whatever follows the accepted
+    names is the block."""
+    listed = [m["name"] for m in bench["per_layer"]]
+    assert listed[:len(after)] == after
+    assert listed[len(after):] == block, (
+        "the block is held to be the end of the list: the next PR's entry fails it"
+    )
+
+
+def test_a_block_check_that_pins_the_tail_fails_on_the_grown_copy(manifest):
+    """The fault that PR 28's test carried for four PRs, planted: written
+    over the repository's last three entries it passes on the repository,
+    as it did in the PR that wrote it, and fails on the grown copy with a
+    message of its own; that the repository's names are a prefix holds on
+    both."""
+    bench, root = manifest
+    tree = [m["name"] for m in rules.load_bench(ROOT)["per_layer"]]
+    block, after = tree[-3:], tree[:-3]
+    if root == ROOT:
+        _block_check_with_a_tail_pin(bench, block, after)
+    else:
+        with pytest.raises(AssertionError, match="held to be the end of the list"):
+            _block_check_with_a_tail_pin(bench, block, after)
+    listed = [m["name"] for m in bench["per_layer"]]
+    assert listed[:len(tree)] == tree
+
+
+def _from_the_copy(root, *command):
+    """A command run from the copy at ``root`` as the driver runs the
+    benchmark from a checkout; the program itself comes from the
+    repository, which the copy does not hold."""
+    env = {**os.environ, "PYTHONPATH": ROOT}
+    return subprocess.run(
+        [sys.executable, *command], capture_output=True, text=True,
+        timeout=300, cwd=root, env=env,
+    )
+
+
+def test_the_grown_copy_s_cell_rehearses_through_the_command(grown_root):
+    """The made-up cell runs from the copy through `run.py` itself, which
+    finds its configuration, its traffic file, the runner that file names
+    and the readers of its entries there, `.py` and `.json`: `correct`
+    true, the three appended names in the line, and every request of three
+    bursts attempted. Its reference's own control test runs there too."""
+    done = _from_the_copy(
+        grown_root, os.path.join("benchmarks", "chip", "run.py"), "--workload",
+        MADE_UP["cell"], "--seed", str(2**31 + 999), "--seconds", "1.5",
+        "--trace", "1", "--rehearse-cpu",
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True, line["compared"]
+    assert set(line["metrics"]) == set(MADE_UP["per_layer"])
+    assert line["metrics"][MADE_UP["per_layer"][2]]["value"] >= 1.0
+    # 50/s in periods of 0.5 s: 25 a burst, three bursts in the window
+    assert (line["attempted"], line["failed"]) == (75, 0)
+    done = _from_the_copy(
+        grown_root, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+        os.path.join(rules.TESTS, f"test_control_{MADE_UP['reference']}.py"),
+    )
+    assert done.returncode == 0 and "1 passed" in done.stdout, done.stdout[-2000:]
+
+
+def test_deploy_s_defaults_are_the_server_s_own():
+    """`DEPLOY_DEFAULTS` against `EngineServer.__init__`'s signature, read
+    from its text; the two keys the configurations no longer restate are
+    what they stated."""
+    found = rules.signature_defaults(
+        os.path.join(ROOT, "predictionio_tpu", "serving", "engine_server.py"),
+        "EngineServer",
+    )
+    assert {k: found[k] for k in rules.DEPLOY_DEFAULTS} == rules.DEPLOY_DEFAULTS
+    assert (found["pipeline_depth"], found["adaptive_wait"]) == (2, True)
+    for config in CONFIGS:
+        assert not {"pipeline_depth", "adaptive_wait"} & set(_config(config)["server"])
 
 
 def _re_source_the_widths(body):
@@ -300,7 +431,7 @@ def _tune_the_server(body):
     }
 
 
-@pytest.mark.parametrize("config", rules.ACCEPTED_CONFIGS)
+@pytest.mark.parametrize("config", rules.KDDCUP_CONFIGS)
 @pytest.mark.parametrize("alter,message", [
     (_re_source_the_widths, "the widths of KDD Cup 2011 Track 1, uncut"),
     (_halve_the_pool, "a quarter of 16 GiB resident"),
@@ -309,8 +440,8 @@ def _tune_the_server(body):
 def test_accepted_configuration_stays_pinned(tmp_path, config, alter, message):
     """An altered copy of an accepted configuration that meets the general
     rule (it says what it changed) still fails the pin of its name."""
-    bench, root = _copy_of_the_benchmark(tmp_path)
-    entry = next(c for c in bench["configs"] if c["name"] == config)
+    bench, root = made_up.copy_of_the_benchmark(ROOT, str(tmp_path))
+    entry = rules.entry(bench, "configs", config)
     _write(root, entry["file"], alter(rules.config_body(bench, root, config)))
     rules.check_config_entry(bench, root, entry)
     with pytest.raises(AssertionError, match=re.escape(message)):
@@ -349,6 +480,217 @@ def test_schedule_gives_every_seed_the_same_work_in_another_order():
     assert 0.9 < gaps.std() / gaps.mean() < 1.05
     w = loadgen.zipf_weights(24, 1.0)
     assert w.sum() == pytest.approx(1.0) and w[0] / w[1] == pytest.approx(2.0)
+
+
+#: SHA-256 of what the generator drew at commit a7d4146 (PR 31), before its
+#: optional parameters came: `arrival_schedule` at the single cell's
+#: parameters (350/s, 53 s, 24 tenants, exponent 1.0); the first 2,000
+#: requests (tenant, body) of the open loop's process 0 of 2 and the 400
+#: requests it kept; the first 20 posts of a closed-loop client before the
+#: window opens and inside it (where the kept sample draws from the same
+#: generator). Taken by running the parent's loops over the fake socket below.
+PARENT_DIGESTS = {
+    11: {
+        "schedule": "1c51f0d70718f0ff22c23e7eacb31acf30d000fb291b58d11b18a781966faddb",
+        "open": "12b3ecbba6e936071993688ff2d01c1d682e66855e6f3cfbd4158a9be1fd07c6",
+        "open_kept": "75447b64922d4d322ef3938170c2f5ac2fd929fb28caee52359be0ec449b86a1",
+        "closed_settle": "73ac38be5e22a299642257601fe0b3d19533e0ea59b6e564879b314363f62fd3",
+        "closed_window": "b1a5bbf7a76d9355fb4e6db5c3ab732951c3692c785dcff0c4b3d40c441dbade",
+    },
+    2147483725: {
+        "schedule": "3ebd16d773e960e9540e8bb9b8c011eca47834956d608aa8194a4b58219fb52b",
+        "open": "ac058fe05ba149effce0173aacc19e5c497fa3bf3a782bb9c779366e9bd4d922",
+        "open_kept": "64e256df44687d743dc855b7c8a1de6ee5f0d606d6cc2dedf0812e8054dd6ad6",
+        "closed_settle": "9c711a5066e2c013df880e63ab0bac58cdafa37e7f7cb68598c6140d6335fa84",
+        "closed_window": "9f5d0b6084dc22e303e34628abc47cc8d6b24a5200a3ad2c8e48faaaac53ebd6",
+    },
+}
+_TENANT_NAMES = [f"tenant{t}" for t in range(24)]
+_PLAN = {
+    "host": "h", "port": 1, "tenants": _TENANT_NAMES, "zipf_exponent": 1.0,
+    "n_users": 1000990, "num": 10, "proc": 0,
+}
+_OPEN_PLAN = {
+    **_PLAN, "loop": "open", "t_start": 0.0, "t_window": 8.0, "t_end": 53.0,
+    "rate": 350.0, "procs": 2, "clients": 1, "keep": 400,
+}
+
+
+def _sha(parts):
+    digest = hashlib.sha256()
+    for part in parts:
+        digest.update(part)
+    return digest.hexdigest()
+
+
+def _sent_lines(sent):
+    return [str(t).encode() + b" " + body + b"\n" for t, body in sent]
+
+
+def _drive(monkeypatch, loop, plan, limit):
+    """``(the first limit (tenant, body) sent, the loop's result)`` of a
+    generator loop over a socket that answers at once, on a clock that
+    stands at 0 until ``limit`` requests are out and is past every end
+    after."""
+    sent = []
+
+    class Clock:
+        def monotonic(self):
+            return 0.0 if len(sent) < limit else 1e9
+
+        def sleep(self, seconds):
+            pass
+
+    class Connection:
+        def __init__(self, *args, **kwargs):
+            pass
+
+        def close(self):
+            pass
+
+    def post(conn, path, body):
+        if len(sent) < limit:
+            sent.append((_TENANT_NAMES.index(path.split("accessKey=")[1]), body))
+        return 200, b"[]" if loop == "closed" else b'{"itemScores": []}'
+
+    monkeypatch.setattr(loadgen, "time", Clock())
+    monkeypatch.setattr(loadgen, "_post", post)
+    monkeypatch.setattr(loadgen.http.client, "HTTPConnection", Connection)
+    run = {"closed": loadgen.run_closed, "open": loadgen.run_open}[loop]
+    return sent, run(plan)
+
+
+@pytest.mark.parametrize("seed", sorted(PARENT_DIGESTS))
+def test_without_its_optional_parameters_the_generator_draws_what_it_drew(monkeypatch, seed):
+    """Bit for bit: the schedule, and what both loops send and keep."""
+    want = PARENT_DIGESTS[seed]
+    offsets, who = loadgen.arrival_schedule(seed, 350.0, 53.0, 24, 1.0)
+    assert len(offsets) == 18550
+    assert _sha([offsets.astype("<f8").tobytes(), who.astype("<i8").tobytes()]) == want["schedule"]
+    sent, out = _drive(monkeypatch, "open", {**_OPEN_PLAN, "seed": seed}, 2000)
+    assert _sha(_sent_lines(sent)) == want["open"]
+    assert (len(out["requests"]), len(out["kept"])) == (9275, 400)
+    assert _sha([json.dumps([k[0], k[1]]).encode() for k in out["kept"]]) == want["open_kept"]
+    for name, t_window in (("closed_settle", 1e18), ("closed_window", 0.0)):
+        plan = {
+            **_PLAN, "loop": "closed", "seed": seed, "t_window": t_window,
+            "t_end": 1.0, "clients": 1, "batch": 64, "keep": 4,
+        }
+        sent, _out = _drive(monkeypatch, "closed", plan, 20)
+        assert len(sent) == 20 and _sha(_sent_lines(sent)) == want[name], name
+
+
+@pytest.mark.parametrize("seed", sorted(PARENT_DIGESTS))
+def test_the_loops_send_what_the_pure_draws_give(seed):
+    """`draw_open` and `draw_post` are the loops' draws, so the digests hold
+    for them too, and a test of a parameter needs no socket."""
+    plan = {**_OPEN_PLAN, "seed": seed}
+    offsets, tenants, users, keep = loadgen.draw_open(plan)
+    lines = [
+        (int(t), json.dumps({"user": f"u{u}", "num": 10}).encode())
+        for t, u in zip(tenants[:2000], users[:2000])
+    ]
+    assert _sha(_sent_lines(lines)) == PARENT_DIGESTS[seed]["open"]
+    assert len(keep) == 400 and min(offsets[k] for k in keep) >= 8.0
+    rng = np.random.default_rng([seed, 0, 0])
+    weights = loadgen.zipf_weights(24, 1.0)
+    posts = []
+    for _ in range(20):
+        tenant, drawn = loadgen.draw_post(rng, {**_PLAN, "batch": 64}, weights)
+        posts.append((tenant, json.dumps([{"user": f"u{u}", "num": 10} for u in drawn]).encode()))
+    assert _sha(_sent_lines(posts)) == PARENT_DIGESTS[seed]["closed_settle"]
+    # absent keys are absent from the plan; a present one goes through
+    from runners import serve_http
+
+    assert serve_http.generator_parameters({"loop": "open"}) == {}
+    named = {"loop": "open", "burst": {"period_s": 1.0, "on_share": 0.25}, "user_zipf_exponent": 1.0}
+    assert serve_http.generator_parameters(named) == {k: named[k] for k in loadgen.OPTIONAL_PARAMETERS}
+
+
+def test_bursts_hold_each_period_s_arrivals_in_its_first_share():
+    burst = {"period_s": 2.0, "on_share": 0.25}
+    a, ta = loadgen.arrival_schedule(2**31 + 7, 350.0, 10.0, 24, 1.0, burst=burst)
+    c, tc = loadgen.arrival_schedule(2**31 + 8, 350.0, 10.0, 24, 1.0, burst=burst)
+    assert len(a) == len(c) == 3500 and np.all(np.diff(a) > 0)
+    assert not np.array_equal(a[:50], c[:50])
+    # no arrival outside the first quarter of a period; every period of
+    # every seed holds 700, so the mean rate over a period is the rate
+    for offsets in (a, c):
+        assert np.all(offsets % 2.0 < 0.5)
+        assert np.bincount((offsets // 2.0).astype(int)).tolist() == [700] * 5
+    assert len(a) / 10.0 == 350.0
+    # inside a burst the rate is rate / on_share
+    inside = np.diff(a[:700])
+    assert inside.mean() == pytest.approx(0.25 / 350.0, rel=0.01)
+    # the same gaps and the same share of each tenant as a plain block of
+    # that length, scaled, in another order
+    plain, tp = loadgen.arrival_schedule(2**31 + 7, 350.0, 10.0, 24, 1.0, block_s=2.0)
+    assert np.allclose(np.sort(np.diff(a[:700])), 0.25 * np.sort(np.diff(plain[:700])))
+    assert np.array_equal(np.bincount(ta[:700], minlength=24), np.bincount(tp[:700], minlength=24))
+    assert np.array_equal(np.bincount(ta[:700], minlength=24), np.bincount(tc[1400:2100], minlength=24))
+    # a whole share is the plain schedule of that block length, bit for bit
+    whole, tw = loadgen.arrival_schedule(2**31 + 7, 350.0, 10.0, 24, 1.0, burst={"period_s": 2.0, "on_share": 1.0})
+    assert np.array_equal(whole, plain) and np.array_equal(tw, tp)
+    for bad in ({"period_s": 1.0, "on_share": 0.0}, {"period_s": 1.0, "on_share": 1.5}, {"period_s": 0.0, "on_share": 0.5}):
+        with pytest.raises(ValueError, match="burst wants"):
+            loadgen.arrival_schedule(1, 350.0, 10.0, 24, 1.0, burst=bad)
+
+
+def test_a_closed_loop_that_names_a_burst_is_refused_not_ignored():
+    from runners import serve_http
+
+    traffic = {**rules.traffic_body(BENCH, ROOT, "batch_closed_loop"), "burst": {"period_s": 1.0, "on_share": 0.5}}
+    with pytest.raises(SystemExit, match="'burst' on a closed loop"):
+        serve_http.generator_parameters(traffic)
+    with pytest.raises(SystemExit, match="'burst' on a closed loop"):
+        serve_http.run({}, {}, {}, traffic, None, 0.0, {})
+
+
+def test_zipf_users_are_hot_in_each_tenant_s_own_order():
+    n_users, seed = 100_000, 2**31 + 5
+    plan = {"seed": seed, "n_users": n_users, "tenants": ["a", "b", "c"], "user_zipf_exponent": 1.0}
+    assert loadgen.user_skew({**plan, "user_zipf_exponent": None}) is None
+    assert loadgen.user_skew({k: v for k, v in plan.items() if k != "user_zipf_exponent"}) is None
+    skew = loadgen.user_skew(plan)
+    # a tenant's order is a permutation of its users
+    step, start = loadgen.user_order(seed, 0, n_users)
+    order = (start + step * np.arange(n_users)) % n_users
+    assert np.array_equal(np.sort(order), np.arange(n_users))
+    assert loadgen.user_order(seed, 0, n_users) == (step, start)
+    small = loadgen.user_order(3, 1, 7)
+    assert sorted((small[1] + small[0] * r) % 7 for r in range(7)) == list(range(7))
+    # the top 1% of the order draw the share the weights give them
+    rng = np.random.default_rng(1)
+    users = loadgen.zipf_users(rng, skew, np.zeros(200_000, int))
+    weights = loadgen.zipf_weights(n_users, 1.0)
+    hot = np.isin(users, order[: n_users // 100])
+    assert hot.mean() == pytest.approx(weights[: n_users // 100].sum(), abs=0.005)
+    assert np.mean(users == order[0]) == pytest.approx(weights[0], abs=0.003)
+    # the tenants' hottest users differ, and a tenant's hot users are no
+    # run of low row numbers
+    hottest = [loadgen.user_order(seed, t, n_users)[1] for t in range(3)]
+    assert len(set(hottest)) == 3 and max(hottest) > 1000
+    assert np.min(np.abs(np.diff(order[:100]))) > 1 and order[:100].max() > n_users // 2
+    mixed = loadgen.zipf_users(np.random.default_rng(2), skew, np.array([0, 1, 2] * 20_000))
+    for t in range(3):
+        values, counts = np.unique(mixed[t::3], return_counts=True)
+        assert values[np.argmax(counts)] == hottest[t]
+    # both loops: the closed loop's post is of one tenant's order, the open
+    # loop's users of each request's own tenant; the kept entries carry them
+    tenant, drawn = loadgen.draw_post(
+        np.random.default_rng(3), {**plan, "batch": 4096}, loadgen.zipf_weights(3, 1.0), skew
+    )
+    assert np.mean(np.array(drawn) == hottest[tenant]) == pytest.approx(weights[0], abs=0.02)
+    open_plan = {
+        **plan, "t_start": 0.0, "t_window": 1.0, "t_end": 21.0, "rate": 1000.0,
+        "zipf_exponent": 1.0, "proc": 0, "procs": 1, "keep": 50,
+    }
+    _offsets, tenants, users, keep = loadgen.draw_open(open_plan)
+    assert len(users) == 21_000 and len(keep) == 50
+    for t in range(3):
+        assert np.mean(users[tenants == t] == hottest[t]) == pytest.approx(weights[0], abs=0.02)
+    uniform = loadgen.draw_open({k: v for k, v in open_plan.items() if k != "user_zipf_exponent"})[2]
+    assert np.mean(uniform == hottest[0]) < 0.001
 
 
 def test_window_numbers_cover_all_requests_of_the_window():
@@ -642,7 +984,7 @@ def _rehearse(workload, seed=11, seconds=1.5, trace=0):
     ("serve-pool-int8-batch", "half_left_out"),
     ("serve-pool-single", "altered_answer"),
 ])
-def test_broken_timed_path_is_not_correct(monkeypatch, cell, fault):
+def test_broken_timed_path_is_not_correct(monkeypatch, servers_built, cell, fault):
     """The rest of a run, the look for a chip skipped, over a predict path
     that alters one answer of each device batch where it is produced, or
     leaves half of the batch out: `correct` comes out false. (A lone query
@@ -664,15 +1006,19 @@ def test_broken_timed_path_is_not_correct(monkeypatch, cell, fault):
     monkeypatch.setattr(ALSAlgorithm, "batch_predict_collect", broken)
     result = _rehearse(cell)
     assert result["correct"] is False
+    # the server the cell builds is the one it built when its file restated
+    # the two defaults
+    assert servers_built == [(2, True)]
     if fault == "half_left_out":
         assert result["compared"]["bad_answers"][0] > 0
     else:
         assert result["compared"]["rank_gap_rms"][0] > result["compared"]["rank_gap_rms"][1]
 
 
-def test_sound_rehearsal_is_correct_and_evicts_nothing():
+def test_sound_rehearsal_is_correct_and_evicts_nothing(servers_built):
     result = _rehearse("serve-pool-single", seed=2**31 + 12345, trace=1)
     assert result["correct"] is True, result["compared"]
+    assert servers_built == [(2, True)]
     assert result["failed"] == 0 and result["attempted"] > 20
     assert result["compared"]["evictions"] == [0.0, 0]
     assert "single.queue_wait_ms" in result["metrics"]
