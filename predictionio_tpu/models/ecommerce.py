@@ -58,6 +58,8 @@ from predictionio_tpu.core import (
 from predictionio_tpu.core.controller import SanityCheck
 from predictionio_tpu.data.eventframe import Interactions
 from predictionio_tpu.data.store import EventStore
+from predictionio_tpu.models import staged_rules
+from predictionio_tpu.models.staged_rules import StagedRules
 from predictionio_tpu.obs import tracing
 from predictionio_tpu.ops import similarity
 from predictionio_tpu.ops.als import train_als
@@ -73,9 +75,6 @@ RECENT_VIEWS = 10
 #: users whose resolved seen items a tenant keeps beside the store's
 #: version of them; past it the oldest half goes
 _SEEN_CACHE_USERS = 1 << 18
-#: the item rows of a query that names none and whose user has seen none
-_NO_ROWS = np.empty(0, np.int32)
-_NO_ROWS.setflags(write=False)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -130,30 +129,6 @@ class ECommAlgorithmParams(Params):
 
 
 @dataclasses.dataclass
-class StagedRules:
-    """What `stage_model` keeps on the device beside the factors, one
-    entry per row of the (padded) item table; charged to the tenant by
-    ``quantize.model_resident_bytes`` through its array fields."""
-
-    categories: jax.Array     # [C, rows] int32 category ids, -1 = none
-    unavailable: jax.Array    # [rows] bool: phantom rows + the constraint
-    inv_norm: jax.Array       # [rows] f32
-    popularity: jax.Array     # [rows] f32
-    category_ids: dict        # category name -> id
-    #: version and event id of the ``$set`` `unavailable` was built from
-    constraint: tuple = (None, None)
-    #: user -> (the store's version of the user, seen item rows)
-    seen: dict = dataclasses.field(default_factory=dict)
-
-    @property
-    def catalog(self) -> similarity.CatalogRules:
-        return similarity.CatalogRules(
-            self.categories, self.unavailable, self.inv_norm,
-            self.popularity,
-        )
-
-
-@dataclasses.dataclass
 class ECommModel:
     # host np.ndarray after train, device jax.Array after staging
     user_factors: np.ndarray | jax.Array
@@ -177,66 +152,8 @@ class ECommModel:
     rules: "StagedRules | None" = None
 
 
-@jax.jit
-def _inverse_norms(items):
-    norm = jnp.linalg.norm(items, axis=1)
-    return jnp.where(norm > 0, 1.0 / norm, 0.0).astype(jnp.float32)
-
-
-def _pad_rows(x, rows: int, value=0):
-    """``x`` with its first axis padded to ``rows`` (host or device)."""
-    short = rows - x.shape[0]
-    if short <= 0:
-        return x
-    widths = [(0, short)] + [(0, 0)] * (x.ndim - 1)
-    if isinstance(x, jax.Array):
-        return jnp.pad(x, widths, constant_values=value)
-    return np.pad(np.asarray(x), widths, constant_values=value)
-
-
-def _encode_categories(model: ECommModel):
-    """``(name -> id, [C, I] int32 host or device array)``."""
-    if model.category_rows is not None:
-        names = model.category_names or ()
-        return {n: i for i, n in enumerate(names)}, model.category_rows
-    ids: dict[str, int] = {}
-    per_item = []
-    for item, cats in model.item_categories.items():
-        row = model.item_map.get(item, -1)
-        if row >= 0 and cats:
-            per_item.append(
-                (row, [ids.setdefault(c, len(ids)) for c in cats])
-            )
-    width = max((len(c) for _, c in per_item), default=1)
-    rows = np.full((width, len(model.item_map)), -1, np.int32)
-    for row, cats in per_item:
-        rows[: len(cats), row] = cats
-    return ids, rows
-
-
-def _bucket(n: int) -> int:
-    return 1 << max(0, n - 1).bit_length()
-
-
-def _listed_rows(get, black, white, seen: np.ndarray) -> np.ndarray:
-    """The item rows of one query's list: what to leave out (``seen`` and
-    the blackList), or with a whiteList what alone may come back (white -
-    black - seen). ``get`` maps an item id to its row; an id the model
-    does not know is dropped."""
-    out = [get(str(x), -1) for x in black]
-    if not white:
-        return np.concatenate(
-            [seen, np.array([r for r in out if r >= 0], np.int32)]
-        )
-    rows = {get(str(x), -1) for x in white}
-    rows.difference_update(out, seen.tolist(), (-1,))
-    return np.fromiter(rows, np.int32, len(rows))
-
-
-class _Counters:
+class _Counters(staged_rules.RegistryCounters):
     """The template's counters in one registry."""
-
-    _by_registry: dict = {}
 
     def __init__(self, registry):
         self.queries = registry.counter(
@@ -282,14 +199,6 @@ class _Counters:
         self.seen = {
             r: self.seen_lookups.labels(r) for r in ("hit", "miss")
         }
-
-    @classmethod
-    def of(cls, registry) -> "_Counters":
-        found = cls._by_registry.get(id(registry))
-        if found is None or found[0] is not registry:
-            found = (registry, cls(registry))
-            cls._by_registry[id(registry)] = found
-        return found[1]
 
 
 class ECommAlgorithm(Algorithm):
@@ -338,38 +247,25 @@ class ECommAlgorithm(Algorithm):
         items from the first predict on). ``model.popularity`` itself
         stays as trained."""
         n_items = len(model.item_map)
-        multiple = np.lcm(
-            similarity.CATALOG_ROW_MULTIPLE, max(ctx.model_parallelism, 1)
-        )
-        rows = -(-n_items // multiple) * multiple
         user_f, _ = partition.stage_factor_matrix(
             ctx, model.user_factors, n_real=len(model.user_map)
         )
         item_f, item_mask = partition.stage_factor_matrix(
-            ctx, _pad_rows(model.item_factors, rows), n_real=n_items
+            ctx,
+            similarity.pad_rows(
+                model.item_factors, staged_rules.padded_rows(ctx, n_items)
+            ),
+            n_real=n_items,
         )
         return dataclasses.replace(
             model,
             user_factors=user_f,
             item_factors=item_f,
             item_phantom_mask=item_mask,
-            rules=self._stage_rules(
-                model, item_f, NamedSharding(ctx.mesh, PartitionSpec())
+            rules=staged_rules.stage(
+                model, item_f, NamedSharding(ctx.mesh, PartitionSpec()),
+                popularity=model.popularity,
             ),
-        )
-
-    def _stage_rules(self, model, item_f, sharding=None) -> StagedRules:
-        rows, n_items = item_f.shape[0], len(model.item_map)
-        ids, categories = _encode_categories(model)
-        put = lambda x: jax.device_put(x, sharding)  # noqa: E731
-        return StagedRules(
-            categories=put(_pad_rows(categories.T, rows, -1).T),
-            unavailable=put(np.arange(rows) >= n_items),
-            inv_norm=put(_inverse_norms(item_f)),
-            popularity=put(
-                _pad_rows(model.popularity, rows).astype(np.float32)
-            ),
-            category_ids=ids,
         )
 
     # -- serve-time business rules (reference ECommAlgorithm.predict) -----
@@ -497,8 +393,9 @@ class ECommAlgorithm(Algorithm):
             return None
         with tracing.stage(tracing.PREDICT_PREP):
             if model.rules is None:  # an unstaged model (evaluation)
-                model.rules = self._stage_rules(
-                    model, jnp.asarray(model.item_factors)
+                model.rules = staged_rules.stage(
+                    model, jnp.asarray(model.item_factors),
+                    popularity=model.popularity,
                 )
             rules = model.rules
             counters = _Counters.of(tracing.bound_registry())
@@ -513,10 +410,11 @@ class ECommAlgorithm(Algorithm):
                 for q in queries
             ])
             num = min(max(1, max(nums)), n_items)
-            num_bucket = min(_bucket(num), n_items)
+            num_bucket = min(similarity.bucket(num), n_items)
             # the batch's operands, filled in place through these views
             operands = similarity.QueryRules.blank(
-                _bucket(n), _bucket(max(1, max(map(len, wanted))))
+                similarity.bucket(n),
+                similarity.bucket(max(1, max(map(len, wanted)))),
             )
             per_query, q_cats = operands.per_query, operands.categories
             user_rows = np.fromiter(
@@ -525,7 +423,8 @@ class ECommAlgorithm(Algorithm):
             mode = np.where(
                 user_rows >= 0, similarity.KNOWN, similarity.POPULAR
             )
-            lists = [_NO_ROWS] * n  # each user's seen rows, then the rest
+            # each user's seen rows, then the rest
+            lists = [staged_rules.NO_ROWS] * n
             with tracing.stage(tracing.PREDICT_RULES):
                 reader = self._reader(model)
                 try:
@@ -547,7 +446,9 @@ class ECommAlgorithm(Algorithm):
             get = model.item_map.getter()
             for i in range(n):
                 if black[i] or white[i]:
-                    lists[i] = _listed_rows(get, black[i], white[i], lists[i])
+                    lists[i] = staged_rules.listed_rows(
+                        get, black[i], white[i], lists[i]
+                    )
                     operands.allow[i] = bool(white[i])
             filtered = [i for i in range(n) if wanted[i]]
             category_id = rules.category_ids.get
@@ -557,7 +458,9 @@ class ECommAlgorithm(Algorithm):
                     category_id(str(c), similarity.NO_CATEGORY - 1)
                     for c in wanted[i]
                 ]
-            operands = operands._replace(lists=similarity.pack_lists(lists))
+            operands = dataclasses.replace(
+                operands, lists=similarity.pack_lists(lists)
+            )
             # the batch's counts, each counter once
             counters.rule["blackList"].inc(sum(map(bool, black)))
             counters.rule["whiteList"].inc(sum(map(bool, white)))
@@ -583,21 +486,12 @@ class ECommAlgorithm(Algorithm):
         with tracing.stage(tracing.PREDICT_DEVICE_GET):
             scores, items = jax.device_get((scores, items))
         with tracing.stage(tracing.PREDICT_MATERIALIZE):
-            out, short = [], 0
-            inverse = model.item_map.inverse
-            filled = (scores > -np.inf).sum(axis=1)
-            for i, num in enumerate(nums):
-                n = min(num, int(filled[i]))
-                short += n < num
-                out.append({
-                    "itemScores": [
-                        {
-                            "item": inverse(int(items[i, j])),
-                            "score": float(scores[i, j]),
-                        }
-                        for j in range(n)
-                    ]
-                })
+            out = staged_rules.served_lists(
+                scores, items, nums, model.item_map.inverse
+            )
+            short = sum(
+                len(a["itemScores"]) < num for a, num in zip(out, nums)
+            )
             if short:
                 counters.short.inc(short)
         return out

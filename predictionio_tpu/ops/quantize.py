@@ -103,8 +103,9 @@ def quantize_factors(x, mode: str = "int8") -> QuantizedFactors:
 
 
 def dequantize(qf: QuantizedFactors) -> jax.Array:
-    """Full f32 reconstruction (tests/eval only — serving never
-    materializes this)."""
+    """Full f32 reconstruction. Serving materializes it only inside the
+    rules step's program, which has no quantized kernel
+    (:func:`predictionio_tpu.ops.similarity.rules_top_k`)."""
     x = qf.data.astype(jnp.float32)
     if qf.scale is not None:
         x = x * qf.scale[:, None]

@@ -4,13 +4,15 @@ Replaces the reference's per-query RDD predict (ALSAlgorithm.predict:
 ``productFeatures.lookup`` + cosine ``collect`` — a Spark job per query,
 the serving anti-pattern SURVEY.md §3.2 flags) with pre-compiled dense
 scoring: one [B, k] × [k, I] matmul + ``lax.top_k``. The same kernels
-serve the recommendation template (dot-product scores) and the
-similar-product template (cosine over item factors,
+serve the recommendation template (dot-product scores); the e-commerce
+and similar-product templates share the rules step (`rules_top_k`: the
+summed cosine to a set of items is its `SIMILAR` branch,
 examples/scala-parallel-similarproduct/multi/.../ALSAlgorithm.scala).
 """
 
 from __future__ import annotations
 
+import dataclasses
 from functools import lru_cache, partial
 from typing import NamedTuple
 
@@ -243,7 +245,10 @@ def gather_top_k_dot(
 #: a query's branch (reference ECommAlgorithm.predict / predictSimilar /
 #: predictDefault)
 KNOWN, SIMILAR, POPULAR = 0, 1, 2
-#: item rows gathered for the SIMILAR branch (the latest 10 views, padded)
+#: item rows a query of the SIMILAR branch may name (the e-commerce
+#: template's latest 10 views, padded); a caller whose queries name more (the
+#: similar-product template's baskets) asks for a power of two times this
+#: many (`recent_slots`), a shape of the compiled step
 RECENT_SLOTS = 16
 #: padding of a query's category slots; an unknown category is any other
 #: negative id, which filters and matches nothing
@@ -268,42 +273,68 @@ class CatalogRules(NamedTuple):
     categories: jax.Array    # [C, rows] int32, -1 = no category
     unavailable: jax.Array   # [rows] bool
     inv_norm: jax.Array      # [rows] f32, 1/|V[i]| (0 for a zero row)
-    popularity: jax.Array    # [rows] f32
+    popularity: jax.Array | None  # [rows] f32; None: no query is POPULAR
 
 
-class QueryRules(NamedTuple):
+@partial(
+    jax.tree_util.register_dataclass,
+    data_fields=("per_query", "lists"), meta_fields=("item_slots",),
+)
+@dataclasses.dataclass(frozen=True)
+class QueryRules:
     """One batch's rules as two compact host arrays, which the jitted step
     uploads itself and slices apart on the device: each host operand of a
     call costs the launching thread 0.7-0.9 ms on a busy server, whatever
     its size (PERF.md section 6, PR 29). The rows' lists (seen + blackList,
     or a whiteList) are packed together, sorted by item row so that a
-    kernel streaming item blocks finds each block's entries side by side."""
+    kernel streaming item blocks finds each block's entries side by side.
+    ``item_slots`` is no operand: it says where ``per_query``'s columns
+    divide, and is a shape of the compiled step."""
 
-    per_query: np.ndarray    # [B, 3 + RECENT_SLOTS + QC] int32, by column:
+    per_query: np.ndarray    # [B, 3 + item_slots + QC] int32, by column:
                              # idx, mode, allow, recent, categories
     lists: np.ndarray        # [2, N] int32: list_rows, list_cols (`pack_lists`)
+    item_slots: int = RECENT_SLOTS  # item rows a SIMILAR query may name
 
     @classmethod
-    def blank(cls, batch: int, slots: int) -> "QueryRules":
-        """``batch`` rows that ask user row 0 for the popular items of the
-        whole catalog, with ``slots`` category slots and no lists yet: a
+    def blank(
+        cls, batch: int, slots: int, item_slots: int = RECENT_SLOTS,
+        mode: int = POPULAR,
+    ) -> "QueryRules":
+        """``batch`` rows of branch ``mode`` that name no item (POPULAR:
+        user row 0 asks for the popular items of the whole catalog;
+        SIMILAR: an empty answer), with ``item_slots`` item slots
+        (`recent_slots`), ``slots`` category slots and no lists yet: a
         launch fills the views in place and adds ``lists`` last
-        (``_replace``), so nothing is copied together."""
-        return cls(_fresh(_blank_per_query(batch, slots)), None)
+        (``dataclasses.replace``), so nothing is copied together."""
+        return cls(
+            _fresh(_blank_per_query(batch, slots, item_slots, mode)), None,
+            item_slots,
+        )
 
     # views, of the host arrays here and of the traced ones in the step
     idx = property(lambda r: r.per_query[:, 0])     # [B] user rows
     mode = property(lambda r: r.per_query[:, 1])    # [B] KNOWN, SIMILAR, POPULAR
     allow = property(lambda r: r.per_query[:, 2])   # [B] 1: a whiteList
-    recent = property(                              # [B, RECENT_SLOTS], -1 = none
-        lambda r: r.per_query[:, 3:3 + RECENT_SLOTS]
+    recent = property(                              # [B, item_slots], -1 = none
+        lambda r: r.per_query[:, 3:3 + r.item_slots]
     )
     categories = property(                          # [B, QC], NO_CATEGORY = padding
-        lambda r: r.per_query[:, 3 + RECENT_SLOTS:]
+        lambda r: r.per_query[:, 3 + r.item_slots:]
     )
     list_rows = property(lambda r: r.lists[0])      # [N] each entry's query row
     list_cols = property(lambda r: r.lists[1])      # [N] its item row, ascending;
                                                     # NO_ITEM in the unused tail
+
+
+def recent_slots(longest: int) -> int:
+    """Item slots of a batch whose longest query names ``longest`` items:
+    `RECENT_SLOTS` times a power of two, so that baskets of any length
+    take few shapes of the compiled step."""
+    slots = RECENT_SLOTS
+    while slots < longest:
+        slots *= 2
+    return slots
 
 
 def list_capacity(total: int) -> int:
@@ -338,14 +369,17 @@ def _copy_held(dst: np.ndarray, src) -> None:
 
 
 @lru_cache(maxsize=64)
-def _blank_per_query(batch: int, slots: int) -> np.ndarray:
-    per_query = np.zeros((batch, 3 + RECENT_SLOTS + slots), np.int32)
-    rules = QueryRules(per_query, None)
-    rules.mode[:] = POPULAR
+def _blank_per_query(
+    batch: int, slots: int, item_slots: int, mode: int
+) -> np.ndarray:
+    rules = QueryRules(
+        np.zeros((batch, 3 + item_slots + slots), np.int32), None, item_slots
+    )
+    rules.mode[:] = mode
     rules.recent[:] = -1
     rules.categories[:] = NO_CATEGORY
-    per_query.setflags(write=False)
-    return per_query
+    rules.per_query.setflags(write=False)
+    return rules.per_query
 
 
 @lru_cache(maxsize=8)
@@ -389,6 +423,32 @@ def pack_lists(lists) -> np.ndarray:
         _copy_held(packed[0, :total], halves[:, 0])
         _copy_held(packed[1, :total], halves[:, 1])
     return packed
+
+
+# -- shapes and per-item arrays a template stages for the step ---------------
+
+
+def bucket(n: int) -> int:
+    """The power of two at or above ``n``: batch rows, category slots and
+    the top-k size take few shapes of the compiled step."""
+    return 1 << max(0, n - 1).bit_length()
+
+
+@jax.jit
+def inverse_norms(items):
+    norm = jnp.linalg.norm(items, axis=1)
+    return jnp.where(norm > 0, 1.0 / norm, 0.0).astype(jnp.float32)
+
+
+def pad_rows(x, rows: int, value=0):
+    """``x`` with its first axis padded to ``rows`` (host or device)."""
+    short = rows - x.shape[0]
+    if short <= 0:
+        return x
+    widths = [(0, short)] + [(0, 0)] * (x.ndim - 1)
+    if isinstance(x, jax.Array):
+        return jnp.pad(x, widths, constant_values=value)
+    return np.pad(np.asarray(x), widths, constant_values=value)
 
 
 def _listed_mask(list_rows, list_cols, batch: int, rows: int) -> jax.Array:
@@ -437,18 +497,23 @@ def _rules_top_k(
 ):
     batch, rows = rules.per_query.shape[0], items.shape[0]
     mode = rules.mode[:, None]
+    named, q_cats = rules.recent, rules.categories
+    items = _widened(items)
     with jax.named_scope("gather"):
-        vecs = jnp.take(factors, rules.idx, axis=0)
-        recent = jnp.clip(rules.recent, 0, None)
-        weight = jnp.where(
-            rules.recent >= 0, jnp.take(catalog.inv_norm, recent), 0.0
-        )
-        views = (jnp.take(items, recent, axis=0) * weight[:, :, None]).sum(1)
-        vecs = jnp.where(mode == SIMILAR, views, vecs)
-    per_query = (mode, rules.allow[:, None], rules.categories)
+        at = jnp.clip(named, 0, None)
+        weight = jnp.where(named >= 0, jnp.take(catalog.inv_norm, at), 0.0)
+        vecs = (jnp.take(items, at, axis=0) * weight[:, :, None]).sum(1)
+        if factors is not None:  # None: no user table, every row SIMILAR
+            vecs = jnp.where(mode == SIMILAR, vecs, _rows(factors, rules.idx))
+    per_query = (mode, rules.allow[:, None], q_cats)
+    # a catalog with no popularity serves no POPULAR row: any per-item
+    # array stands in its operand's place and is never read into a score
+    popularity = (
+        catalog.inv_norm if catalog.popularity is None else catalog.popularity
+    )
     per_item = (
         catalog.categories, catalog.unavailable[None, :],
-        catalog.inv_norm[None, :], catalog.popularity[None, :],
+        catalog.inv_norm[None, :], popularity[None, :],
     )
     if fused:
         from predictionio_tpu.ops.pallas_topk import fused_rules_top_k
@@ -467,6 +532,26 @@ def _rules_top_k(
         return jax.lax.top_k(scores, num)
 
 
+def _widened(table):
+    """``table`` as f32 in a trace. The rules step has no quantized
+    kernel: a pool's int8/bf16 table is widened here, inside the one
+    program (`rules_top_k`)."""
+    if _quantized(table):
+        from predictionio_tpu.ops import quantize
+
+        return quantize.dequantize(table)
+    return table
+
+
+def _rows(table, at):
+    """``table[at]`` in a trace, f32 rows of a quantized table too."""
+    if _quantized(table):
+        from predictionio_tpu.ops import quantize
+
+        return quantize.gather_rows(table, at)
+    return jnp.take(table, at, axis=0)
+
+
 def rules_top_k(
     factors, items, num: int, catalog: CatalogRules, rules: QueryRules
 ) -> tuple[jax.Array, jax.Array]:
@@ -476,20 +561,17 @@ def rules_top_k(
     Row b's candidates: not ``catalog.unavailable``; in one of the
     query's categories if it names any; on its list if ``allow[b]``, off
     it otherwise. Its scores: ``factors[idx[b]] . item`` (KNOWN), the
-    summed cosine to its ``recent`` items (SIMILAR), both kept only where
-    positive, or the item's popularity (POPULAR). Returns ([B, num]
-    scores, [B, num] item rows); a slot with no candidate left has score
-    -inf. Chosen by :func:`_use_pallas` like the unmasked step; the fused
-    side takes catalogs of whole blocks (`CATALOG_ROW_MULTIPLE`)."""
-    if _quantized(factors) or _quantized(items):
-        # a pool's int8/bf16 tables: the rules step has no quantized
-        # kernel yet, so the tables are widened for the call
-        from predictionio_tpu.ops import quantize
-
-        if _quantized(factors):
-            factors = quantize.dequantize(factors)
-        if _quantized(items):
-            items = quantize.dequantize(items)
+    summed cosine to each of its ``recent`` items (SIMILAR), both kept only
+    where positive, or the item's popularity (POPULAR). ``factors`` is
+    None for a caller with no user table, none of whose rows is KNOWN.
+    Either table may be a pool's quantized one: the step has no quantized
+    kernel, so the program gathers a user's row as f32 and widens the
+    item table to f32 on the device first, every call (at 4.16 M x 16
+    int8: PERF.md section 5); the tenant still keeps the smaller table.
+    Returns ([B, num] scores, [B, num] item rows); a slot with no
+    candidate left has score -inf. Chosen by :func:`_use_pallas` like the
+    unmasked step; the fused side takes catalogs of whole blocks
+    (`CATALOG_ROW_MULTIPLE`)."""
     batch, rows = len(rules.per_query), items.shape[0]
     tracing.launch_call()
     return _rules_top_k(
@@ -499,56 +581,4 @@ def rules_top_k(
         and rows % CATALOG_ROW_MULTIPLE == 0
         and len(rules.list_cols) <= FUSED_LIST_CAPACITY,
         interpret=jax.default_backend() != "tpu",
-    )
-
-
-@partial(jax.jit, static_argnames=("num",))
-def _gather_mean_top_k_cosine_xla(
-    items_f: jax.Array,   # [I, k] staged
-    idx: jax.Array,       # [L] int32, -1 = padding
-    num: int,
-    mask: jax.Array | None = None,  # [I] True = exclude (phantom rows)
-) -> tuple[jax.Array, jax.Array]:
-    with jax.named_scope("gather"):
-        valid = idx >= 0
-        rows = jnp.take(items_f, jnp.clip(idx, 0, None), axis=0)
-        w = valid.astype(items_f.dtype)[:, None]
-        q = (rows * w).sum(axis=0, keepdims=True) / jnp.maximum(
-            w.sum(), 1.0
-        )
-    return _top_k_dot_xla(
-        l2_normalize(q), l2_normalize(items_f), num, mask
-    )
-
-
-def gather_mean_top_k_cosine(
-    items_f, idx, num: int, mask=None
-) -> tuple[jax.Array, jax.Array]:
-    """Similar-product query in one dispatch: mean of the (``-1``-padded)
-    gathered item rows → cosine against the whole catalog → top-``num``.
-    ``mask`` ([I] bool, True = exclude) drops rows from the ranking —
-    the phantom padding rows of a model-sharded catalog score -inf.
-    Returns ([1, num] scores, [1, num] indices)."""
-    if _quantized(items_f):
-        from predictionio_tpu.ops import quantize
-
-        idx = jnp.asarray(idx, jnp.int32)
-        valid = idx >= 0
-        rows = quantize.gather_rows(items_f, jnp.clip(idx, 0, None))
-        w = valid.astype(rows.dtype)[:, None]
-        q = (rows * w).sum(axis=0, keepdims=True) / jnp.maximum(
-            w.sum(), 1.0
-        )
-        return quantize.top_k_dot_quantized(
-            l2_normalize(q),
-            quantize.normalized(items_f),
-            min(num, items_f.shape[0]),
-            mask,
-        )
-    items_f = jnp.asarray(items_f)
-    return _gather_mean_top_k_cosine_xla(
-        items_f,
-        jnp.asarray(idx, jnp.int32),
-        min(num, items_f.shape[0]),
-        mask,
     )

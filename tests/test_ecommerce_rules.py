@@ -8,6 +8,7 @@ seen by the next query through `EngineServer`."""
 from __future__ import annotations
 
 import collections
+import dataclasses
 import datetime as _dt
 import json
 import os
@@ -78,7 +79,7 @@ def _dense_case(seed, n_users=50, n_items=3072, rank=16, batch=8, cats_per_item=
     rules = S.QueryRules.blank(batch, slots)
     rules.idx[:], rules.mode[:], rules.allow[:] = idx, mode, allow
     rules.recent[:], rules.categories[:] = recent, q_cats
-    rules = rules._replace(lists=S.pack_lists(lists))
+    rules = dataclasses.replace(rules, lists=S.pack_lists(lists))
     # the same, densely
     q = users[idx].astype(np.float64)
     for b in range(batch):

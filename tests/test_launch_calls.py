@@ -7,6 +7,7 @@ equal to the two-program composition it replaced, and compiled once."""
 from __future__ import annotations
 
 import contextvars
+import dataclasses
 import json
 import os
 
@@ -29,7 +30,9 @@ from predictionio_tpu.utils.bimap import BiMap
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 N_USERS, N_ITEMS, RANK, NUM = 90, 1024, 8, 8
-PATHS = ("f32", "int8", "bf16", "rules")
+#: "rules_int8": the rules step over a pool's quantized tables, which it
+#: widens inside its one program
+PATHS = ("f32", "int8", "bf16", "rules", "rules_int8")
 _JITTED = type(S._top_k_dot_xla)
 
 
@@ -38,9 +41,10 @@ def _tables(path: str, seed: int = 0, n_items: int = N_ITEMS):
     rng = np.random.default_rng(seed)
     users = rng.normal(size=(N_USERS, RANK)).astype(np.float32)
     items = rng.normal(size=(n_items, RANK)).astype(np.float32)
-    if path in Q.MODES:
+    kind = path.removeprefix("rules_")
+    if kind in Q.MODES:
         return tuple(
-            Q.stage_quantized(Q.quantize_factors(x, path)) for x in (users, items)
+            Q.stage_quantized(Q.quantize_factors(x, kind)) for x in (users, items)
         )
     return S.stage_factors(users), S.stage_factors(items)
 
@@ -62,7 +66,7 @@ def _query_rules(batch: int, seed: int) -> S.QueryRules:
     rules.idx[:] = rng.integers(0, N_USERS, batch)
     rules.mode[:] = rng.integers(0, 3, batch)
     rules.recent[:, :3] = rng.integers(0, N_ITEMS, (batch, 3))
-    return rules._replace(lists=S.pack_lists([
+    return dataclasses.replace(rules, lists=S.pack_lists([
         rng.choice(N_ITEMS, 20, replace=False).astype(np.int32)
         for _ in range(batch)
     ]))
@@ -72,10 +76,11 @@ def _launch(path: str):
     """``launch(seed)``: one launch of the path over a fresh numpy ``idx``
     (and fresh rules) of one shape, as `predict.prep` hands them over."""
     users, items = _tables(path)
-    catalog = _catalog_rules() if path == "rules" else None
+    rules = path.startswith("rules")
+    catalog = _catalog_rules() if rules else None
 
     def launch(seed: int, batch: int = 4):
-        if path == "rules":
+        if rules:
             return S.rules_top_k(
                 users, items, NUM, catalog, _query_rules(batch, seed)
             )
@@ -136,6 +141,7 @@ def test_a_launch_is_one_hand_over_to_the_runtime(path, monkeypatch):
         assert _launch_calls(registry) == batches
     wanted = {
         "f32": "_gather_top_k_dot_xla", "rules": "_rules_top_k",
+        "rules_int8": "_rules_top_k",
     }.get(path, "_top_k_dot_quant_xla")
     assert set(handed_over) == {wanted}
 
@@ -191,7 +197,9 @@ def test_query_rules_are_two_host_operands_filled_in_place():
     assert rules.mode.tolist() == [S.POPULAR] * 4
     assert (rules.recent == -1).all() and (rules.categories == S.NO_CATEGORY).all()
     rules.mode[1], rules.allow[2] = S.SIMILAR, True
-    rules = rules._replace(lists=S.pack_lists([np.array([7, 3], np.int32)] * 4))
+    rules = dataclasses.replace(
+        rules, lists=S.pack_lists([np.array([7, 3], np.int32)] * 4)
+    )
     leaves = jax.tree_util.tree_leaves(rules)
     assert [type(x) for x in leaves] == [np.ndarray, np.ndarray]
     assert [x.dtype for x in leaves] == [np.int32, np.int32]

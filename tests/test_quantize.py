@@ -170,13 +170,50 @@ class TestSimilarityAcceptsQuantized:
         _, i_q = similarity.top_k_cosine(q, qi, 10)
         assert quantize.recall_at_k(i_ref, i_q) >= 0.9
 
-    def test_gather_mean_cosine_quantized(self):
+    def test_similar_step_on_quantized_tables(self):
+        """The rules step's SIMILAR branch (the similar-product query:
+        summed cosine to a set of items) over an int8 table, widened
+        inside the step, ranks as it does over the f32 table."""
         items = _tables(400, 16, seed=16)
         qi = quantize.quantize_factors(items, "int8")
-        idx = np.array([3, 7, 12, -1], np.int32)
-        _, i_ref = similarity.gather_mean_top_k_cosine(items, idx, 10)
-        _, i_q = similarity.gather_mean_top_k_cosine(qi, idx, 10)
+        catalog = similarity.CatalogRules(
+            jnp.full((1, 400), -1, jnp.int32), jnp.zeros(400, bool),
+            similarity.inverse_norms(jnp.asarray(items)), None,
+        )
+        basket = np.array([3, 7, 12], np.int32)
+        rules = similarity.QueryRules.blank(1, 1, mode=similarity.SIMILAR)
+        rules.recent[0, :3] = basket
+        rules = dataclasses.replace(
+            rules, lists=similarity.pack_lists([basket])
+        )
+        _, i_ref = similarity.rules_top_k(None, items, 10, catalog, rules)
+        _, i_q = similarity.rules_top_k(None, qi, 10, catalog, rules)
+        assert not set(basket.tolist()) & set(np.asarray(i_q)[0].tolist())
         assert quantize.recall_at_k(i_ref, i_q) >= 0.9
+
+    @pytest.mark.parametrize("mode", quantize.MODES)
+    def test_known_row_of_the_rules_step_on_quantized_tables(self, mode):
+        """The rules step's KNOWN branch (the e-commerce query) gathers
+        its user's row from a quantized user table as f32 and ranks a
+        quantized item table, widened inside the step, as the f32 one."""
+        users, items = _tables(30, 16, seed=17), _tables(400, 16, seed=18)
+        catalog = similarity.CatalogRules(
+            jnp.full((1, 400), -1, jnp.int32), jnp.zeros(400, bool),
+            similarity.inverse_norms(jnp.asarray(items)),
+            jnp.zeros(400, jnp.float32),
+        )
+        rules = similarity.QueryRules.blank(2, 1, mode=similarity.KNOWN)
+        rules.idx[:] = [4, 9]
+        rules = dataclasses.replace(
+            rules, lists=similarity.pack_lists([np.array([1], np.int32)] * 2)
+        )
+        s_ref, i_ref = similarity.rules_top_k(users, items, 10, catalog, rules)
+        s_q, i_q = similarity.rules_top_k(
+            quantize.quantize_factors(users, mode),
+            quantize.quantize_factors(items, mode), 10, catalog, rules,
+        )
+        assert quantize.recall_at_k(i_ref, i_q) >= 0.8
+        np.testing.assert_allclose(s_q[:, 0], s_ref[:, 0], rtol=0.05)
 
 
 class TestModelHelpers:

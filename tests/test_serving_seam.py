@@ -180,3 +180,85 @@ def test_default_collect_blocks_on_device_outputs():
     assert handle is queries  # nothing launched, the queries handed on
     out = algo.batch_predict_collect(None, handle, queries)
     assert len(out) == 2 and all(p["scores"].is_ready() for p in out)
+
+
+def test_a_two_algorithm_engine_stages_two_batchers_a_tenant_in_a_pool(
+    ctx, memory_storage
+):
+    """The similar-product engine of ``examples/similarproduct`` (two ALS
+    algorithms) as a pool's tenant: two batchers, each wired to the
+    template's own launch and collect, the tenant charged the device
+    bytes of both models, and a post through the HTTP route crosses both
+    and is combined by the Serving."""
+    import json
+    import urllib.request
+
+    import predictionio_tpu.models  # noqa: F401 - templates self-register
+    from predictionio_tpu.core.controller import Algorithm
+    from predictionio_tpu.models.similarproduct import SimilarALSAlgorithm
+    from predictionio_tpu.ops import quantize
+
+    assert (
+        SimilarALSAlgorithm.batch_predict_launch
+        is not Algorithm.batch_predict_launch
+    )
+    tpl._seed(memory_storage, "simapp")
+    engine = engine_registry()["similarproduct"]()
+    params = tpl.TestSimilarProduct()._params(multi=True)
+    run_train(
+        engine, params, engine_id="seam-pool", ctx=ctx,
+        storage=memory_storage, engine_variant="shop-a",
+    )
+    _, algos, models, serving = load_deployment(
+        engine, params, engine_id="seam-pool", ctx=ctx,
+        storage=memory_storage, engine_variant="shop-a",
+    )
+    queries = [{"items": ["i0"], "num": 5}, {"items": ["i1", "i3"], "num": 3}]
+    expected = [
+        serving.serve(q, [a.predict(m, q) for a, m in zip(algos, models)])
+        for q in queries
+    ]
+    registry = MetricRegistry()
+    es = EngineServer(
+        engine, params, engine_id="seam-pool", storage=memory_storage,
+        ctx=ctx, registry=registry, tenants={"a": "shop-a"},
+    )
+    http = es.serve(host="127.0.0.1", port=0)
+    http.start()
+    try:
+        request = urllib.request.Request(
+            f"http://127.0.0.1:{http.port}/batch/queries.json?accessKey=a",
+            data=json.dumps(queries).encode(),
+            headers={"Content-Type": "application/json"},
+        )
+        with urllib.request.urlopen(request, timeout=60) as reply:
+            slots = json.loads(reply.read())
+        assert [s["status"] for s in slots] == [200, 200]
+        assert [s["prediction"] for s in slots] == expected
+        with es._pool.pin("a", es._tenant_loader("a")) as staged:
+            assert [b.name for b in staged.batchers] == [
+                "seam-pool/a/algo0", "seam-pool/a/algo1"
+            ]
+            assert staged.warmed
+            assert staged.nbytes == sum(
+                quantize.model_resident_bytes(m) for m in models
+            ) > 2 * models[0].item_factors.nbytes
+    finally:
+        http.shutdown()
+        es.close()
+    data = registry.to_dict()
+    batchers = {
+        s["labels"]["batcher"]: s["value"]
+        for s in data["pio_batches_total"]["samples"]
+    }
+    assert batchers == {"seam-pool/a/algo0": 1, "seam-pool/a/algo1": 1}
+    stages = {
+        s["labels"]["stage"]: s["count"]
+        for s in data["pio_stage_seconds"]["samples"]
+    }
+    # one launch and one collect an algorithm; the warm-up's are the
+    # loader thread's, on the process's registry
+    for stage in ("predict.prep", "predict.enqueue", "predict.device_get",
+                  "predict.materialize"):
+        assert stages[stage] == 2, stage
+    assert stages["engine.serve"] == 1

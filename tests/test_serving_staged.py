@@ -6,8 +6,10 @@ analogue: the deployed model stays resident in the server JVM
 
 from __future__ import annotations
 
+import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -157,7 +159,7 @@ class TestDeployStages:
 
 
 class TestFusedKernels:
-    """gather_top_k_dot / gather_mean_top_k_cosine vs reference math."""
+    """gather_top_k_dot / the rules step's SIMILAR branch vs reference math."""
 
     def test_gather_top_k_dot_matches_numpy(self):
         rng = np.random.default_rng(2)
@@ -175,20 +177,44 @@ class TestFusedKernels:
                 scores[b], want[b][order], rtol=1e-5
             )
 
-    def test_gather_mean_top_k_cosine_ignores_padding(self):
+    def test_similar_step_ignores_padding(self):
+        """The rules step's SIMILAR branch (the similar-product query)
+        sums over the item slots that are filled: the same basket in 16
+        slots and in 64, and in a batch padded by blank rows, scores the
+        same."""
         rng = np.random.default_rng(3)
         itf = rng.normal(size=(9, 4)).astype(np.float32)
-        idx_padded = np.array([2, 5, -1, -1], np.int32)
-        s_pad, c_pad = jax.device_get(
-            similarity.gather_mean_top_k_cosine(itf, idx_padded, 4)
+        catalog = similarity.CatalogRules(
+            jnp.full((1, 9), -1, jnp.int32), jnp.zeros(9, bool),
+            similarity.inverse_norms(jnp.asarray(itf)), None,
         )
-        s_exact, c_exact = jax.device_get(
-            similarity.gather_mean_top_k_cosine(
-                itf, np.array([2, 5], np.int32), 4
+
+        def step(batch, item_slots):
+            rules = similarity.QueryRules.blank(
+                batch, 1, item_slots=item_slots, mode=similarity.SIMILAR
             )
+            assert rules.recent.shape == (batch, item_slots)
+            rules.recent[0, :2] = [2, 5]
+            rules = dataclasses.replace(rules, lists=similarity.pack_lists(
+                [np.array([2, 5], np.int32)]
+            ))
+            return jax.device_get(similarity.rules_top_k(
+                None, itf, 4, catalog, rules
+            ))
+
+        s_exact, c_exact = step(1, similarity.RECENT_SLOTS)
+        s_pad, c_pad = step(4, 4 * similarity.RECENT_SLOTS)
+        np.testing.assert_array_equal(c_pad[0], c_exact[0])
+        np.testing.assert_allclose(s_pad[0], s_exact[0], rtol=1e-5)
+        assert not {2, 5} & set(c_exact[0][np.isfinite(s_exact[0])].tolist())
+        # the blank rows name no item and answer nothing
+        assert not np.isfinite(s_pad[1:]).any()
+        unit = itf / np.linalg.norm(itf, axis=1, keepdims=True)
+        want = (unit[[2, 5]].sum(0) * unit).sum(1)
+        kept = np.isfinite(s_exact[0])
+        np.testing.assert_allclose(
+            s_exact[0][kept], want[c_exact[0][kept]], rtol=1e-5
         )
-        np.testing.assert_array_equal(c_pad, c_exact)
-        np.testing.assert_allclose(s_pad, s_exact, rtol=1e-5)
 
     def test_ecommerce_and_similarproduct_stage(self, ctx):
         from predictionio_tpu.models.ecommerce import (
