@@ -223,12 +223,14 @@ class _HistogramChild:
         self.sum = 0.0
         self.count = 0
 
-    def observe(self, value: float) -> None:
+    def observe(self, value: float, n: int = 1) -> None:
+        """``n`` observations of ``value`` (a group's slots share one
+        wait): what ``n`` calls would leave, under one lock."""
         idx = bisect.bisect_left(self._bounds, value)
         with self._lock:
-            self.counts[idx] += 1
-            self.sum += value
-            self.count += 1
+            self.counts[idx] += n
+            self.sum += value * n
+            self.count += n
 
     def time(self):
         """``with histogram.time():`` — observe the block's wall clock."""

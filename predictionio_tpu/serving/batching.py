@@ -27,6 +27,17 @@ decode) on the completer, so the *enqueue* of batch N+1 overlaps the
 items on: it runs exactly once per batch, as the collect, with no
 extra device barriers added around it.
 
+A request's items enter, ride and leave as one **group**
+(:class:`BatchGroup`, ``submit_group``): what they share — deadline,
+criticality, tenant, request ID and span, arrival instant — is read
+once, the queue's condition taken once, the collector notified once;
+the completer stores a batch's outcomes into the groups they belong to
+and wakes each group's one waiter when its last slot is in. Per slot
+there is a pair in the queue going in and an outcome stored coming
+out: no Future, lock or labelled metric. ``submit(item) -> Future`` is
+the group of one behind a Future, on the same admission and the same
+settle; every per-slot guarantee below holds slot by slot of a group.
+
 Overload discipline (docs/robustness.md "Overload & backpressure"):
 the wait queue is criticality- and deadline-aware. When backlog
 exceeds one batch, the most-urgent slots (nearest ``X-PIO-Deadline``)
@@ -45,21 +56,24 @@ the batcher records batch occupancy, queue depth, device-dispatch time
 ``pio_device_dispatch_seconds``), dispatched/shed/cancelled counts —
 the queue instrumentation the Podracer line of work treats as a
 prerequisite for scaling; built without a registry it counts into a
-private one. Each slot carries the submitting request's
+private one. Each group carries the submitting request's
 ID (from the obs contextvar), so a slow or failing dispatch logs
 exactly which requests rode in it.
 """
 
 from __future__ import annotations
 
+import heapq
 import itertools
 import logging
 import math
+import operator
 import os
 import queue
 import threading
 import time
-from concurrent.futures import Future
+from concurrent.futures import CancelledError, Future
+from concurrent.futures import TimeoutError as FuturesTimeout
 from typing import Any, Callable, NamedTuple, Sequence
 
 from predictionio_tpu.obs import MetricRegistry, get_request_id
@@ -128,27 +142,221 @@ class TwoPhaseBatchFn:
         self.collect = collect
 
 
-class _Slot(NamedTuple):
-    """One queued submission: the payload, its Future, the submitting
-    request's identity (ID + open span + submit time) for dispatch logs
-    and trace spans, its deadline so expired work is dropped before
-    the device sees it, and its criticality class so overload evicts
-    the least-critical queued work first."""
+class _State:
+    """A slot's entry in its group's ``outcomes`` until an answer, or
+    the exception that refused it, takes its place."""
 
-    item: Any
-    future: Future
-    request_id: str | None
-    parent_span: Any  # tracing.Span | None
-    submitted_mono: float
-    deadline: Any  # resilience.Deadline | None
-    criticality: str = admission.DEFAULT
-    tenant: str = ""
+    __slots__ = ("_name",)
+
+    def __init__(self, name: str):
+        self._name = name
+
+    def __repr__(self) -> str:
+        return self._name
+
+
+_QUEUED = _State("<queued>")
+_RUNNING = _State("<running>")
+
+#: a queued slot is the pair (its group, its index in the group): what
+#: the collector's rules read of a slot (deadline, criticality, arrival
+#: order) they read of its group, and the buffer keeps arrival order
+_group_of = operator.itemgetter(0)
+
+
+class _Group:
+    """One admission to a :class:`MicroBatcher`, as the batcher sees it:
+    the slots of one ``submit_group`` (:class:`BatchGroup`), or the one
+    slot of a ``submit`` (:class:`_SlotFuture`).
+
+    The slots share what their request shares, read here once: one
+    deadline, one criticality, one tenant, one request ID and span (so
+    dispatch logs can name the requests in a slow or failed batch, and
+    the dispatch span can link back to every request it coalesced; with
+    tracing off that costs the ``current_span()`` read), and one arrival
+    instant. What stays per slot is its place in ``outcomes``: the
+    answer, or the exception that refused it (shed at the bound,
+    evicted, expired, cancelled, its batch failed). A group may ride
+    more than one batch; it is done, and ``_finish`` called once, when
+    its last slot has an outcome.
+
+    ``admitted`` slots entered the queue: the slots from ``admitted``
+    on were shed at the bound and hold :class:`BatcherOverloaded`.
+    """
+
+    __slots__ = (
+        "items", "outcomes", "admitted", "deadline", "criticality",
+        "tenant", "request_id", "parent_span", "submitted_mono",
+        "_pending", "_lock",
+    )
+
+    def __init__(self, items: Sequence[Any]):
+        self.items = items
+        self.outcomes: list = [_QUEUED] * len(items)
+        self.admitted = 0
+        self.deadline = resilience.get_deadline()
+        self.criticality = admission.get_criticality()
+        self.tenant = admission.get_tenant()
+        self.request_id = get_request_id()
+        self.parent_span = tracing.current_span()
+        self.submitted_mono = 0.0
+        #: admitted slots with no outcome yet (``_lock`` held to change):
+        #: whoever brings it to 0 calls ``_finish``, so that happens once
+        self._pending = 0
+        #: orders a slot's leaving the queue (the cut-off at dispatch
+        #: entry, an eviction, a cancel) and the count-down: taken once
+        #: a group and batch, never once a slot
+        self._lock = threading.Lock()
+
+    def _finish(self) -> None:
+        """Every slot has its outcome: wake whoever waits. Called once,
+        under no lock."""
+        raise NotImplementedError
+
+    def _drop(self, indices, exc: Exception) -> int:
+        """The slots at ``indices`` that still wait leave the queue
+        unserved, with ``exc`` for outcome (cancelled, evicted);
+        returns how many of the others are running."""
+        outcomes = self.outcomes
+        dropped = running = 0
+        with self._lock:
+            for i in indices:
+                state = outcomes[i]
+                if state is _QUEUED:
+                    outcomes[i] = exc
+                    dropped += 1
+                elif state is _RUNNING:
+                    running += 1
+            self._pending -= dropped
+            finished = dropped and not self._pending
+        if finished:
+            self._finish()
+        return running
+
+    def _start(self, indices: list) -> tuple[list, int, int]:
+        """The cut-off at dispatch entry for the group's slots of one
+        batch: ``(live, cancelled, expired)``. Cancelled slots drop out;
+        past its deadline the group's queued slots are refused here
+        (their waiter is gone or about to time out); the live ones are
+        the device's from now on and no cancel reaches them."""
+        outcomes = self.outcomes
+        expired = 0
+        with self._lock:
+            live = [i for i in indices if outcomes[i] is _QUEUED]
+            cancelled = len(indices) - len(live)
+            finished = False
+            if live and self.deadline is not None and self.deadline.expired:
+                exc = resilience.DeadlineExceeded(
+                    "deadline expired while queued for dispatch"
+                )
+                for i in live:
+                    outcomes[i] = exc
+                self._pending -= len(live)
+                finished = not self._pending
+                expired, live = len(live), []
+            for i in live:
+                outcomes[i] = _RUNNING
+        if finished:
+            self._finish()
+        return live, cancelled, expired
+
+    def _store(self, indices: list, values: Sequence[Any]) -> None:
+        """A batch's outcomes for the group's slots that rode it. They
+        are running, so the batcher owns them: no lock until the
+        count-down."""
+        first, n = indices[0], len(indices)
+        if indices[-1] - first == n - 1:  # increasing, so: contiguous
+            self.outcomes[first:first + n] = values
+        else:
+            outcomes = self.outcomes
+            for i, value in zip(indices, values):
+                outcomes[i] = value
+        with self._lock:
+            self._pending -= n
+            finished = not self._pending
+        if finished:
+            self._finish()
+
+
+class BatchGroup(_Group):
+    """What ``submit_group`` returns: a request's slots, waited for
+    once (``wait``) and read in the items' order (``result(i)``)."""
+
+    __slots__ = ("_done",)
+
+    def __init__(self, items: Sequence[Any]):
+        super().__init__(items)
+        #: a latch: held from birth, released once by whoever stores
+        #: the last outcome, so the one waiter wakes on a single lock
+        #: hand-over (an Event would wake it to queue for the Event's
+        #: own lock next)
+        self._done = threading.Lock()
+        self._done.acquire()
+
+    def _finish(self) -> None:
+        self._done.release()
+
+    def wait(self, timeout: float | None = None) -> bool:
+        """Block until every slot has its outcome; False on time-out."""
+        if not self._done.acquire(
+            timeout=-1 if timeout is None else max(0.0, timeout)
+        ):
+            return False
+        self._done.release()
+        return True
+
+    def result(self, index: int) -> Any:
+        """Slot ``index``'s answer; raises the exception it was refused
+        or failed with, and ``TimeoutError`` while it has neither."""
+        outcome = self.outcomes[index]
+        if outcome is _QUEUED or outcome is _RUNNING:
+            raise FuturesTimeout()
+        if isinstance(outcome, BaseException):
+            raise outcome
+        return outcome
+
+    def cancel(self, indices: Sequence[int] | None = None) -> int:
+        """Abandon the group's slots, or those at ``indices``: one
+        still queued is dropped (the device never sees it; the
+        collector counts it in ``pio_batch_cancelled_total`` when it
+        reaches it) and holds ``CancelledError``; one with an outcome
+        keeps it. Returns how many are already past the cut-off with no
+        answer yet: device work under way that nobody will read."""
+        return self._drop(
+            range(self.admitted) if indices is None else indices,
+            CancelledError(),
+        )
+
+
+class _SlotFuture(_Group, Future):
+    """What ``submit`` returns: the group of one, as a ``Future``.
+    ``cancel`` drops the slot while it still waits, and the slot's
+    outcome resolves the future."""
+
+    def __init__(self, item: Any):
+        Future.__init__(self)
+        _Group.__init__(self, (item,))
+
+    def cancel(self) -> bool:
+        self._drop((0,), CancelledError())
+        return self.cancelled()
+
+    def _finish(self) -> None:
+        outcome = self.outcomes[0]
+        if isinstance(outcome, CancelledError):
+            Future.cancel(self)
+            self.set_running_or_notify_cancel()
+        elif isinstance(outcome, BaseException):
+            self.set_exception(outcome)
+        else:
+            self.set_result(outcome)
 
 
 class _Inflight(NamedTuple):
     """One enqueued batch riding the collector→completer handoff."""
 
-    live: list  # [_Slot]
+    runs: list  # [(group, [index])]: the live slots, in batch order
+    n: int  # how many they are
     handle: Any
     start_wall: float
     start_mono: float
@@ -253,9 +461,10 @@ class _BatcherMetrics:
 
     __slots__ = ("_depth", "_shed", "_shed_class", "_name", "_occupancy",
                  "_dispatch", "_enqueue", "_sync", "_batches",
-                 "_windows_waited",
+                 "_groups", "_group_slots", "_windows_waited",
                  "_cancelled", "_expired", "_leaked",
                  "_tenant_device", "_tenant_wait", "_tenant_requests",
+                 "_tenant_children",
                  "_attr_lock", "_noisy")
 
     def __init__(self, registry: MetricRegistry, name: str):
@@ -310,6 +519,20 @@ class _BatcherMetrics:
             "Device batches dispatched",
             ("batcher",),
         ).labels(name)
+        self._groups = registry.counter(
+            "pio_batch_groups_total",
+            "Groups admitted by submit_group (a post's queries in one "
+            "admission)",
+            ("batcher",),
+        ).labels(name)
+        self._group_slots = registry.counter(
+            "pio_batch_group_slots_total",
+            "Slots that entered the queue in groups (over "
+            "pio_batch_groups_total: slots a group; over "
+            "pio_batch_occupancy's sum: the share of dispatched "
+            "queries that arrived in groups)",
+            ("batcher",),
+        ).labels(name)
         self._windows_waited = registry.counter(
             "pio_batch_windows_waited_total",
             "Coalescing windows in which the collector slept for "
@@ -355,6 +578,10 @@ class _BatcherMetrics:
             "Batch slots settled per tenant, by outcome",
             ("tenant", "status"),
         )
+        #: a tenant's three children by (tenant, status): resolved at
+        #: its first settle, then hit directly (a batch of a lone query
+        #: would pay three labels() lookups for one slot)
+        self._tenant_children: dict = {}
         self._attr_lock = threading.Lock()
         self._noisy = _NoisyRollup(
             registry.gauge(
@@ -369,9 +596,13 @@ class _BatcherMetrics:
     def queue_depth(self, n: int) -> None:
         self._depth.set(n)
 
-    def shed(self, criticality: str) -> None:
-        self._shed.inc()
-        self._shed_class.labels(self._name, criticality).inc()
+    def shed(self, criticality: str, n: int = 1) -> None:
+        self._shed.inc(n)
+        self._shed_class.labels(self._name, criticality).inc(n)
+
+    def grouped(self, slots: int) -> None:
+        self._groups.inc()
+        self._group_slots.inc(slots)
 
     def window_waited(self) -> None:
         self._windows_waited.inc()
@@ -397,25 +628,42 @@ class _BatcherMetrics:
         self._leaked.inc()
 
     def attributed(
-        self, tenant: str, device_s: float, wait_s: float, status: str
+        self, tenant: str, n: int, device_s: float, wait_s: float,
+        status: str,
     ) -> None:
-        """One slot's share of a settled batch. Conservation contract:
-        the settle paths call this for EVERY live slot with exactly
-        ``(enqueue_s + sync_s) / len(live)``, success and failure
+        """The share of a settled batch of one group's ``n`` slots that
+        rode it: ``device_s`` a slot, after one ``wait_s`` (they arrived
+        together). Conservation contract: the settle paths call this
+        for EVERY live slot of the batch with exactly
+        ``(enqueue_s + sync_s) / live`` a slot, success and failure
         alike, so the per-tenant sum equals the batcher's measured
-        device time (asserted in tests and scripts/metrics_smoke.py)."""
-        self._tenant_device.labels(tenant).inc(device_s)
-        self._tenant_wait.labels(tenant).observe(wait_s)
-        self._tenant_requests.labels(tenant, status).inc()
+        device time, and the wait histogram gets one observation a
+        slot: a mean over queries (asserted in tests and
+        scripts/metrics_smoke.py)."""
+        children = self._tenant_children.get((tenant, status))
+        if children is None:
+            children = self._tenant_children[tenant, status] = (
+                self._tenant_device.labels(tenant),
+                self._tenant_wait.labels(tenant),
+                self._tenant_requests.labels(tenant, status),
+            )
+        device, wait, requests = children
+        device.inc(n * device_s)
+        wait.observe(wait_s, n)
+        requests.inc(n)
         # settlement runs on the completer AND the collector (a failed
         # dispatch); the rollup's read-modify-write needs its own tiny
         # guard
         with self._attr_lock:
-            self._noisy.observe(tenant, device_s, wait_s)
+            self._noisy.observe(tenant, n * device_s, wait_s)
 
 
 class MicroBatcher:
-    """Coalesce submit()-ed items into batches for ``batch_fn``.
+    """Coalesce submitted items (``submit``: one, behind a Future;
+    ``submit_group``: a request's, as one :class:`BatchGroup`) into
+    batches for ``batch_fn``. A group longer than ``max_batch``, or one
+    that meets a part-filled buffer, rides two batches and completes
+    when its last slot settles, answers in the items' order.
 
     A batch is dispatched when ``max_batch`` items are waiting or the
     coalescing wait elapsed since the first queued item — the classic
@@ -425,7 +673,8 @@ class MicroBatcher:
     the gaps between consecutive arrivals (weight ``_GAP_WEIGHT``, each
     gap counted as at most ``_GAP_CAP_WINDOWS`` windows), and the
     collector skips the window when that mean is longer than
-    ``max_wait_ms`` — waiting only adds latency when nothing will join.
+    ``max_wait_ms`` — waiting only adds latency when nothing will join
+    (a group of n is n arrivals, n-1 of them at gap 0).
     Otherwise, and always with no history (fewer than two arrivals) or
     with ``adaptive_wait`` off, it waits up to ``max_wait_ms``. Under
     load the coalescing comes from backlog: what arrives while the
@@ -438,11 +687,11 @@ class MicroBatcher:
     and collected results (default 2 = double buffering; at least 1:
     dispatch and collect always run on two threads).
 
-    Returned futures support ``cancel()`` up to the moment their batch
-    is dispatched: a cancelled slot is dropped from the batch (its
-    device work never happens) and counted in
+    Returned futures and groups support ``cancel()`` up to the moment
+    their batch is dispatched: a cancelled slot is dropped from the
+    batch (its device work never happens) and counted in
     ``pio_batch_cancelled_total``. Callers that abandon accepted
-    futures (e.g. a partially-overloaded multi-algorithm batch slot)
+    slots (e.g. a partially-overloaded multi-algorithm batch slot)
     should cancel them rather than leak the dispatch.
 
     Overload semantics: the wait queue is not strictly FIFO. When the
@@ -452,8 +701,10 @@ class MicroBatcher:
     deadline-less slots. At the ``max_queue`` bound, a submission of a
     strictly higher criticality class (``X-PIO-Criticality``, read
     from the admission contextvar) evicts the lowest-class queued slot
-    — the evicted future fails with :class:`BatcherOverloaded` and the
-    shed is accounted per class in ``pio_shed_total{batcher,class}``.
+    — the evicted slot fails with :class:`BatcherOverloaded` and the
+    shed is accounted per class in ``pio_shed_total{batcher,class}``;
+    a group at the bound is admitted as far as it fits (and evicts),
+    and its other slots are shed.
     """
 
     def __init__(
@@ -504,7 +755,7 @@ class MicroBatcher:
         #: collector selects under the same lock. One lock, never held
         #: across dispatch or any blocking wait (Condition.wait excepted)
         self._cv = threading.Condition()
-        self._buf: list[_Slot] = []
+        self._buf: list[tuple[_Group, int]] = []
         self._closed = threading.Event()
         #: EWMA of end-to-end batch seconds — feeds retry_after_s().
         #: Guarded by the cv: the settle path runs on BOTH worker
@@ -522,104 +773,125 @@ class MicroBatcher:
         self._thread.start()
 
     def submit(self, item: Any) -> Future:
+        """One item, answered through a ``Future``: a group of one.
+        Raises what refused it at admission (closed, deadline expired,
+        shed at the bound)."""
+        future = _SlotFuture(item)
+        self._admit(future)
+        if not future.admitted:
+            raise future.outcomes[0]
+        return future
+
+    def submit_group(self, items: Sequence[Any]) -> BatchGroup:
+        """A request's items in one admission, answered through the
+        :class:`BatchGroup`: ``wait`` once, then ``result(i)`` in the
+        items' order. Raises where no slot can be admitted for a reason
+        the whole request shares (closed, deadline expired); at the
+        queue bound the slots that fit are admitted and the rest hold
+        :class:`BatcherOverloaded` (``group.admitted`` says where they
+        start)."""
+        group = BatchGroup(items)
+        self._admit(group)
+        self._metrics.grouped(group.admitted)
+        return group
+
+    def _admit(self, group: _Group) -> None:
+        """The one admission: the condition taken once, the closed
+        check, the deadline check and the queue bound applied once over
+        the group's slots, the collector notified once."""
         # a request whose budget already ran out must not take a
         # queue slot at all — the 504 costs nothing here but would
         # cost a dispatch slot at flush time. Checked BEFORE the
         # overload bound: doomed work must never trigger an eviction.
-        deadline = resilience.get_deadline()
-        criticality = admission.get_criticality()
-        tenant = admission.get_tenant()
-        victim: _Slot | None = None
-        # the cv orders submit against close(): once closed is set under
-        # it, no new slot can slip into the buffer behind the drain
+        deadline = group.deadline
+        criticality = group.criticality
+        n = len(group.items)
+        fit, victims = n, ()
+        # the cv orders admission against close(): once closed is set
+        # under it, no new slot can slip into the buffer behind the drain
         with self._cv:
             if self._closed.is_set():
                 raise RuntimeError("batcher is closed")
             if deadline is not None and deadline.expired:
-                self._metrics.expired(1)
+                self._metrics.expired(n)
                 raise resilience.DeadlineExceeded(
                     "deadline expired before batch submit"
                 )
-            if (
-                self._max_queue > 0
-                and len(self._buf) >= self._max_queue
-            ):
-                victim = self._pick_victim(criticality)
-                if victim is None:
-                    self._metrics.shed(criticality)
-                    raise BatcherOverloaded(
-                        f"batch queue at capacity ({self._max_queue})"
-                    )
-                self._buf.remove(victim)
-                self._metrics.shed(victim.criticality)
-            future: Future = Future()
-            # the submitting request's ID and span ride the slot so
-            # dispatch logs can name the requests in a slow/failed
-            # batch, and the dispatch span can link back to every query
-            # it coalesced. With tracing off the extra cost is exactly
-            # the current_span() contextvar read (parent is None).
-            parent_span = tracing.current_span()
-            # submit time is stamped unconditionally (not just under a
-            # trace): per-tenant queue-wait attribution needs it for
-            # every slot, and the window rule the gap to the arrival
-            # before it
-            now = time.monotonic()
-            if self._last_arrival is not None:
-                gap = min(now - self._last_arrival, self._gap_cap)
-                self._gap_ewma += _GAP_WEIGHT * (gap - self._gap_ewma)
-            self._last_arrival = now
-            self._buf.append(
-                _Slot(
-                    item,
-                    future,
-                    get_request_id(),
-                    parent_span,
-                    now,
-                    deadline,
-                    criticality,
-                    tenant,
-                )
+            if self._max_queue > 0:
+                room = max(0, self._max_queue - len(self._buf))
+                if room < n:
+                    victims = self._pick_victims(criticality, n - room)
+                    fit = room + len(victims)
+                    if victims:
+                        evicted = set(victims)
+                        self._buf = [
+                            s for s in self._buf if s not in evicted
+                        ]
+            if fit:
+                # the arrival instant is stamped unconditionally (not
+                # just under a trace): per-tenant queue-wait attribution
+                # needs it, and the window rule the gap to the arrival
+                # before it. A group is ``fit`` arrivals, all but the
+                # first at gap 0: the folds of those, in closed form
+                now = time.monotonic()
+                if self._last_arrival is not None:
+                    gap = min(now - self._last_arrival, self._gap_cap)
+                    self._gap_ewma += _GAP_WEIGHT * (gap - self._gap_ewma)
+                    self._gap_ewma *= (1.0 - _GAP_WEIGHT) ** (fit - 1)
+                self._last_arrival = now
+                group.submitted_mono = now
+                # before the slots can be seen: a batch may settle them
+                # before this thread runs again
+                group.admitted = group._pending = fit
+                self._buf += [(group, i) for i in range(fit)]
+                self._metrics.queue_depth(len(self._buf))
+                self._cv.notify()
+        # outcomes are stored OUTSIDE the lock: a done-callback runs
+        # inline and must not execute under the batcher's condition
+        for victim, index in victims:
+            self._metrics.shed(victim.criticality)
+            victim._drop(
+                (index,),
+                BatcherOverloaded(
+                    "shed: evicted by a higher-criticality submission "
+                    "under overload"
+                ),
             )
-            self._metrics.queue_depth(len(self._buf))
-            self._cv.notify()
-        if victim is not None:
-            # settle the evicted waiter OUTSIDE the lock: its
-            # done-callbacks run inline and must not execute under the
-            # batcher's condition
-            if victim.future.set_running_or_notify_cancel():
-                victim.future.set_exception(
-                    BatcherOverloaded(
-                        "shed: evicted by a higher-criticality "
-                        "submission under overload"
-                    )
+        if fit < n:
+            self._metrics.shed(criticality, n - fit)
+            group.outcomes[fit:] = [
+                BatcherOverloaded(
+                    f"batch queue at capacity ({self._max_queue})"
                 )
-        return future
+            ] * (n - fit)
+        if not fit:
+            group._finish()  # nothing of it waits: done at once
 
-    def _pick_victim(self, criticality: str) -> "_Slot | None":
-        """cv held. The queued slot a full buffer sheds to admit a
-        ``criticality``-class submission: strictly lower class only
-        (equal class waits its turn — no churn), lowest class first,
-        then the nearest deadline (the slot most likely to die unserved
-        anyway loses the least goodput), then the latest arrival."""
+    def _pick_victims(self, criticality: str, wanted: int) -> list:
+        """cv held. The queued slots, at most ``wanted``, a full buffer
+        sheds to admit a ``criticality``-class submission: strictly
+        lower class only (equal class waits its turn — no churn),
+        lowest class first, then the nearest deadline (the slot most
+        likely to die unserved anyway loses the least goodput), then
+        the latest arrival."""
         incoming = admission.CLASS_RANK.get(
             criticality, admission.CLASS_RANK[admission.DEFAULT]
         )
-        victim = None
-        victim_key = None
+        candidates = []
         for i, slot in enumerate(self._buf):
-            rank = admission.CLASS_RANK.get(slot.criticality, 1)
-            if rank >= incoming or slot.future.cancelled():
-                continue
-            key = (
+            group, index = slot
+            rank = admission.CLASS_RANK.get(group.criticality, 1)
+            if rank >= incoming or group.outcomes[index] is not _QUEUED:
+                continue  # no lower class, or cancelled
+            candidates.append((
                 rank,
-                slot.deadline.expires_mono
-                if slot.deadline is not None
+                group.deadline.expires_mono
+                if group.deadline is not None
                 else math.inf,
                 -i,
-            )
-            if victim_key is None or key < victim_key:
-                victim, victim_key = slot, key
-        return victim
+                slot,
+            ))
+        return [c[3] for c in heapq.nsmallest(wanted, candidates)]
 
     def __call__(self, item: Any, timeout: float | None = 30.0) -> Any:
         # the waiter must never outlive the budget it was admitted
@@ -693,8 +965,8 @@ class MicroBatcher:
             order = sorted(
                 range(len(buf)),
                 key=lambda i: (
-                    buf[i].deadline.expires_mono
-                    if buf[i].deadline is not None
+                    buf[i][0].deadline.expires_mono
+                    if buf[i][0].deadline is not None
                     else math.inf,
                     i,
                 ),
@@ -756,46 +1028,44 @@ class MicroBatcher:
         # device sees the work
         with tracing.stage(tracing.BATCH_BACKPRESSURE):
             self._inflight.acquire()
-        # transition every slot to running; cancelled slots drop out
-        # HERE, before the device sees them — cancellation is how an
-        # abandoning caller turns wasted dispatch into avoided dispatch.
-        # Expired-deadline slots drop out the same way (the deadline
-        # re-check at dispatch entry): their waiter is already gone (or
-        # about to time out), so dispatching them would burn device
-        # time computing unreceivable answers.
-        live = []
-        expired = 0
-        for slot in batch:
-            if not slot.future.set_running_or_notify_cancel():
-                continue
-            if slot.deadline is not None and slot.deadline.expired:
-                slot.future.set_exception(
-                    resilience.DeadlineExceeded(
-                        "deadline expired while queued for dispatch"
-                    )
-                )
-                expired += 1
-                continue
-            live.append(slot)
-        if dropped := len(batch) - len(live) - expired:
-            self._metrics.cancelled(dropped)
+        # the cut-off, group by group (a group's slots lie together in
+        # arrival order): cancelled slots drop out HERE, before the
+        # device sees them — cancellation is how an abandoning caller
+        # turns wasted dispatch into avoided dispatch. Expired-deadline
+        # slots drop out the same way (the deadline re-check at dispatch
+        # entry): their waiter is already gone (or about to time out),
+        # so dispatching them would burn device time computing
+        # unreceivable answers.
+        runs = []
+        items = []
+        cancelled = expired = 0
+        # dispatch-span bookkeeping only when at least one group was
+        # submitted under an open trace — untraced traffic pays nothing
+        traced = False
+        for group, slots in itertools.groupby(batch, _group_of):
+            live, dropped, late = group._start([i for _, i in slots])
+            cancelled += dropped
+            expired += late
+            if live:
+                runs.append((group, live))
+                items += [group.items[i] for i in live]
+                traced = traced or group.parent_span is not None
+        if cancelled:
+            self._metrics.cancelled(cancelled)
         if expired:
             self._metrics.expired(expired)
             log_json(
                 logger, logging.DEBUG, "batch_slots_expired",
                 batcher=self.name, expired=expired,
             )
-        if not live:
+        if not runs:
             self._inflight.release()
             return
-        # dispatch-span bookkeeping only when at least one slot was
-        # submitted under an open trace — untraced traffic pays nothing
-        traced = any(slot.parent_span is not None for slot in live)
+        n = len(items)
         start_wall = tracing.now() if traced else 0.0
         # dispatch-start is stamped unconditionally: queue-wait
         # attribution (submit -> dispatch) covers untraced traffic too
         start_mono = time.monotonic()
-        items = [slot.item for slot in live]
         t0 = time.perf_counter()
         try:
             handle = self._dispatch_fn(items)
@@ -804,7 +1074,7 @@ class MicroBatcher:
             enqueue_s = time.perf_counter() - t0
             self._metrics.enqueued(enqueue_s)
             self._settle(
-                live, e, time.perf_counter() - t0,
+                runs, n, e, time.perf_counter() - t0,
                 start_wall, start_mono, traced,
                 enqueue_s=enqueue_s, sync_s=0.0, phase="dispatch",
             )
@@ -813,7 +1083,7 @@ class MicroBatcher:
         self._metrics.enqueued(enqueue_s)
         self._pending.put(
             _Inflight(
-                live, handle, start_wall, start_mono, t0, enqueue_s,
+                runs, n, handle, start_wall, start_mono, t0, enqueue_s,
                 traced, seq,
             )
         )
@@ -824,7 +1094,7 @@ class MicroBatcher:
             rec = self._pending.get()
             if rec is None:
                 return
-            self._stages.bind(batch=rec.seq, n=len(rec.live))
+            self._stages.bind(batch=rec.seq, n=rec.n)
             try:
                 t1 = time.perf_counter()
                 sync_s = 0.0
@@ -838,15 +1108,15 @@ class MicroBatcher:
                     finally:
                         sync_s = time.perf_counter() - t1
                         self._metrics.synced(sync_s)
-                    if len(outcome) != len(rec.live):
+                    if len(outcome) != rec.n:
                         raise RuntimeError(
                             f"batch_fn returned {len(outcome)} results "
-                            f"for {len(rec.live)} items"
+                            f"for {rec.n} items"
                         )
                 except Exception as e:  # noqa: BLE001 - to every waiter
                     outcome = e
                 self._settle(
-                    rec.live, outcome, time.perf_counter() - rec.t0,
+                    rec.runs, rec.n, outcome, time.perf_counter() - rec.t0,
                     rec.start_wall, rec.start_mono, rec.traced,
                     enqueue_s=rec.enqueue_s, sync_s=sync_s,
                     phase="collect",
@@ -868,66 +1138,78 @@ class MicroBatcher:
             )
 
     def _attribute(
-        self, live, start_mono: float, enqueue_s: float, sync_s: float,
-        status: str,
+        self, runs, n: int, start_mono: float, enqueue_s: float,
+        sync_s: float, status: str,
     ) -> None:
         """Apportion the batch's measured device time across its slots
         by slot count — every live slot, on success AND failure paths,
-        so per-tenant sums conserve the batcher's total device time."""
-        share = (enqueue_s + sync_s) / len(live)
-        for slot in live:
+        so per-tenant sums conserve the batcher's total device time.
+        A group's slots of the batch are charged in one step."""
+        share = (enqueue_s + sync_s) / n
+        for group, live in runs:
             self._metrics.attributed(
-                slot.tenant,
+                group.tenant,
+                len(live),
                 share,
-                max(0.0, start_mono - slot.submitted_mono),
+                max(0.0, start_mono - group.submitted_mono),
                 status,
             )
 
     def _settle(
-        self, live, outcome, elapsed: float, start_wall: float,
+        self, runs, n: int, outcome, elapsed: float, start_wall: float,
         start_mono: float, traced: bool, enqueue_s: float, sync_s: float,
         phase: str,
     ) -> None:
-        """Resolve a batch's futures: ``outcome`` is its results, one a
-        slot, or the exception every waiter gets (raised in ``phase``)."""
+        """Store a batch's outcomes into the groups its ``n`` slots
+        belong to: ``outcome`` is its results, one a slot, or the
+        exception every slot gets (raised in ``phase``). A group whose
+        last slot this was wakes its waiter, once."""
         failed = isinstance(outcome, Exception)
         error = f"{type(outcome).__name__}: {outcome}" if failed else None
         with tracing.stage(tracing.BATCH_SETTLE):
             self._observe_batch_time(elapsed)
-            self._metrics.dispatched(len(live), elapsed)
+            self._metrics.dispatched(n, elapsed)
             self._attribute(
-                live, start_mono, enqueue_s, sync_s,
+                runs, n, start_mono, enqueue_s, sync_s,
                 "error" if failed else "ok",
             )
             if traced:
                 self._record_dispatch_spans(
-                    live, start_wall, start_mono, elapsed,
+                    runs, n, start_wall, start_mono, elapsed,
                     enqueue_s=enqueue_s, sync_s=sync_s, error=error,
                 )
-            request_ids = [s.request_id for s in live if s.request_id]
             if failed:
                 log_json(
                     logger, logging.WARNING, "batch_dispatch_failed",
-                    batcher=self.name, occupancy=len(live), phase=phase,
+                    batcher=self.name, occupancy=n, phase=phase,
                     ms=round(elapsed * 1000, 3), error=error,
-                    requestIds=request_ids,
+                    requestIds=self._request_ids(runs),
                 )
-                for slot in live:
-                    if not slot.future.done():
-                        slot.future.set_exception(outcome)
-                return
-            log_json(
-                logger, logging.DEBUG, "batch_dispatch",
-                batcher=self.name, occupancy=len(live),
-                ms=round(elapsed * 1000, 3),
-                enqueueMs=round(enqueue_s * 1000, 3),
-                requestIds=request_ids,
-            )
-            for slot, result in zip(live, outcome):
-                slot.future.set_result(result)
+            elif logger.isEnabledFor(logging.DEBUG):
+                # asked first: the line's fields cost a lone query's
+                # batch as much as storing its answer
+                log_json(
+                    logger, logging.DEBUG, "batch_dispatch",
+                    batcher=self.name, occupancy=n,
+                    ms=round(elapsed * 1000, 3),
+                    enqueueMs=round(enqueue_s * 1000, 3),
+                    requestIds=self._request_ids(runs),
+                )
+            at = 0
+            for group, live in runs:
+                end = at + len(live)
+                group._store(
+                    live,
+                    [outcome] * len(live) if failed else outcome[at:end],
+                )
+                at = end
+
+    @staticmethod
+    def _request_ids(runs) -> list:
+        return [g.request_id for g, _ in runs if g.request_id]
 
     def _record_dispatch_spans(
-        self, live, start_wall: float, start_mono: float,
+        self, runs, n: int, start_wall: float, start_mono: float,
         elapsed: float, enqueue_s: float = 0.0, sync_s: float = 0.0,
         error: str | None = None,
     ) -> None:
@@ -938,14 +1220,14 @@ class MicroBatcher:
         one child ``batch_dispatch`` span copy carrying the shared
         timing plus its queue wait, with ``links`` naming every
         coalesced query span — the cross-request join Perfetto can't
-        infer. Distinct matters: a batch-queries request submits many
-        slots under one span, and per-slot copies would overflow the
-        per-trace span cap with duplicates."""
+        infer. Distinct matters: a request that submits more than once
+        under one span (a single query after another) must not overflow
+        the per-trace span cap with duplicates."""
         parents: dict[str, tuple] = {}
-        for slot in live:
-            span = slot.parent_span
+        for group, _live in runs:
+            span = group.parent_span
             if span is not None and span.span_id not in parents:
-                parents[span.span_id] = (span, slot.submitted_mono)
+                parents[span.span_id] = (span, group.submitted_mono)
         links = [
             f"{p.trace_id}:{p.span_id}" for p, _t in parents.values()
         ]
@@ -963,7 +1245,7 @@ class MicroBatcher:
                 trace_key=parent.trace_key,
                 attributes={
                     "batcher": self.name,
-                    "occupancy": len(live),
+                    "occupancy": n,
                     "queueWaitMs": round(
                         max(0.0, start_mono - submitted_mono) * 1000, 3
                     ),

@@ -1230,23 +1230,35 @@ class EngineServer:
         ``time.monotonic()`` value) bounds the TOTAL wait across all of
         them, further capped by the request's propagated
         X-PIO-Deadline when one rode in."""
-        request_deadline = resilience.get_deadline()
-        if request_deadline is not None:
-            deadline = min(deadline, request_deadline.expires_mono)
+        deadline, request_deadline = self._wait_until(deadline)
         try:
             return [
                 f.result(timeout=max(0.001, deadline - time.monotonic()))
                 for f in futures
             ]
         except FuturesTimeout:
-            if request_deadline is not None and request_deadline.expired:
-                # the CLIENT's budget ran out while the query sat in
-                # the batch queue — a 504, not a server fault; the
-                # batcher will drop the still-queued slot pre-dispatch
-                raise resilience.DeadlineExceeded(
-                    "deadline expired while queued for dispatch"
-                ) from None
-            raise
+            raise self._timeout_error(request_deadline) from None
+
+    @staticmethod
+    def _wait_until(deadline: float):
+        """``(deadline, request deadline)``: ``deadline`` capped by the
+        request's propagated X-PIO-Deadline when one rode in."""
+        request_deadline = resilience.get_deadline()
+        if request_deadline is not None:
+            deadline = min(deadline, request_deadline.expires_mono)
+        return deadline, request_deadline
+
+    @staticmethod
+    def _timeout_error(request_deadline) -> Exception:
+        """What a wait for the batcher that ran out raises. Where the
+        CLIENT's budget ran out while the query sat in the batch queue
+        that is a 504, not a server fault (the batcher will drop the
+        still-queued slot pre-dispatch); else the time-out stands."""
+        if request_deadline is not None and request_deadline.expired:
+            return resilience.DeadlineExceeded(
+                "deadline expired while queued for dispatch"
+            )
+        return FuturesTimeout()
 
     def _serve_tail(self, serving, query, supplemented, predictions):
         """serve → feedback → plugin block/sniff
@@ -1298,7 +1310,7 @@ class EngineServer:
                     serving, batchers = pinned.enter_context(
                         self._serving_snapshot(request)
                     )
-                    entries, any_submitted = self._submit_batch(
+                    entries, groups, any_submitted = self._submit_batch(
                         serving, batchers, payload
                     )
                 if _attempt == 0 and not any_submitted and any(
@@ -1310,7 +1322,7 @@ class EngineServer:
                     # batchers is safe (mirrors the single-query retry)
                     continue
                 results = self._collect_batch(
-                    serving, entries, payload, request
+                    serving, entries, groups, payload, request
                 )
                 break
 
@@ -1329,34 +1341,29 @@ class EngineServer:
         return Response(200, results)
 
     def _collect_batch(
-        self, serving, entries, payload, request
+        self, serving, entries, groups, payload, request
     ) -> list[dict]:
-        """Collect a submitted batch's slots into per-query statuses
+        """Collect a submitted post's groups into per-query statuses
         (runs inside the serving snapshot so multi-tenant pins cover
         the waits)."""
-        # one deadline for the WHOLE batch: a hung dispatch must not
+        # one deadline for the WHOLE post: a hung dispatch must not
         # hold the connection for N sequential predict timeouts
-        deadline = time.monotonic() + self._predict_timeout_s
+        deadline, request_deadline = self._wait_until(
+            time.monotonic() + self._predict_timeout_s
+        )
 
         # every wait first, then every tail: the response leaves when
         # the last query is done either way, and each stage is one
-        # interval of the post, observed once
-        awaited: dict[int, Any] = {}
+        # interval of the post, observed once. One wait an algorithm:
+        # a group wakes this thread once, when its last slot is in
         with tracing.stage(tracing.ENGINE_AWAIT):
-            for i, (state, _data, futures) in enumerate(entries):
-                if state != "ok":
-                    continue
-                try:
-                    awaited[i] = self._await_predictions(futures, deadline)
-                except Exception as exc:  # noqa: BLE001 - per-slot status below
-                    awaited[i] = exc
+            for group in groups:
+                group.wait(max(0.001, deadline - time.monotonic()))
 
         results = []
         logged = False  # one remote report per batch, not per slot
         with tracing.stage(tracing.ENGINE_SERVE):
-            for i, ((state, data, futures), q) in enumerate(
-                zip(entries, payload)
-            ):
+            for (state, data, slot), q in zip(entries, payload):
                 if state == "bad":
                     results.append(
                         {"status": 400,
@@ -1388,9 +1395,12 @@ class EngineServer:
                     results.append({"status": 500, "message": str(data)})
                     continue
                 try:
-                    predictions = awaited[i]
-                    if isinstance(predictions, Exception):
-                        raise predictions
+                    try:
+                        predictions = [g.result(slot) for g in groups]
+                    except FuturesTimeout:
+                        raise self._timeout_error(
+                            request_deadline
+                        ) from None
                     prediction = self._serve_tail(
                         serving, q, data, predictions
                     )
@@ -1404,7 +1414,11 @@ class EngineServer:
                          "dispatch"}
                     )
                 except BatcherOverloaded:
-                    self._abandon([f for f in futures if not f.done()])
+                    # a queued slot was evicted by a higher-criticality
+                    # submission while the post waited: the sibling
+                    # algorithms' slots of this query that have no
+                    # answer yet are abandoned
+                    self._abandon_slots(groups, (slot,))
                     results.append(
                         {"status": 503,
                          "message": "shed under overload; retry later"}
@@ -1417,7 +1431,7 @@ class EngineServer:
         return results
 
     def _abandon(self, futures) -> None:
-        """A slot's accepted per-algorithm submits are being discarded
+        """A query's accepted per-algorithm submits are being discarded
         (partial overload or mid-submit reload): cancel them so the
         batcher drops the slots before dispatch. A future past the
         point of cancellation is genuinely wasted device work — counted
@@ -1427,31 +1441,36 @@ class EngineServer:
             if not f.cancel():
                 self._shed_wasted.inc()
 
+    def _abandon_slots(self, groups, slots) -> None:
+        """:meth:`_abandon` for a post: the queries at ``slots`` are
+        discarded in every algorithm's group. The slots still queued
+        are dropped before dispatch; those the device is already
+        working on are the wasted ones."""
+        for group in groups:
+            if wasted := group.cancel(slots):
+                self._shed_wasted.inc(wasted)
+
     def _submit_batch(
         self, serving, batchers, payload
-    ) -> tuple[list[tuple], bool]:
-        """Submit every query; returns (slots, any_submitted).
+    ) -> tuple[list[tuple], list, bool]:
+        """Submit a post's queries, one group an algorithm; returns
+        ``(entries, groups, any_submitted)``.
 
-        Slots: ``("ok", supplemented, futures)`` |
-        ``("bad"|"shed"|"reloading"|"expired", None, None)`` |
-        ``("error", exc, None)``. ``any_submitted`` is True once ANY
-        ``submit`` was accepted — including a partial multi-algorithm
-        slot whose later batcher then raised — which is exactly the
-        condition under which a whole-batch retry would double-dispatch
-        (close() is graceful: accepted items still run). Abandoned
-        partial slots are cancelled via :meth:`_abandon`, so
-        ``any_submitted`` stays conservative: a cancelled future can
-        already have been dispatched by the time cancel() runs."""
-        entries: list[tuple[str, Any, list | None]] = []
-        reloading = False
-        any_submitted = False
+        Entries, one a query: ``("ok", supplemented, slot)`` (its place
+        in every group) | ``("bad"|"shed"|"reloading"|"expired", None,
+        None)`` | ``("error", exc, None)``. A query is ``ok`` when every
+        algorithm's batcher admitted it; one that any batcher shed at
+        its bound is ``shed``, and its slots the other batchers took
+        are cancelled (:meth:`_abandon_slots`). ``any_submitted`` is
+        True once ANY slot was admitted — including those of a group
+        abandoned because a later batcher refused the post — which is
+        exactly the condition under which a whole-post retry would
+        double-dispatch (close() is graceful: accepted items still
+        run): a cancelled slot can already have been dispatched by the
+        time cancel() runs."""
+        entries: list[tuple[str, Any, int | None]] = []
+        items = []
         for q in payload:
-            if reloading:
-                # /reload closed the snapshot's batchers mid-submit;
-                # earlier accepted slots stay valid (graceful close) —
-                # the remaining slots simply report the reload
-                entries.append(("reloading", None, None))
-                continue
             if not isinstance(q, dict):
                 entries.append(("bad", None, None))
                 continue
@@ -1460,26 +1479,36 @@ class EngineServer:
             except Exception as exc:  # noqa: BLE001 - per-slot status
                 entries.append(("error", exc, None))
                 continue
-            futures = []
-            try:
-                for b in batchers:
-                    futures.append(b.submit(supplemented))
-                    any_submitted = True
-            except BatcherOverloaded:
-                self._abandon(futures)
-                entries.append(("shed", None, None))
-                continue
-            except resilience.DeadlineExceeded:
-                self._abandon(futures)
-                entries.append(("expired", None, None))
-                continue
-            except RuntimeError:
-                self._abandon(futures)
-                reloading = True
-                entries.append(("reloading", None, None))
-                continue
-            entries.append(("ok", supplemented, futures))
-        return entries, any_submitted
+            entries.append(("ok", supplemented, len(items)))
+            items.append(supplemented)
+        if not items:
+            return entries, [], False
+        groups: list = []
+        refused = "shed"
+        try:
+            for b in batchers:
+                groups.append(b.submit_group(items))
+            # each batcher admits the post as far as its queue has room
+            admitted = min((g.admitted for g in groups), default=len(items))
+        except resilience.DeadlineExceeded:
+            refused, admitted = "expired", 0
+        except RuntimeError:
+            # /reload closed the snapshot's batchers mid-submit
+            refused, admitted = "reloading", 0
+        any_submitted = any(g.admitted for g in groups)
+        if admitted < len(items):
+            # what some batcher did not take must not run for nothing
+            # in the others
+            self._abandon_slots(groups, range(admitted, len(items)))
+            entries = [
+                (refused, None, None)
+                if entry[2] is not None and entry[2] >= admitted
+                else entry
+                for entry in entries
+            ]
+            if not admitted:
+                groups = []  # nothing left to wait for
+        return entries, groups, any_submitted
 
     def _record_feedback(self, query: dict, prediction):
         """Store a ``predict`` event (entity ``pio_pr``) carrying query +

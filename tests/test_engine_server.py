@@ -276,6 +276,141 @@ class TestBatchQueries:
         assert after == before + 2
 
 
+class _ParkedBatchFn:
+    """A batcher's ``batch_fn`` for the group tests: the batch that
+    starts with ``"plug"`` parks the collector until released, so what
+    a post submits meanwhile stays queued; a batch holding ``x ==
+    "boom"`` fails; answers are the fake engine's."""
+
+    def __init__(self):
+        self.parked = threading.Event()
+        self.release = threading.Event()
+        self.batches = []
+
+    def __call__(self, items):
+        if items[0] == "plug":
+            self.parked.set()
+            assert self.release.wait(10)
+            return items
+        self.batches.append([q["x"] for q in items])
+        if any(q["x"] == "boom" for q in items):
+            raise ValueError("injected batch failure")
+        return [{"result": 30 + q["x"]} for q in items]
+
+    def park(self, batcher):
+        batcher.submit("plug")
+        assert self.parked.wait(10)
+
+
+class TestBatchPostAsGroups:
+    """A post is one group a batcher (docs/serving.md "A post is one
+    group"): the statuses stay per query."""
+
+    def test_bad_entry_shed_tail_and_failed_batch_keep_their_slots(
+        self, server
+    ):
+        base, es, _ = server
+        fn = _ParkedBatchFn()
+        batcher = MicroBatcher(fn, max_batch=2, max_wait_ms=1, max_queue=4)
+        old, es._batchers = es._batchers, [batcher]
+        submitted = threading.Event()
+        admit = batcher.submit_group
+
+        def spy(items):
+            group = admit(items)
+            submitted.set()
+            return group
+
+        batcher.submit_group = spy
+        try:
+            fn.park(batcher)
+            answer = []
+            post = threading.Thread(
+                target=lambda: answer.append(_call(
+                    f"{base}/batch/queries.json", "POST",
+                    [{"x": 1}, "not-a-query", {"x": 2}, {"x": "boom"},
+                     {"x": 4}, {"x": 5}, {"x": 6}],
+                ))
+            )
+            post.start()
+            assert submitted.wait(10)
+            fn.release.set()
+            post.join(15)
+            status, body = answer[0]
+        finally:
+            fn.release.set()
+            es._batchers = old
+            batcher.close()
+        assert status == 200
+        # four fit the queue, the tail is shed; the second batch failed
+        assert [r["status"] for r in body] == [
+            200, 400, 200, 500, 500, 503, 503
+        ]
+        assert [body[i]["prediction"]["result"] for i in (0, 2)] == [31, 32]
+        assert "injected" in body[3]["message"]
+        assert "overloaded" in body[5]["message"]
+        assert fn.batches == [[1, 2], ["boom", 4]]
+
+    def test_second_batcher_shedding_abandons_the_firsts_matching_slots(
+        self,
+    ):
+        """Two algorithms: a query is served only where both batchers
+        admitted it, and what the first took of the rest is cancelled
+        before its device sees it."""
+        from predictionio_tpu.obs import MetricRegistry
+
+        registry = MetricRegistry()
+        first_fn, second_fn = _ParkedBatchFn(), _ParkedBatchFn()
+        first = MicroBatcher(
+            first_fn, max_batch=8, max_wait_ms=1,
+            registry=registry, name="first",
+        )
+        second = MicroBatcher(
+            second_fn, max_batch=8, max_wait_ms=1, max_queue=3,
+            registry=registry, name="second",
+        )
+
+        class Seam:
+            _shed_wasted = registry.counter(
+                "pio_shed_wasted_dispatch_total", "h"
+            )
+            _abandon_slots = EngineServer._abandon_slots
+            _submit_batch = EngineServer._submit_batch
+
+        class Serving:
+            def supplement(self, q):
+                return q
+
+        try:
+            first_fn.park(first)
+            second_fn.park(second)
+            entries, groups, any_submitted = Seam()._submit_batch(
+                Serving(), [first, second], [{"x": i} for i in range(5)]
+            )
+            assert [e[0] for e in entries] == ["ok"] * 3 + ["shed"] * 2
+            assert [e[2] for e in entries[:3]] == [0, 1, 2]
+            assert any_submitted
+            assert [g.admitted for g in groups] == [5, 3]
+            first_fn.release.set()
+            second_fn.release.set()
+            assert all(g.wait(10) for g in groups)
+            assert [g.result(2) for g in groups] == [{"result": 32}] * 2
+        finally:
+            first_fn.release.set()
+            second_fn.release.set()
+            first.close()
+            second.close()
+        # exactly the two the second batcher shed never ran in the first
+        assert first_fn.batches == [[0, 1, 2]]
+        assert second_fn.batches == [[0, 1, 2]]
+        cancelled = registry.counter(
+            "pio_batch_cancelled_total", "", ("batcher",)
+        )
+        assert cancelled.labels("first").value == 2
+        assert cancelled.labels("second").value == 0
+        assert Seam._shed_wasted.value == 0
+
+
 class TestBindAndUndeploy:
     def test_undeploy_before_deploy_stops_old_server(
         self, ctx, memory_storage

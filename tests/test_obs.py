@@ -415,14 +415,23 @@ class TestShedCancellation:
         )
 
         class AlwaysOverloaded:
-            def submit(self, item):
-                raise BatcherOverloaded("full")
+            """A batcher at its bound: the group it returns admitted
+            no slot."""
+
+            class Shed:
+                admitted = 0
+
+                def cancel(self, indices=None):
+                    return 0
+
+            def submit_group(self, items):
+                return self.Shed()
 
         class FakeES:
             _shed_wasted = registry.counter(
                 "pio_shed_wasted_dispatch_total", "h"
             )
-            _abandon = EngineServer._abandon
+            _abandon_slots = EngineServer._abandon_slots
             _submit_batch = EngineServer._submit_batch
 
         class PassthroughServing:
@@ -431,12 +440,13 @@ class TestShedCancellation:
 
         es = FakeES()
         try:
-            entries, any_submitted = es._submit_batch(
+            entries, _groups, any_submitted = es._submit_batch(
                 PassthroughServing(), [ok, AlwaysOverloaded()],
                 [{"x": 1}],
             )
             assert entries[0][0] == "shed"
-            # the accepted future was cancelled before dispatch: the
+            assert any_submitted
+            # the accepted slot was cancelled before dispatch: the
             # batcher counts it dropped, no device batch ever runs
             deadline = time.time() + 5
             cancelled = registry.counter(
