@@ -426,11 +426,17 @@ def load_deployment(
         last_error: Exception | None = None
         for candidate in candidates:
             try:
-                blob = load_generation(models_backend, candidate.id)
+                # the three steps of a load are stages (a pool's cold
+                # load nests them in its `pool.load`; a single-tenant
+                # deploy or /reload observes them bare)
+                with tracing.stage(tracing.POOL_READ):
+                    blob = load_generation(models_backend, candidate.id)
                 # a blob that passed (or predates) checksums can still
                 # be an unreadable pickle — for fallback purposes both
                 # are the same failure: this generation cannot serve
-                entries = deserialize_models(blob)
+                with tracing.stage(tracing.POOL_DESERIALIZE):
+                    entries = deserialize_models(blob)
+                del blob  # tens of MB a tenant: gone before the promote
             except (
                 ModelIntegrityError,
                 pickle.UnpicklingError,
@@ -468,7 +474,8 @@ def load_deployment(
             )
     else:
         stored = [None] * len(algorithms)
-    algorithms, models, serving = engine.prepare_deploy(
-        ctx, params, instance.id, stored
-    )
+    with tracing.stage(tracing.POOL_PROMOTE):
+        algorithms, models, serving = engine.prepare_deploy(
+            ctx, params, instance.id, stored
+        )
     return instance, algorithms, models, serving

@@ -570,6 +570,35 @@ STAGES = (
 PREDICT_RULES = "predict.rules"
 NESTED_STAGES = (PREDICT_RULES,)
 
+#: the model pool's stages (serving/modelpool.py; multi-tenant serving).
+#: ``pool.wait`` runs on a handler's thread, between ``engine.decode``
+#: and ``engine.submit``: the time a request whose tenant was not
+#: resident spent inside ``ModelPool.pin`` until the cold load it asked
+#: for (or joined) was in. It is a stage of the request's partition, so
+#: the handler's stages still add up to the request; a lookup that hits
+#: opens no stage. The others run on the pool's one loader thread and
+#: are no part of any request: ``pool.load`` around a whole cold load,
+#: and nested in it ``pool.read`` (the blob out of the model store,
+#: checksum included), ``pool.deserialize`` (the unpickle, id maps
+#: included), ``pool.promote`` (the tables to the device; the quantizer
+#: where the server has one), ``pool.warmup`` (the compile buckets) and
+#: ``pool.batchers``; ``pool.close`` around the ``close_fn`` of an
+#: evicted or replaced generation. A group of their own: :data:`STAGES`
+#: is the tuple the idle states of a device trace are built on, and no
+#: load belongs to a batch
+POOL_WAIT = "pool.wait"
+POOL_LOAD = "pool.load"
+POOL_READ = "pool.read"
+POOL_DESERIALIZE = "pool.deserialize"
+POOL_PROMOTE = "pool.promote"
+POOL_WARMUP = "pool.warmup"
+POOL_BATCHERS = "pool.batchers"
+POOL_CLOSE = "pool.close"
+POOL_STAGES = (
+    POOL_WAIT, POOL_LOAD, POOL_READ, POOL_DESERIALIZE, POOL_PROMOTE,
+    POOL_WARMUP, POOL_BATCHERS, POOL_CLOSE,
+)
+
 #: ``factory(name, **keywords)`` -> context manager that writes a host
 #: event into a running profiler's trace, and ``active()`` -> whether a
 #: profiler runs (a flag test; a stage builds no annotation while none
@@ -611,11 +640,18 @@ class StageSink:
     """``pio_stage_seconds{stage}`` of one registry (``None``: the
     process's), every child resolved here. A server builds one at
     wiring time and each of its threads binds it (with the keywords its
-    annotations carry) before the stages it runs."""
+    annotations carry) before the stages it runs. A sink of ``names``
+    alone (the model pool's loader: :data:`POOL_STAGES`) takes those
+    stages; what else its thread runs (a warm-up's ``predict.*``, its
+    launches, a model's own counters) stays the process's, so that a
+    server's series of served batches hold no warm-up."""
 
     __slots__ = ("_children",)
 
-    def __init__(self, registry: MetricRegistry | None):
+    def __init__(
+        self, registry: MetricRegistry | None,
+        names: tuple[str, ...] | None = None,
+    ):
         if registry is None:
             registry = get_registry()
         family = registry.histogram(
@@ -626,8 +662,11 @@ class StageSink:
             buckets=STAGE_BUCKETS,
         )
         self._children = _StageChildren(
-            (name, family.labels(name)) for name in STAGES + NESTED_STAGES
+            (name, family.labels(name))
+            for name in names or STAGES + NESTED_STAGES + POOL_STAGES
         )
+        if names is not None:
+            registry = get_registry()
         self._children.registry = registry
         self._children.launch_calls = registry.counter(
             "pio_device_launch_calls_total",
@@ -704,8 +743,8 @@ class _Stage:
 
 
 def stage(name: str) -> _Stage:
-    """Context manager around one stage (a name of :data:`STAGES` or
-    :data:`NESTED_STAGES`) of a
+    """Context manager around one stage (a name of :data:`STAGES`,
+    :data:`NESTED_STAGES` or :data:`POOL_STAGES`) of a
     request, a post or a batch — never of one query inside a post or
     of one item. Always observes ``pio_stage_seconds{stage}``; while a
     profiler runs (whoever started it) it is an annotation of the same
@@ -717,10 +756,13 @@ def stage(name: str) -> _Stage:
     )
     child = children.get(name)
     if child is None:
-        raise ValueError(
-            f"unknown stage {name!r}: stages are a closed set "
-            "(obs.tracing.STAGES)"
-        )
+        # a sink of some stages alone leaves the others to the process
+        child = _default_sink._children.get(name)
+        if child is None:
+            raise ValueError(
+                f"unknown stage {name!r}: stages are a closed set "
+                "(obs.tracing.STAGES)"
+            )
     return _Stage(name, child, keywords)
 
 

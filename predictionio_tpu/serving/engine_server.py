@@ -91,6 +91,29 @@ from predictionio_tpu.utils import profiling
 logger = logging.getLogger(__name__)
 
 
+def _model_signature(model, _depth: int = 3):
+    """What of a staged model decides which programs its predict
+    compiles: shape and dtype of every array it holds, its scalars, and
+    kind and size of whatever else (an id map), field by field. Two
+    models of one signature, under one algorithm's params on one
+    compute context, run the same compiled programs."""
+    if isinstance(model, (type(None), bool, int, float, str)):
+        return model
+    shape, dtype = getattr(model, "shape", None), getattr(model, "dtype", None)
+    if shape is not None and dtype is not None:
+        return (tuple(shape), str(dtype))
+    if _depth > 0:
+        if dataclasses.is_dataclass(model) and not isinstance(model, type):
+            return (type(model).__qualname__,) + tuple(
+                (f.name, _model_signature(getattr(model, f.name, None), _depth - 1))
+                for f in dataclasses.fields(model)
+            )
+        if isinstance(model, (list, tuple)):
+            return tuple(_model_signature(v, _depth - 1) for v in model)
+    size = len(model) if hasattr(model, "__len__") else None
+    return (type(model).__qualname__, size)
+
+
 @dataclasses.dataclass
 class _StagedGeneration:
     """One loaded generation: the instance record, its serving layer,
@@ -290,6 +313,10 @@ class EngineServer:
         # without memory stats degrade to a clean no-op.
         self._device_sampler = DeviceSampler(self._registry)
         self._compile_tracker = CompileTracker(self._registry)
+        #: (algorithm, params, model signature, bucket) of every warm-up
+        #: bucket this server has run without a failure (under
+        #: ``self._lock``: a reload and the pool's loader both stage)
+        self._warmed: set = set()
         # what XLA itself compiled, from here (the loads and warm-ups
         # below included) until close()
         self._compile_watch = CompileWatch(self._registry)
@@ -499,21 +526,32 @@ class EngineServer:
         return load
 
     def _preload_tenants(self) -> None:
-        """Eager initial load of every tenant through the pool (LRU
-        keeps whatever fits the budget; the rest reload on first hit).
-        The replica only advertises warm once every tenant's warmup
-        compiled — matching the single-tenant contract the router's
-        admission gate reads."""
+        """Eager initial load through the pool, in the order the
+        tenants were given, until the pool is full: the first tenant
+        that would need a victim (judged by the size of the one staged
+        before it) and all after it stay cold and load on first hit —
+        staging them now would only evict what was just staged. The
+        replica advertises warm once every tenant it staged warmed —
+        matching the single-tenant contract the router's admission
+        gate reads."""
         warmed_all = True
+        staged_count = 0
+        nbytes = 0
         for tenant in self._tenants:
+            if staged_count and not self._pool.fits(nbytes):
+                break
             with self._pool.pin(
                 tenant, self._tenant_loader(tenant)
             ) as staged:
                 warmed_all = warmed_all and staged.warmed
+                nbytes = staged.nbytes
+            staged_count += 1
         self._warmed_gauge.set(1 if warmed_all else 0)
         logger.info(
-            "multi-tenant server preloaded %d tenant(s), %d resident",
-            len(self._tenants), len(self._pool.resident()),
+            "multi-tenant server preloaded %d of %d tenant(s), %d left "
+            "cold (they load on first hit)",
+            staged_count, len(self._tenants),
+            len(self._tenants) - staged_count,
         )
 
     def _resolve_tenant(self, request: Request) -> str:
@@ -643,12 +681,13 @@ class EngineServer:
             from predictionio_tpu.ops import quantize as quantize_mod
 
             if self._quantize:
-                models = [
-                    quantize_mod.quantize_model_factors(
-                        m, self._quantize
-                    )
-                    for m in models
-                ]
+                with tracing.stage(tracing.POOL_PROMOTE):
+                    models = [
+                        quantize_mod.quantize_model_factors(
+                            m, self._quantize
+                        )
+                        for m in models
+                    ]
             nbytes = sum(
                 quantize_mod.model_resident_bytes(m) for m in models
             )
@@ -657,10 +696,11 @@ class EngineServer:
             if tenant is not None
             else f"{self._engine_id}/"
         )
-        warmed = bool(
-            self._warmup
-            and self._precompile(algorithms, models, name_prefix)
-        )
+        with tracing.stage(tracing.POOL_WARMUP):
+            warmed = bool(
+                self._warmup
+                and self._precompile(algorithms, models, name_prefix)
+            )
 
         def batch_fn(a, m):
             # the collector enqueues batch N+1's device work while the
@@ -672,19 +712,22 @@ class EngineServer:
                 lambda state: collect(m, *state),
             )
 
-        batchers = [
-            MicroBatcher(
-                batch_fn(algo, model),
-                max_batch=self._max_batch,
-                max_wait_ms=self._max_wait_ms,
-                max_queue=self._max_queue,
-                pipeline_depth=self._pipeline_depth,
-                adaptive_wait=self._adaptive_wait,
-                registry=self._registry,
-                name=f"{name_prefix}algo{i}",
-            )
-            for i, (algo, model) in enumerate(zip(algorithms, models))
-        ]
+        with tracing.stage(tracing.POOL_BATCHERS):
+            batchers = [
+                MicroBatcher(
+                    batch_fn(algo, model),
+                    max_batch=self._max_batch,
+                    max_wait_ms=self._max_wait_ms,
+                    max_queue=self._max_queue,
+                    pipeline_depth=self._pipeline_depth,
+                    adaptive_wait=self._adaptive_wait,
+                    registry=self._registry,
+                    name=f"{name_prefix}algo{i}",
+                )
+                for i, (algo, model) in enumerate(
+                    zip(algorithms, models)
+                )
+            ]
         return _StagedGeneration(
             instance=instance,
             serving=serving,
@@ -711,10 +754,20 @@ class EngineServer:
         compile fine — but repeated failures cap out rather than burn
         the whole reload window.
 
-        Returns True when every attempted bucket compiled (cold-by-
-        design algorithms don't count against it) — the condition for
-        ``pio_warmup_complete`` to read 1; an all-failures warmup must
-        not advertise a warm server to traffic gates.
+        A bucket this server has already warmed for the same
+        algorithm class and params over a model of the same signature
+        (:func:`_model_signature`: the second tenant of a pool, the
+        next generation of a reload) is not run again: its programs
+        are in this process's jit cache, and a warm run is only a round
+        trip to the device a bucket. The first model of a signature
+        still warms them all.
+
+        Returns True when every bucket is compiled — run now without a
+        failure, or by an earlier model of the same key, whose programs
+        this one relies on (cold-by-design algorithms don't count
+        against it) — the condition for ``pio_warmup_complete`` to read
+        1; an all-failures warmup must not advertise a warm server to
+        traffic gates.
         """
         t0 = time.perf_counter()
         # per-bucket wall time lands in the registry so a scrape
@@ -739,8 +792,25 @@ class EngineServer:
                 # without burning three failed warmup attempts
                 logger.info("%s: no warmup query — serving cold", name)
                 continue
-            bucket, failures, compiled = 1, 0, 0
-            while True:
+            failures, compiled = 0, 0
+            # what decides the programs a bucket compiles: the
+            # algorithm, its params, and the model's shapes and dtypes
+            programs = (
+                type(algo).__qualname__, i,
+                repr(getattr(algo, "params", None)),
+                _model_signature(model),
+            )
+            # powers of two through the next one a non-power-of-two
+            # max_batch rounds up into at predict time
+            buckets = [1]
+            while buckets[-1] < self._max_batch:
+                buckets.append(2 * buckets[-1])
+            with self._lock:
+                todo = [
+                    b for b in buckets if (programs, b) not in self._warmed
+                ]
+            warm = len(buckets) - len(todo)
+            for bucket in todo:
                 b0 = time.perf_counter()
                 try:
                     algo.batch_predict(model, [query] * bucket)
@@ -751,6 +821,8 @@ class EngineServer:
                     self._compile_tracker.record(
                         batcher_name, str(bucket)
                     )
+                    with self._lock:
+                        self._warmed.add((programs, bucket))
                 except Exception as e:  # noqa: BLE001 - warmup best-effort
                     bucket_gauge.labels(batcher_name, str(bucket)).set(
                         time.perf_counter() - b0
@@ -776,15 +848,11 @@ class EngineServer:
                         )
                     if failures >= 3:
                         break
-                if bucket >= self._max_batch:
-                    # covers the next-pow2 bucket a non-power-of-two
-                    # max_batch rounds up into at predict time
-                    break
-                bucket *= 2
             total_failures += failures
             logger.info(
-                "%s: warmup compiled %d bucket(s)%s",
+                "%s: warmup compiled %d bucket(s)%s%s",
                 name, compiled,
+                f", {warm} warm already" if warm else "",
                 f", {failures} failed" if failures else "",
             )
         logger.info(
@@ -1115,12 +1183,13 @@ class EngineServer:
             # for the WHOLE submit→collect span, so eviction can't
             # close the generation under an in-flight query
             with contextlib.ExitStack() as pinned:
-                # the stage covers taking the snapshot too: in a pool
-                # that is the tenant's pin, possibly its load
+                # the snapshot comes before the stage: in a pool it is
+                # the tenant's pin, and where the tenant has to load
+                # first the pool times that wait as `pool.wait`
+                serving, batchers = pinned.enter_context(
+                    self._serving_snapshot(request)
+                )
                 with tracing.stage(tracing.ENGINE_SUBMIT):
-                    serving, batchers = pinned.enter_context(
-                        self._serving_snapshot(request)
-                    )
                     supplemented = serving.supplement(query)
                     futures = []
                     # single-flight leaders submit at the HIGHEST class
@@ -1306,10 +1375,10 @@ class EngineServer:
             # pin (multi-tenant) spans submit AND collection, same as
             # the single-query route
             with contextlib.ExitStack() as pinned:
+                serving, batchers = pinned.enter_context(
+                    self._serving_snapshot(request)
+                )
                 with tracing.stage(tracing.ENGINE_SUBMIT):
-                    serving, batchers = pinned.enter_context(
-                        self._serving_snapshot(request)
-                    )
                     entries, groups, any_submitted = self._submit_batch(
                         serving, batchers, payload
                     )

@@ -21,7 +21,12 @@ tables in a single chip's HBM. The pool is the residency authority:
 * **per-tenant metrics** — ``pio_pool_hits_total`` /
   ``pio_pool_misses_total`` / ``pio_pool_evictions_total`` /
   ``pio_pool_resident_bytes`` plus pool-wide
-  ``pio_pool_budget_bytes`` / ``pio_pool_tenants_resident``.
+  ``pio_pool_budget_bytes`` / ``pio_pool_tenants_resident`` /
+  ``pio_pool_loaded_bytes_total`` / ``pio_pool_load_queue``.
+* **stages** (``obs.tracing.POOL_STAGES``) — the loader thread times
+  ``pool.load`` around each cold load and ``pool.close`` around each
+  evicted generation's ``close_fn``; a request thread times
+  ``pool.wait`` around the wait a miss costs it. A hit opens no stage.
 
 The pool stores opaque values: the engine server keeps whole staged
 generations (models + batchers) in it, the density bench keeps bare
@@ -41,6 +46,7 @@ import time
 from typing import Callable
 
 from predictionio_tpu.obs import timeline as timeline_mod
+from predictionio_tpu.obs import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -162,6 +168,9 @@ class ModelPool:
         self._loading: dict[str, _Load] = {}
         self._resident_bytes = 0  # includes retired-but-pinned bytes
         self._evictions = 0
+        self._queued_loads = 0  # enqueued, not yet begun by the loader
+        #: where the loader thread times the pool's stages
+        self._stages = tracing.StageSink(registry, tracing.POOL_STAGES)
         self._closed = False
         self._jobs: queue.Queue = queue.Queue()
         # non-daemon on purpose: joined in close(), which owners call
@@ -171,6 +180,7 @@ class ModelPool:
         )
         self._worker.start()
         self._hits = self._misses = self._evicted = None
+        self._loaded_bytes = None
         self._resident_gauge = None
         self._byte_seconds = None
         self._timeline = timeline
@@ -211,10 +221,25 @@ class ModelPool:
                 "pio_pool_tenants_resident",
                 "Tenants currently resident in the model pool",
             ).set_function(lambda: float(len(self._entries)))
+            self._loaded_bytes = registry.counter(
+                "pio_pool_loaded_bytes_total",
+                "Device bytes committed by cold loads (and replaces) "
+                "since the pool was built",
+            )
+            registry.gauge(
+                "pio_pool_load_queue",
+                "Cold loads waiting for the pool's one loader thread "
+                "(the load it is running is not among them)",
+            ).set_function(lambda: float(self._queued_loads))
 
     @property
     def budget_bytes(self) -> int:
         return self._budget
+
+    def fits(self, nbytes: int) -> bool:
+        """Whether ``nbytes`` more would go in without a victim."""
+        with self._lock:
+            return self._resident_bytes + int(nbytes) <= self._budget
 
     def _charge(self, entry, now: float | None = None) -> None:
         """Accrue the entry's residency since its last charge (bytes x
@@ -258,54 +283,58 @@ class ModelPool:
             self._unpin(entry)
 
     def _acquire(self, tenant, loader, timeout):
+        # a lookup is a hit or a miss once, here; the pin taken after
+        # waiting out a cold load is the same miss, not a new hit
+        with self._lock:
+            if self._closed:
+                raise RuntimeError("model pool is closed")
+            entry = self._entries.get(tenant)
+            if entry is not None:
+                entry.pins += 1
+                entry.last_used = time.monotonic()
+                self._charge(entry, entry.last_used)
+                entry.hits += 1
+        if entry is not None:
+            if self._hits is not None:
+                self._hits.labels(tenant).inc()
+            return entry
+        if self._misses is not None:
+            self._misses.labels(tenant).inc()
+        with tracing.stage(tracing.POOL_WAIT):
+            return self._await_load(tenant, loader, timeout)
+
+    def _await_load(self, tenant, loader, timeout):
+        """After a miss: join the tenant's single-flight load (or
+        enqueue one), wait it out, and pin what it inserted."""
         deadline = (
             None if timeout is None else time.monotonic() + timeout
         )
-        first_pass = True
         while True:
-            load = None
             with self._lock:
                 if self._closed:
                     raise RuntimeError("model pool is closed")
                 entry = self._entries.get(tenant)
                 if entry is not None:
+                    # the pin, as `_acquire` takes it inline (a hit
+                    # pays no call for it)
                     entry.pins += 1
                     entry.last_used = time.monotonic()
                     self._charge(entry, entry.last_used)
-                    if first_pass:
-                        entry.hits += 1
-                else:
-                    load = self._loading.get(tenant)
-                    if load is None:
-                        load = _Load(tenant, loader)
-                        self._loading[tenant] = load
-                        self._jobs.put(load)
-            if entry is not None:
-                # a lookup is a hit or a miss once, on its first pass —
-                # the pin taken after waiting out a cold load is the
-                # same miss, not a new hit
-                if first_pass and self._hits is not None:
-                    self._hits.labels(tenant).inc()
-                return entry
-            if first_pass and self._misses is not None:
-                self._misses.labels(tenant).inc()
-            first_pass = False
+                    return entry
+                load = self._loading.get(tenant)
+                if load is None:
+                    load = _Load(tenant, loader)
+                    self._loading[tenant] = load
+                    self._queued_loads += 1
+                    self._jobs.put(load)
             remaining = (
                 None
                 if deadline is None
                 else deadline - time.monotonic()
             )
-            if remaining is not None and remaining <= 0:
-                self._emit(
-                    "pool_load_timeout",
-                    f"cold load for tenant {tenant!r} missed the "
-                    "caller's deadline",
-                    severity=timeline_mod.ERROR, tenant=tenant,
-                )
-                raise PoolLoadTimeout(
-                    f"timed out waiting for tenant {tenant!r} to load"
-                )
-            if not load.done.wait(remaining):
+            if (
+                remaining is not None and remaining <= 0
+            ) or not load.done.wait(remaining):
                 self._emit(
                     "pool_load_timeout",
                     f"cold load for tenant {tenant!r} missed the "
@@ -323,12 +352,32 @@ class ModelPool:
             # pass (or, under extreme pressure, re-loaded)
 
     def _unpin(self, entry) -> None:
-        close = False
         with self._lock:
             entry.pins -= 1
-            close = entry.retired and entry.pins == 0
-        if close:
-            self._jobs.put(_Close(entry))
+            if entry.pins or not (
+                entry.retired or self._resident_bytes > self._budget
+            ):
+                return  # every hit in a pool inside its budget
+            to_close: list[_Entry] = []
+            if entry.retired:
+                to_close.append(entry)
+            else:
+                self._shed_overcommit_locked(to_close)
+        for stale in to_close:
+            self._jobs.put(_Close(stale))
+
+    def _shed_overcommit_locked(self, to_close: list) -> None:
+        """A load that found every other tenant pinned left the pool
+        over its budget; the first pin to drain pays that back, not the
+        next load (which may never come). ``_resident_bytes`` also
+        counts what is retired and waiting for its close, and that is
+        no overcommit: the entries that are in are summed (only here,
+        while the ledger reads over budget)."""
+        live = sum(e.nbytes for e in self._entries.values())
+        if live > self._budget:
+            # "incoming" less the retired bytes: the loop then holds
+            # the live entries alone to the budget
+            self._evict_for_locked(live - self._resident_bytes, to_close)
 
     # -- lifecycle (loader thread) ----------------------------------------
 
@@ -338,13 +387,33 @@ class ModelPool:
             if job is _STOP:
                 break
             if isinstance(job, _Close):
+                self._stages.bind(tenant=job.entry.tenant)
                 self._close_entry(job.entry)
                 continue
             self._do_load(job)
 
     def _do_load(self, load: _Load) -> None:
+        with self._lock:
+            self._queued_loads -= 1
+        # the loader's stages go to this pool's registry, and carry
+        # the tenant in a running profiler's trace
+        self._stages.bind(tenant=load.tenant)
+        to_close: list[_Entry] = []
         try:
-            value, nbytes, close_fn = load.loader()
+            with tracing.stage(tracing.POOL_LOAD):
+                value, nbytes, close_fn = load.loader()
+                entry = _Entry(
+                    load.tenant, value, nbytes, close_fn,
+                    time.monotonic(),
+                )
+                with self._lock:
+                    self._evict_for_locked(entry.nbytes, to_close)
+                    old = self._entries.get(load.tenant)
+                    if old is not None:  # a replace raced us; retire it
+                        self._retire_locked(old, to_close)
+                    self._entries[load.tenant] = entry
+                    self._resident_bytes += entry.nbytes
+                    self._loading.pop(load.tenant, None)
         except BaseException as exc:  # surfaced to every waiter
             with self._lock:
                 self._loading.pop(load.tenant, None)
@@ -357,23 +426,18 @@ class ModelPool:
             load.error = exc
             load.done.set()
             return
-        entry = _Entry(
-            load.tenant, value, nbytes, close_fn, time.monotonic()
-        )
-        to_close: list[_Entry] = []
-        with self._lock:
-            self._evict_for_locked(entry.nbytes, to_close)
-            old = self._entries.get(load.tenant)
-            if old is not None:  # a replace raced us; retire it
-                self._retire_locked(old, to_close)
-            self._entries[load.tenant] = entry
-            self._resident_bytes += entry.nbytes
-            self._loading.pop(load.tenant, None)
         if self._resident_gauge is not None:
             self._resident_gauge.labels(load.tenant).set(
                 float(entry.nbytes)
             )
+        if self._loaded_bytes is not None:
+            self._loaded_bytes.inc(float(entry.nbytes))
+        # the waiters go on as soon as the entry is in: the victims'
+        # closes (two thread joins a batcher) are the loader's to pay,
+        # not the request's
+        load.done.set()
         for stale in to_close:
+            self._stages.bind(tenant=stale.tenant)
             self._close_entry(stale)
         with self._lock:
             resident = self._resident_bytes
@@ -383,7 +447,6 @@ class ModelPool:
                 "every other tenant is pinned",
                 resident, self._budget,
             )
-        load.done.set()
 
     def _evict_for_locked(self, incoming: int, to_close: list) -> None:
         """Pop LRU *unpinned* entries until ``incoming`` fits the
@@ -425,12 +488,17 @@ class ModelPool:
     def _close_entry(self, entry) -> None:
         try:
             if entry.close_fn is not None:
-                entry.close_fn()
+                with tracing.stage(tracing.POOL_CLOSE):
+                    entry.close_fn()
         except Exception:
             logger.exception(
                 "closing pooled model for tenant %r failed",
                 entry.tenant,
             )
+        # nobody is pinned on a closed entry: what it held (the staged
+        # generation, through it the device tables) goes with this
+        # reference, not with whoever still holds the entry
+        entry.value = entry.close_fn = None
         with self._lock:
             # the retired-but-pinned tail still held HBM: charge it
             # through to the actual close
@@ -484,6 +552,8 @@ class ModelPool:
             self._resident_bytes += entry.nbytes
         if self._resident_gauge is not None:
             self._resident_gauge.labels(tenant).set(float(entry.nbytes))
+        if self._loaded_bytes is not None:
+            self._loaded_bytes.inc(float(entry.nbytes))
         for stale in to_close:
             self._jobs.put(_Close(stale))
 

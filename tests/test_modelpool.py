@@ -237,3 +237,146 @@ class TestMetricsAndStats:
         with pytest.raises(RuntimeError):
             with pool.pin("b", _loader("b")):
                 pass
+
+
+def _stage_count(registry, name, field="count"):
+    family = registry.to_dict().get("pio_stage_seconds", {"samples": []})
+    return sum(
+        s[field] for s in family["samples"] if s["labels"]["stage"] == name
+    )
+
+
+class TestStagesAndTheLoadQueue:
+    """The pool's stages (`tracing.POOL_STAGES`) and the two series that
+    came with them: one `pool.load` a cold load and one `pool.wait` a
+    lookup that missed, on the pool's registry whatever thread waits;
+    nothing a hit; `pool.close` a closed victim."""
+
+    def test_a_load_and_a_wait_a_miss_and_nothing_a_hit(self):
+        from predictionio_tpu.obs import tracing
+
+        registry = MetricRegistry()
+        # the waiting thread is bound to the pool's registry, as a
+        # server's handler is
+        token = tracing._bound_stages.set(None)
+        tracing.StageSink(registry).bind()
+        pool = ModelPool(250, registry=registry)
+        closed = []
+        try:
+            for tenant, nbytes in (("a", 100), ("a", 100), ("b", 200), ("b", 200)):
+                with pool.pin(tenant, _loader(tenant, nbytes, closed, delay=0.02)):
+                    pass
+            assert _stage_count(registry, tracing.POOL_LOAD) == 2
+            assert _stage_count(registry, tracing.POOL_WAIT) == 2
+            assert _stage_count(registry, tracing.POOL_WAIT, "sum") >= 0.04
+            assert _stage_count(registry, tracing.POOL_LOAD, "sum") >= 0.04
+            deadline = time.monotonic() + 2.0
+            while time.monotonic() < deadline and not closed:
+                time.sleep(0.005)
+            assert closed == ["a"]
+            assert _stage_count(registry, tracing.POOL_CLOSE) == 1
+            text = registry.render_prometheus()
+            assert "pio_pool_loaded_bytes_total 300" in text
+            assert "pio_pool_load_queue 0" in text
+        finally:
+            tracing._bound_stages.reset(token)
+            pool.close()
+
+    def test_fits_says_whether_a_victim_would_be_needed(self):
+        pool = ModelPool(250)
+        try:
+            assert pool.fits(250) and not pool.fits(251)
+            with pool.pin("a", _loader("a", 100)):
+                pass
+            assert pool.fits(150) and not pool.fits(151)
+            assert pool.fits(0)
+        finally:
+            pool.close()
+
+    def test_the_queue_counts_loads_the_loader_has_not_begun(self):
+        registry = MetricRegistry()
+        pool = ModelPool(1000, registry=registry)
+        begun, go = threading.Event(), threading.Event()
+
+        def slow():
+            begun.set()
+            go.wait(5.0)
+            return "model-a", 10, None
+
+        def queue_depth():
+            family = registry.to_dict()["pio_pool_load_queue"]
+            return family["samples"][0]["value"]
+
+        threads = [
+            threading.Thread(
+                target=lambda t=t, f=f: pool.pin(t, f).__enter__()
+            )
+            for t, f in (("a", slow), ("b", _loader("b")), ("c", _loader("c")))
+        ]
+        try:
+            threads[0].start()
+            assert begun.wait(5.0)
+            assert queue_depth() == 0  # the running load is not queued
+            for t in threads[1:]:
+                t.start()
+            deadline = time.monotonic() + 2.0
+            while time.monotonic() < deadline and queue_depth() != 2:
+                time.sleep(0.005)
+            assert queue_depth() == 2
+            go.set()
+            for t in threads:
+                t.join(5.0)
+            assert queue_depth() == 0
+        finally:
+            go.set()
+            pool.close()
+
+    def test_a_waiter_goes_on_before_the_victims_close(self):
+        """The entry is in and the waiters are let go before the loader
+        closes what it evicted: a slow close delays the next load, not
+        the request that caused it."""
+        closing, go = threading.Event(), threading.Event()
+
+        def victim():
+            def close():
+                closing.set()
+                go.wait(5.0)
+
+            return "model-a", 200, close
+
+        pool = ModelPool(250)
+        try:
+            with pool.pin("a", victim):
+                pass
+            with pool.pin("b", _loader("b", 200)) as value:
+                # pinned while the victim's close is still running
+                assert value == "model-b"
+                assert closing.wait(5.0) and not go.is_set()
+            go.set()
+        finally:
+            go.set()
+            pool.close()
+
+
+def test_an_overcommit_ends_with_the_first_pin_that_drains():
+    """A load that finds every other tenant pinned goes in over the
+    budget; the pool is back inside it when a pin drains, with no
+    further load, and what waits for its close is no overcommit."""
+    closed = []
+    pool = ModelPool(250)
+    try:
+        with pool.pin("a", _loader("a", 200, closed)):
+            with pool.pin("b", _loader("b", 200, closed)):
+                assert pool.resident() == ["a", "b"]  # both pinned
+                assert pool.stats()["residentBytes"] == 400
+            # b's pin drained: b is the one unpinned entry, and goes
+            assert pool.resident() == ["a"]
+        assert pool.resident() == ["a"]
+        deadline = time.monotonic() + 2.0
+        while time.monotonic() < deadline and closed != ["b"]:
+            time.sleep(0.005)
+        assert closed == ["b"]
+        assert pool.stats()["evictions"] == 1
+        assert pool.stats()["residentBytes"] == 200
+    finally:
+        pool.close()

@@ -389,3 +389,52 @@ def test_span_leak_rule_knows_stage():
     """
     assert "span-leak" in _rules(leaking)
     assert "span-leak" not in _rules(sound)
+
+
+def test_the_pool_s_stages_are_a_group_of_their_own(bound):
+    """Eight names beside the sixteen of a request's and a batch's
+    partition and the nested one: resolved by every sink, observed like
+    the others, and in no tuple the idle states of a trace are built on."""
+    registry, sink = bound
+    assert len(tracing.POOL_STAGES) == len(set(tracing.POOL_STAGES)) == 8
+    assert all(name.startswith("pool.") for name in tracing.POOL_STAGES)
+    assert not set(tracing.POOL_STAGES) & set(
+        tracing.STAGES + tracing.NESTED_STAGES
+    )
+    assert len(tracing.STAGES) == 16
+    for names in profiling.IDLE_STATES:
+        assert not set(names[1]) & set(tracing.POOL_STAGES)
+    assert not set(profiling._HANDLER_STAGES) & set(tracing.POOL_STAGES)
+    sink.bind(tenant="t007")
+    for name in tracing.POOL_STAGES:
+        with tracing.stage(name):
+            pass
+        assert _count(registry, name) == 1, name
+
+
+def test_a_sink_of_some_stages_leaves_the_others_to_the_process(bound):
+    """The model pool's loader binds a sink of `POOL_STAGES` alone: those
+    observe in its registry; a warm-up's `predict.*` stages, its launch
+    counts and a model's own counters stay the process's; a name that is
+    no stage is still an error."""
+    registry = MetricRegistry()  # `bound` only restores the context
+    tracing.StageSink(registry, tracing.POOL_STAGES).bind(tenant="t007")
+    process = tracing.get_registry()
+    before = _count(process, tracing.PREDICT_ENQUEUE)
+
+    def launches():
+        found = process.to_dict()["pio_device_launch_calls_total"]["samples"]
+        return sum(s["value"] for s in found)
+
+    launched = launches()
+    with tracing.stage(tracing.POOL_LOAD):
+        with tracing.stage(tracing.PREDICT_ENQUEUE):
+            tracing.launch_call()
+    assert _count(registry, tracing.POOL_LOAD) == 1
+    assert _count(process, tracing.PREDICT_ENQUEUE) == before + 1
+    stages = registry.to_dict()["pio_stage_seconds"]["samples"]
+    assert {s["labels"]["stage"] for s in stages} == set(tracing.POOL_STAGES)
+    assert launches() == launched + 1
+    assert tracing.bound_registry() is process
+    with pytest.raises(ValueError, match="unknown stage"):
+        tracing.stage("pool.loaded")
