@@ -37,7 +37,9 @@ the batcher's; device_get, materialize, settle on the completer's)
 into ``pio_stage_seconds{stage}`` and, through a profiler annotation
 of the same name, onto the clock of any ``jax.profiler`` trace, so a
 device trace shows what the host was doing in every gap
-(docs/observability.md "Stages").
+(docs/observability.md "Stages"). While that profiler runs, and only
+then, a stage also reads its thread's CPU clock: wall minus CPU is
+the time the stage spent off the CPU.
 
 Export: ``Tracer.chrome_trace()`` renders Chrome trace-event JSON that
 loads directly in Perfetto (https://ui.perfetto.dev) — served at
@@ -631,14 +633,53 @@ _bound_stages: contextvars.ContextVar[tuple[dict, dict] | None] = (
 
 class _StageChildren(dict):
     """The children of ``pio_stage_seconds`` by stage, the registry they
-    belong to (`bound_registry`) and its launch counter (`launch_call`)."""
+    belong to (`bound_registry`), its launch counter (`launch_call`), and
+    by stage the children of the two families a stage touches only while
+    a profiler runs (`clocks`: ``pio_stage_cpu_seconds``,
+    ``pio_stage_profiled_seconds_total``)."""
 
-    __slots__ = ("registry", "launch_calls")
+    __slots__ = ("registry", "launch_calls", "clocks")
+
+
+def _clock_families(registry: MetricRegistry) -> tuple:
+    """The two families a stage touches only while a profiler runs."""
+    cpu = registry.histogram(
+        "pio_stage_cpu_seconds",
+        "Thread CPU time of one stage, observed only while a profiler "
+        "runs (docs/observability.md \"Stages\")",
+        ("stage",),
+        buckets=STAGE_BUCKETS,
+    )
+    wall = registry.counter(
+        "pio_stage_profiled_seconds_total",
+        "Wall time of the stages pio_stage_cpu_seconds observed, the "
+        "same instances: wall minus CPU is their time off the CPU",
+        ("stage",),
+    )
+    return cpu, wall
+
+
+def stage_clock_totals(
+    registry: MetricRegistry | None = None,
+) -> dict[str, tuple[int, float, float]]:
+    """``{stage: (instances, CPU seconds, wall seconds)}`` that the
+    profiled stages of ``registry`` (``None``: the process's) have added
+    up so far; two readings a profile apart give that profile's."""
+    cpu, wall = _clock_families(
+        get_registry() if registry is None else registry
+    )
+    walls = {values[0]: child.value for values, child in wall.samples()}
+    return {
+        values[0]: (child.count, child.sum, walls.get(values[0], 0.0))
+        for values, child in cpu.samples()
+    }
 
 
 class StageSink:
     """``pio_stage_seconds{stage}`` of one registry (``None``: the
-    process's), every child resolved here. A server builds one at
+    process's), and beside it ``pio_stage_cpu_seconds{stage}`` and
+    ``pio_stage_profiled_seconds_total{stage}``, which count only while
+    a profiler runs; every child resolved here. A server builds one at
     wiring time and each of its threads binds it (with the keywords its
     annotations carry) before the stages it runs. A sink of ``names``
     alone (the model pool's loader: :data:`POOL_STAGES`) takes those
@@ -661,10 +702,14 @@ class StageSink:
             ("stage",),
             buckets=STAGE_BUCKETS,
         )
+        stages = names or STAGES + NESTED_STAGES + POOL_STAGES
         self._children = _StageChildren(
-            (name, family.labels(name))
-            for name in names or STAGES + NESTED_STAGES + POOL_STAGES
+            (name, family.labels(name)) for name in stages
         )
+        cpu, wall = _clock_families(registry)
+        self._children.clocks = {
+            name: (cpu.labels(name), wall.labels(name)) for name in stages
+        }
         if names is not None:
             registry = get_registry()
         self._children.registry = registry
@@ -705,8 +750,20 @@ def launch_call(calls: int = 1) -> None:
     _bound_children().launch_calls.inc(calls)
 
 
+def _clocks_of(name: str) -> tuple:
+    """``(pio_stage_cpu_seconds, pio_stage_profiled_seconds_total)``
+    children of ``name`` on the sink :func:`stage` took its
+    ``pio_stage_seconds`` child from: looked up only while a profiler
+    runs."""
+    clocks = _bound_children().clocks.get(name)
+    return clocks or _default_sink._children.clocks[name]
+
+
 class _Stage:
-    __slots__ = ("_name", "_child", "_keywords", "_annotation", "_t0")
+    __slots__ = (
+        "_name", "_child", "_keywords", "_annotation", "_t0", "_cpu0",
+        "_clocks",
+    )
 
     def __init__(self, name: str, child, keywords: dict):
         self._name = name
@@ -720,12 +777,21 @@ class _Stage:
                 self._name, **self._keywords
             )
             self._annotation.__enter__()
+            self._clocks = _clocks_of(self._name)
+            self._t0 = time.perf_counter()
+            self._cpu0 = time.thread_time()
+            return self
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t0 = self._t0
         seconds = time.perf_counter() - t0
+        # the thread clock is read only where __enter__ read it: while no
+        # profiler runs (and for a stage it started inside) never
+        annotation = self._annotation
+        if annotation is not None:
+            cpu = time.thread_time() - self._cpu0
         self._child.observe(seconds)
         parent = _current_span.get()
         if parent is not None and parent.tracer.enabled:
@@ -737,8 +803,11 @@ class _Stage:
                 self._name, t0, seconds,
                 f"{exc_type.__name__}: {exc}" if exc_type else None,
             )
-        if self._annotation is not None:
-            self._annotation.__exit__(exc_type, exc, tb)
+        if annotation is not None:
+            cpu_child, wall_child = self._clocks
+            cpu_child.observe(cpu)
+            wall_child.inc(seconds)
+            annotation.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -749,8 +818,11 @@ def stage(name: str) -> _Stage:
     of one item. Always observes ``pio_stage_seconds{stage}``; while a
     profiler runs (whoever started it) it is an annotation of the same
     name, so any profiler session sees the stage on the device trace's
-    clock; and under an open span of an enabled tracer it is also that
-    span's child in the finished trace."""
+    clock, and it reads the thread's CPU clock beside the wall into
+    ``pio_stage_cpu_seconds{stage}`` and
+    ``pio_stage_profiled_seconds_total{stage}``; and under an open span
+    of an enabled tracer it is also that span's child in the finished
+    trace."""
     children, keywords = _bound_stages.get() or (
         _default_sink._children, {}
     )

@@ -2026,6 +2026,7 @@ class EngineServer:
                 duration_ms / 1000.0,
                 tracer=self._tracer,
                 device_sample_fn=self._device_sampler.sample_once,
+                registry=self._registry,
             )
             bundle = profiling.bundle(manifest["artifactDir"])
         finally:

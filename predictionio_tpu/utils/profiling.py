@@ -157,6 +157,7 @@ def capture(
     tracer: "tracing.Tracer | None" = None,
     device_sample_fn=None,
     out_dir: str | None = None,
+    registry=None,
 ) -> dict:
     """On-demand profile capture (the ``POST /debug/profile`` body of
     docs/observability.md): run a duration-bounded :func:`trace`
@@ -167,7 +168,10 @@ def capture(
 
     * ``jax_trace/`` — the jax.profiler output (TensorBoard/Perfetto)
     * ``summary.json`` — :func:`summarize` of that trace: what the
-      device did, and what the host was doing while it idled
+      device did, and what the host was doing while it idled; and
+      ``stage_clocks``, the window's stages of ``registry`` (the
+      server's; ``None``: the process's) by their wall and thread CPU
+      (:func:`stage_clocks`)
     * ``spans.json`` — the tracing flight recorder's chrome trace (its
       own clock; the stages in ``jax_trace/`` are on the profiler's)
     * ``device.json`` — HBM/live-array sample (when a sampler is given)
@@ -184,15 +188,17 @@ def capture(
     artifact_dir = os.path.join(base, f"profile-{art_id}")
     trace_dir = os.path.join(artifact_dir, "jax_trace")
     os.makedirs(trace_dir, exist_ok=True)
+    clocks = tracing.stage_clock_totals(registry)
     t0 = time.perf_counter()
     with trace(trace_dir):
         time.sleep(max(0.0, duration_s))
     elapsed = time.perf_counter() - t0
+    clocks = stage_clocks(clocks, tracing.stage_clock_totals(registry))
     tracer = tracer if tracer is not None else tracing.get_tracer()
     with open(os.path.join(artifact_dir, "spans.json"), "w") as f:
         json.dump(tracer.chrome_trace(), f, default=str)
     with open(os.path.join(artifact_dir, "summary.json"), "w") as f:
-        json.dump(summarize(trace_dir), f, indent=2)
+        json.dump({**summarize(trace_dir), "stage_clocks": clocks}, f, indent=2)
     files = ["jax_trace/", "manifest.json", "spans.json", "summary.json"]
     if device_sample_fn is not None:
         try:
@@ -218,6 +224,28 @@ def capture(
         art_id, elapsed, artifact_dir,
     )
     return manifest
+
+
+def stage_clocks(before: dict, after: dict) -> dict:
+    """Per stage, what its profiled instances added between two
+    readings of :func:`~predictionio_tpu.obs.tracing.stage_clock_totals`:
+    their count, and their mean wall, thread CPU and wall minus CPU (the
+    time off the CPU: the interpreter lock, a blocking call, the
+    runtime), in ms. A stage with no such instance is left out."""
+    out = {}
+    for stage, (count, cpu_s, wall_s) in after.items():
+        count0, cpu0, wall0 = before.get(stage, (0, 0.0, 0.0))
+        n = count - count0
+        if n <= 0:
+            continue
+        wall, cpu = (wall_s - wall0) / n, (cpu_s - cpu0) / n
+        out[stage] = {
+            "count": n,
+            "wall_ms": round(1e3 * wall, 6),
+            "cpu_ms": round(1e3 * cpu, 6),
+            "offcpu_ms": round(1e3 * (wall - cpu), 6),
+        }
+    return out
 
 
 def bundle(artifact_dir: str) -> bytes:

@@ -881,6 +881,35 @@ class TestProfileEndpoint:
         assert any(n.endswith("spans.json") for n in names)
         assert any("jax_trace" in n for n in names)
 
+    def test_profile_reads_the_stage_clocks_of_the_server_s_registry(
+        self, server, monkeypatch
+    ):
+        from predictionio_tpu.utils import profiling
+
+        base, srv = server
+        given = []
+        capture = profiling.capture
+
+        def recording(*args, **kwargs):
+            given.append(kwargs.get("registry"))
+            return capture(*args, **kwargs)
+
+        monkeypatch.setattr(profiling, "capture", recording)
+        status, body = _call(
+            f"{base}/debug/profile", method="POST", body={"durationMs": 60},
+            timeout=60,
+        )
+        assert status == 200, body
+        assert given == [srv._registry]
+        raw = base64.b64decode(json.loads(body)["bundle"])
+        with tarfile.open(fileobj=io.BytesIO(raw), mode="r:gz") as tar:
+            member = next(
+                m for m in tar.getmembers() if m.name.endswith("summary.json")
+            )
+            summary = json.load(tar.extractfile(member))
+        # no profiler ran in the fake window: no stage instance was profiled
+        assert summary["stage_clocks"] == {}
+
     def test_profile_rejects_bad_duration(self, server):
         base, _srv = server
         status, body = _call(

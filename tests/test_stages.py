@@ -438,3 +438,217 @@ def test_a_sink_of_some_stages_leaves_the_others_to_the_process(bound):
     assert tracing.bound_registry() is process
     with pytest.raises(ValueError, match="unknown stage"):
         tracing.stage("pool.loaded")
+
+
+# -- the thread clock, read only while a profiler runs ----------------------
+
+ALL_STAGES = tracing.STAGES + tracing.NESTED_STAGES + tracing.POOL_STAGES
+
+
+def _clocks(registry):
+    """``{stage: (cpu count, cpu sum, profiled wall)}`` of the registry's
+    two families, every stage a sink resolved included."""
+    found = registry.to_dict()
+    walls = {
+        s["labels"]["stage"]: s["value"]
+        for s in found["pio_stage_profiled_seconds_total"]["samples"]
+    }
+    return {
+        s["labels"]["stage"]: (s["count"], s["sum"], walls[s["labels"]["stage"]])
+        for s in found["pio_stage_cpu_seconds"]["samples"]
+    }
+
+
+@pytest.fixture()
+def thread_clock_calls(monkeypatch):
+    """A counting stand-in for ``time.thread_time``: the list of its calls
+    on the test's own thread (a server another test left running is not
+    this test's to count)."""
+    import threading
+    import time
+
+    calls, clock, me = [], time.thread_time, threading.get_ident()
+
+    def counting():
+        if threading.get_ident() == me:
+            calls.append(1)
+        return clock()
+
+    monkeypatch.setattr(time, "thread_time", counting)
+    return calls
+
+
+@pytest.mark.parametrize("installed", ["jax", "recorder"])
+def test_no_profiler_no_thread_clock_and_nothing_counted(
+    bound, monkeypatch, thread_clock_calls, installed
+):
+    """With no profiler running a stage reads no thread clock and the two
+    families stay at 0, whatever the factory: the one jax installs or
+    none at all running."""
+    registry, sink = bound
+    if installed == "recorder":
+        _profiling(monkeypatch, _Recorder(), active=False)
+    else:
+        assert tracing._annotation_active is not tracing._no_profiler
+        assert tracing._annotation_active() is False
+    sink.bind(request_id="r-3")
+    for name in ALL_STAGES:
+        with tracing.stage(name):
+            pass
+    assert thread_clock_calls == []
+    assert all(_count(registry, name) == 1 for name in ALL_STAGES)
+    assert _clocks(registry) == {name: (0, 0.0, 0.0) for name in ALL_STAGES}
+
+
+@pytest.mark.parametrize("name", ALL_STAGES)
+def test_every_stage_reads_its_clock_while_a_profiler_runs(
+    bound, monkeypatch, thread_clock_calls, name
+):
+    """The two families sit in the registry of ``pio_stage_seconds`` and
+    count every stage of the three tuples while a profiler runs: two
+    thread-clock reads, one CPU observation, the wall of the same instance."""
+    registry, sink = bound
+    _profiling(monkeypatch, _Recorder())
+    sink.bind(tenant="t001")
+    with tracing.stage(name):
+        pass
+    assert len(thread_clock_calls) == 2
+    clocks = _clocks(registry)
+    count, cpu, wall = clocks.pop(name)
+    assert count == 1 and 0 <= cpu and 0 < wall
+    assert set(clocks.values()) == {(0, 0.0, 0.0)}
+
+
+def _spin(seconds):
+    import time
+
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+def _sleep(seconds):
+    import time
+
+    time.sleep(seconds)
+
+
+@pytest.mark.parametrize("work,low,high", [
+    (_spin, 0.7, 1.05), (_sleep, 0.0, 0.3),
+], ids=["spins", "sleeps"])
+def test_cpu_against_wall_of_a_spinning_and_a_sleeping_stage(
+    bound, monkeypatch, work, low, high
+):
+    """About 20 ms either way: spinning is work, sleeping is off the CPU."""
+    registry, sink = bound
+    _profiling(monkeypatch, _Recorder())
+    sink.bind()
+    with tracing.stage(tracing.PREDICT_ENQUEUE):
+        work(0.02)
+    count, cpu, wall = _clocks(registry)[tracing.PREDICT_ENQUEUE]
+    assert count == 1 and wall >= 0.02
+    assert low * wall <= cpu <= high * wall
+
+
+@pytest.mark.parametrize("at_enter,at_exit,recorded", [
+    (False, True, 0), (True, False, 1),
+], ids=["starts-inside", "stops-inside"])
+def test_a_profiler_that_starts_inside_a_stage_records_nothing_for_it(
+    bound, monkeypatch, thread_clock_calls, at_enter, at_exit, recorded
+):
+    """The clock is read at exit only where it was read at enter: a
+    profiler that starts inside a stage leaves no half-read; one that
+    stops inside it leaves the whole instance."""
+    registry, sink = bound
+    recorder = _Recorder()
+    _profiling(monkeypatch, recorder, active=at_enter)
+    sink.bind()
+    with tracing.stage(tracing.HTTP_RESPOND):
+        _profiling(monkeypatch, recorder, active=at_exit)
+    assert len(thread_clock_calls) == 2 * recorded
+    assert _clocks(registry)[tracing.HTTP_RESPOND][0] == recorded
+    assert _count(registry, tracing.HTTP_RESPOND) == 1
+
+
+def test_a_nested_stage_reads_its_own_clock(bound, monkeypatch):
+    registry, sink = bound
+    _profiling(monkeypatch, _Recorder())
+    sink.bind()
+    with tracing.stage(tracing.PREDICT_PREP):
+        _spin(0.005)
+        with tracing.stage(tracing.PREDICT_RULES):
+            _spin(0.005)
+    clocks = _clocks(registry)
+    prep, rules = clocks[tracing.PREDICT_PREP], clocks[tracing.PREDICT_RULES]
+    assert prep[0] == rules[0] == 1
+    # nothing subtracted: the outer stage's clocks hold the inner one's
+    assert prep[1] >= rules[1] and prep[2] >= rules[2] >= 0.005
+
+
+def test_a_pool_sink_counts_the_pool_s_clocks_alone(bound, monkeypatch):
+    """As `pio_stage_seconds`: the loader's sink takes the `pool.*` stages'
+    clocks; a warm-up's `predict.*` inside a load counts in the process's."""
+    registry = MetricRegistry()
+    _profiling(monkeypatch, _Recorder())
+    tracing.StageSink(registry, tracing.POOL_STAGES).bind(tenant="t007")
+    process = tracing.get_registry()
+    before = _clocks(process)[tracing.PREDICT_ENQUEUE][0]
+    with tracing.stage(tracing.POOL_LOAD):
+        with tracing.stage(tracing.POOL_DESERIALIZE):
+            pass
+        with tracing.stage(tracing.PREDICT_ENQUEUE):
+            pass
+    clocks = _clocks(registry)
+    assert set(clocks) == set(tracing.POOL_STAGES)
+    assert clocks[tracing.POOL_LOAD][0] == clocks[tracing.POOL_DESERIALIZE][0] == 1
+    assert _clocks(process)[tracing.PREDICT_ENQUEUE][0] == before + 1
+
+
+def test_stage_clocks_of_a_window(bound, monkeypatch):
+    registry, sink = bound
+    sink.bind()
+    with tracing.stage(tracing.ENGINE_DECODE):  # before the window: no profiler
+        pass
+    before = tracing.stage_clock_totals(registry)
+    _profiling(monkeypatch, _Recorder())
+    for _ in range(2):
+        with tracing.stage(tracing.ENGINE_DECODE):
+            _sleep(0.01)
+    clocks = profiling.stage_clocks(before, tracing.stage_clock_totals(registry))
+    assert list(clocks) == [tracing.ENGINE_DECODE]
+    decode = clocks[tracing.ENGINE_DECODE]
+    assert decode["count"] == 2 and decode["wall_ms"] >= 10.0
+    assert decode["offcpu_ms"] == pytest.approx(
+        decode["wall_ms"] - decode["cpu_ms"], abs=1e-5
+    )
+    assert decode["cpu_ms"] < 0.3 * decode["wall_ms"]
+    assert profiling.stage_clocks(before, before) == {}
+
+
+def test_capture_summarizes_the_window_s_stage_clocks(bound, monkeypatch, tmp_path):
+    """`summary.json` of a capture holds the stages its window profiled, read
+    off the registry it is given, and none from before the window."""
+    import contextlib
+
+    registry, sink = bound
+    sink.bind()
+    with tracing.stage(tracing.BATCH_SETTLE):
+        pass
+
+    @contextlib.contextmanager
+    def fake_trace(trace_dir=None):
+        os.makedirs(trace_dir, exist_ok=True)
+        _profiling(monkeypatch, _Recorder())
+        with tracing.stage(tracing.PREDICT_ENQUEUE):
+            _spin(0.002)
+        yield
+        _profiling(monkeypatch, _Recorder(), active=False)
+
+    monkeypatch.setattr(profiling, "trace", fake_trace)
+    manifest = profiling.capture(0.0, out_dir=str(tmp_path), registry=registry)
+    with open(os.path.join(manifest["artifactDir"], "summary.json")) as f:
+        clocks = json.load(f)["stage_clocks"]
+    assert list(clocks) == [tracing.PREDICT_ENQUEUE]
+    enqueue = clocks[tracing.PREDICT_ENQUEUE]
+    assert enqueue["count"] == 1 and enqueue["wall_ms"] >= 2.0
+    assert set(enqueue) == {"count", "wall_ms", "cpu_ms", "offcpu_ms"}
