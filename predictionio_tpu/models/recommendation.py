@@ -279,10 +279,8 @@ class ALSAlgorithm(Algorithm[RecTrainingData, ALSRecModel, dict, dict]):
             # to powers of two so arbitrary client input cannot force
             # unbounded recompiles at serving time
             num_bucket = min(1 << max(0, (num - 1)).bit_length(), n_items)
-            user_idx = np.asarray(
-                [model.user_map.get(q.get("user", ""), -1) for q in queries],
-                np.int32,
-            )
+            # a restored map answers by binary search, building no dict
+            user_idx = model.user_map.rows([q.get("user", "") for q in queries])
             idx = np.clip(user_idx, 0, None)
             batch_bucket = 1 << max(0, (len(idx) - 1)).bit_length()
             if batch_bucket > len(idx):

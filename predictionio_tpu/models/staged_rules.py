@@ -61,6 +61,12 @@ def stage(model, item_f, sharding=None, popularity=None) -> StagedRules:
     ids, 1/norm, the phantom rows marked unavailable, and ``popularity``
     ([I] interaction counts) for a template with a POPULAR branch."""
     rows, n_items = item_f.shape[0], len(model.item_map)
+    # the launch looks ids up one at a time (`getter()`): a restored
+    # model's maps build their dictionaries here, where the model is
+    # staged, and not on its first query's thread
+    for bimap in (model.item_map, getattr(model, "user_map", None)):
+        if bimap is not None:
+            bimap.getter()
     ids, categories = encode_categories(model)
     put = lambda x: jax.device_put(x, sharding)  # noqa: E731
     if popularity is not None:

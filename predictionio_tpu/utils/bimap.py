@@ -22,32 +22,51 @@ import numpy as np
 class BiMap:
     """Immutable bijection ``str -> int`` with O(1) inverse lookup.
 
-    Pickles as its key array alone (:meth:`__reduce__`): the index is
-    rebuilt from the keys on load, and the sorted view that :meth:`encode`
-    searches is built on its first call, in a fresh map and a restored one
-    alike. A map pickled before that (its whole ``__dict__``) still loads
-    through :meth:`__setstate__`.
+    A map holds its keys, their sorted view (the order that sorts them,
+    and the keys in that order) and a dictionary ``str(key) -> index``.
+    A map built here has all three from birth: the view is what the
+    uniqueness check's sort gives. It pickles as its keys and the order
+    (:meth:`__reduce__`), and a restored map builds **no dictionary**
+    until a per-key caller (:meth:`get`, :meth:`getter`,
+    :meth:`__call__`, ``in``, :meth:`to_dict`) asks for one: a batch's
+    lookups (:meth:`rows`, :meth:`encode`) search the view. Each
+    dictionary built counts in ``pio_idmap_index_builds_total{origin}``.
+    Two older forms still load: the key array alone (its dictionary built
+    at restore) and the instance's whole ``__dict__``
+    (:meth:`__setstate__`); either builds its view on the first
+    :meth:`encode`.
     """
 
     def __init__(self, keys: Sequence[str] | np.ndarray):
         keys = np.asarray(keys)
-        if len(np.unique(keys)) != len(keys):
+        sorted_keys, order = np.unique(keys, return_index=True)
+        if len(sorted_keys) != len(keys):
             raise ValueError("BiMap keys must be unique")
-        self._adopt(keys)
-
-    def _adopt(self, keys: np.ndarray) -> None:
-        """Index ``keys`` (unique) in one pass; no sorted view yet."""
         self._keys = keys
-        if keys.dtype.kind == "U":
-            # tolist() gives the very strs str() would, at C speed
-            self._index = dict(zip(keys.tolist(), range(len(keys))))
-        else:
-            self._index = {str(k): i for i, k in enumerate(keys)}
-        # (argsort of the keys, the keys in that order): encode()'s alone
-        self._sorted_view: tuple[np.ndarray, np.ndarray] | None = None
+        # of unique keys, the first place of each sorted key is its place
+        self._sorted_view: tuple[np.ndarray, np.ndarray] | None = (
+            order.astype(np.int32), sorted_keys
+        )
+        self._index: dict[str, int] | None = _index_of(keys, "built")
+
+    def _dict(self) -> dict[str, int]:
+        index = self._index
+        if index is None:
+            # threads that race here build equal dicts; either is kept
+            index = self._index = _index_of(self._keys, "restored")
+        return index
+
+    def _view(self) -> tuple[np.ndarray, np.ndarray]:
+        """(int32 order that sorts the keys, the keys in that order)."""
+        view = self._sorted_view
+        if view is None:
+            # a map of an older form; threads that race build equal views
+            order = np.argsort(self._keys).astype(np.int32)
+            view = self._sorted_view = (order, self._keys[order])
+        return view
 
     def __reduce__(self):
-        return _from_keys, (self._keys,)
+        return _from_keys, (self._keys, self._view()[0])
 
     def __setstate__(self, state: dict) -> None:
         """The form pickled before maps travelled as their keys: the
@@ -76,16 +95,41 @@ class BiMap:
 
     # -- lookup -----------------------------------------------------------
     def __call__(self, key: str) -> int:
-        return self._index[str(key)]
+        return self._dict()[str(key)]
 
     def get(self, key: str, default: int | None = None) -> int | None:
-        return self._index.get(str(key), default)
+        return self._dict().get(str(key), default)
 
     def getter(self):
         """``get(key, default)`` of the underlying dict itself, for loops
         that look up many ``str`` keys (no ``str()`` of the key, no Python
         frame a call)."""
-        return self._index.get
+        return self._dict().get
+
+    def rows(self, ids: Iterable[object], missing: int = -1) -> np.ndarray:
+        """int32 index of ``str(id)`` for each of ``ids`` (as :meth:`get`
+        reads an id), ``missing`` where the map lacks it: a batch's
+        lookups. By the dictionary where the map has one, else by binary
+        search over the sorted view, so a restored map answers a batch
+        without building its dictionary. A map of non-``str`` keys is
+        sorted as its own type, not as the ``str`` of it: it always asks
+        for the dictionary."""
+        index = self._index
+        if index is None and self._keys.dtype.kind != "U":
+            index = self._dict()
+        if index is not None:
+            get = index.get
+            return np.asarray([get(str(k), missing) for k in ids], np.int32)
+        # searched at the keys' own width: a wider array would have numpy
+        # copy the whole view to its width. An id longer than that is no key
+        values = [str(k) for k in ids]
+        width = self._keys.dtype.itemsize // 4  # 4 B a character
+        longer = [i for i, v in enumerate(values) if len(v) > width]
+        for i in longer:
+            values[i] = ""
+        found = self.encode(np.asarray(values, self._keys.dtype), missing)
+        found[longer] = missing
+        return found
 
     def inverse(self, idx: int) -> str:
         return str(self._keys[idx])
@@ -95,12 +139,7 @@ class BiMap:
         arr = np.asarray(values)
         if len(self._keys) == 0:
             return np.full(arr.shape, missing, dtype=np.int32)
-        view = self._sorted_view
-        if view is None:
-            # threads that race here build equal views; either is kept
-            order = np.argsort(self._keys)
-            view = self._sorted_view = (order, self._keys[order])
-        order, sorted_keys = view
+        order, sorted_keys = self._view()
         pos = np.searchsorted(sorted_keys, arr)
         pos = np.clip(pos, 0, len(sorted_keys) - 1)
         ok = sorted_keys[pos] == arr
@@ -116,18 +155,44 @@ class BiMap:
         return len(self._keys)
 
     def __contains__(self, key: object) -> bool:
-        return str(key) in self._index
+        return str(key) in self._dict()
 
     def to_dict(self) -> dict[str, int]:
-        return dict(self._index)
+        return dict(self._dict())
 
 
-def _from_keys(keys: np.ndarray) -> BiMap:
+def _index_of(keys: np.ndarray, origin: str) -> dict[str, int]:
+    """``str(key) -> index`` over ``keys`` (unique) in one pass, counted
+    by ``origin``: ``built`` for a map made from its keys, ``restored``
+    for one that came out of a pickle."""
+    from predictionio_tpu.obs import tracing
+
+    tracing.bound_registry().counter(
+        "pio_idmap_index_builds_total",
+        "Id-map dictionaries built: origin=built by a map made from its "
+        "keys, origin=restored by an unpickled map a per-key lookup asked",
+        ("origin",),
+    ).labels(origin).inc()
+    if keys.dtype.kind == "U":
+        # tolist() gives the very strs str() would, at C speed
+        return dict(zip(keys.tolist(), range(len(keys))))
+    return {str(k): i for i, k in enumerate(keys)}
+
+
+def _from_keys(keys: np.ndarray, order: np.ndarray | None = None) -> BiMap:
     """A pickled map's restore (named in every published model blob, so
     never renamed): the keys were unique when the map was built, so the
-    check is not run again."""
+    check is not run again. With ``order`` (the keys' int32 argsort) the
+    sorted view is one gather and no dictionary is built; without it (the
+    form that shipped the keys alone) the dictionary is built here."""
     m = BiMap.__new__(BiMap)
-    m._adopt(keys)
+    m._keys = keys
+    if order is None:
+        m._sorted_view = None
+        m._index = _index_of(keys, "restored")
+    else:
+        m._sorted_view = (order, keys[order])
+        m._index = None
     return m
 
 

@@ -462,6 +462,50 @@ def test_a_pool_s_int8_tables_serve_the_same_rules(shop):
     assert numbers["short_answers"] == 0 and numbers["score_rms"] < 0.02
 
 
+def _index_builds(registry, origin):
+    found = registry.to_dict().get("pio_idmap_index_builds_total")
+    return sum(
+        s["value"] for s in (found or {"samples": []})["samples"]
+        if s["labels"]["origin"] == origin
+    )
+
+
+def test_a_restored_model_has_its_dictionaries_before_the_first_query(ctx, shop):
+    """A model out of its blob builds no id-map dictionary at restore;
+    `stage_model` builds both, where a server stages the tenant, so no
+    query builds one and the answers are the fresh model's."""
+    import pickle
+
+    from predictionio_tpu.obs.registry import get_registry
+
+    algo, model, shop_ref, _ = shop
+    unstaged = ECommModel(
+        user_factors=shop_ref.users, item_factors=shop_ref.items,
+        user_map=BiMap([f"u{i}" for i in range(N_USERS)]),
+        item_map=BiMap([f"i{i}" for i in range(N_ITEMS)]),
+        item_categories={
+            f"i{i}": [f"c{c}"] for i, c in enumerate(shop_ref.category)
+        },
+        popularity=shop_ref.popularity.astype(np.float32),
+    )
+    restored = pickle.loads(pickle.dumps(unstaged, 5))
+    assert restored.user_map._index is None and restored.item_map._index is None
+    staged = algo.stage_model(ctx, restored)
+    assert staged.user_map._index is not None
+    assert staged.item_map._index is not None
+    registry = MetricRegistry()
+    process0 = _index_builds(get_registry(), "restored")
+    tracing.StageSink(registry).bind()
+    try:
+        queries = _mixed_queries()
+        answers = algo.batch_predict(staged, queries)
+    finally:
+        tracing._bound_stages.set(None)
+    assert answers == algo.batch_predict(model, queries)
+    assert _index_builds(registry, "restored") == 0
+    assert _index_builds(get_registry(), "restored") == process0
+
+
 def test_a_write_is_in_the_next_answer(shop):
     algo, model, shop_ref, (storage, app_id) = shop
     query = {"user": "u9", "num": 5}

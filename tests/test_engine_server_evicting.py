@@ -283,10 +283,97 @@ def test_an_evicting_server_answers_as_the_reference_before_and_after_reloads(
         assert _total(registry, "pio_pool_tenants_resident") == 4
         # tenant 2's two maps, at the preload and at each of two reloads
         assert len(parent_maps) == 6 * len(parent_form_of)
+        # no restored map built its dictionary: the launch searched the
+        # shipped order (a map of the parent's form carried its dict)
+        assert _builds(registry, "restored") == 0
     finally:
         http.shutdown()
         server.close()
         pool.close()
+
+
+def _builds(registry, origin):
+    found = registry.to_dict().get("pio_idmap_index_builds_total")
+    return sum(
+        s["value"] for s in (found or {"samples": []})["samples"]
+        if s["labels"]["origin"] == origin
+    )
+
+
+def _post_ids(base, tenant, users):
+    body = json.dumps([{"user": u, "num": NUM} for u in users]).encode()
+    req = urllib.request.Request(
+        f"{base}/batch/queries.json?accessKey={_name(tenant)}", data=body,
+        method="POST", headers={"Content-Type": "application/json"},
+    )
+    with urllib.request.urlopen(req, timeout=20) as resp:
+        return resp.status, json.loads(resp.read())
+
+
+def test_a_reloaded_tenant_answers_as_before_its_eviction(ctx, memory_storage):
+    """Tenant 0 is preloaded, answers a post, is evicted and loaded again
+    from its blob, and answers the same post exactly as before. No map of
+    any tenant builds its dictionary, in the server's registry (the
+    handlers and batchers) or the process's (the loader)."""
+    from predictionio_tpu.obs.registry import get_registry
+
+    _publish(memory_storage, 3)
+    process0 = _builds(get_registry(), "restored")
+    server, pool, registry = _server(ctx, memory_storage, 3, 2)
+    http = server.serve(host="127.0.0.1", port=0)
+    http.start()
+    base = f"http://127.0.0.1:{http.port}"
+    users = ["u3", "u299", "u0", "nobody", 7, "", "u17"]
+    try:
+        status, before = _post_ids(base, 0, users)
+        assert status == 200
+        assert [len(s["prediction"]["itemScores"]) for s in before] == [
+            NUM, NUM, NUM, 0, 0, 0, NUM
+        ]
+        for tenant in (1, 2):  # 2 misses and takes 0's place
+            assert _post_ids(base, tenant, users)[0] == 200
+        assert _name(0) not in pool.resident()
+        status, after = _post_ids(base, 0, users)
+        assert status == 200 and after == before
+        assert _total(registry, "pio_pool_misses_total") == 2 + 2
+        assert _total(registry, "pio_pool_evictions_total") == 2
+        assert _builds(registry, "restored") == 0
+        assert _builds(get_registry(), "restored") == process0
+    finally:
+        http.shutdown()
+        server.close()
+        pool.close()
+
+
+def test_a_restored_model_s_launch_finds_a_fresh_model_s_users():
+    """`batch_predict_launch`'s user rows out of a restored model (by its
+    maps' sorted view, no dictionary built) equal a fresh model's (by its
+    dictionary): known ids, unknown ids, and int-valued users."""
+    algorithm = ALSAlgorithm(ALSParams(rank=RANK))
+    fresh = _model(0)
+    keys = [f"u{i}" for i in range(N_USERS - 3)] + ["12", "7", "007"]
+    fresh = ALSRecModel(
+        user_factors=fresh.user_factors, item_factors=fresh.item_factors,
+        user_map=BiMap(keys), item_map=fresh.item_map,
+    )
+    [(_, restored)] = deserialize_models(
+        serialize_models("i", [algorithm], [fresh])
+    )
+    assert restored.user_map._index is None
+    queries = [
+        {"user": "u3"}, {"user": "u296"}, {"user": 12}, {"user": "12"},
+        {"user": 7}, {"user": "007"}, {"user": 1}, {"user": "nobody"},
+        {"user": ""}, {}, {"user": 7.0},
+    ]
+    want = [3, 296, 297, 297, 298, 299, -1, -1, -1, -1, -1]
+    for model in (fresh, restored):
+        handle = algorithm.batch_predict_launch(model, queries)
+        assert handle[2].dtype == np.int32
+        assert handle[2].tolist() == want
+        assert algorithm.batch_predict_collect(model, handle, queries) == (
+            algorithm.batch_predict(fresh, queries)
+        )
+    assert restored.user_map._index is None
 
 
 def test_a_tenant_s_blob_is_its_tables_and_its_key_arrays():

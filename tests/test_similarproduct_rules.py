@@ -114,6 +114,42 @@ def _bound(registry, fn, *args):
         tracing._bound_stages.set(None)
 
 
+def test_a_restored_model_has_its_dictionary_before_the_first_query(ctx, shop):
+    """A model out of its blob builds no id-map dictionary at restore;
+    `stage_model` builds it, where a server stages the tenant, so no query
+    builds one and the lists are the fresh model's."""
+    import pickle
+
+    from predictionio_tpu.obs.registry import get_registry
+
+    def restored_builds(registry):
+        found = registry.to_dict().get("pio_idmap_index_builds_total")
+        return sum(
+            s["value"] for s in (found or {"samples": []})["samples"]
+            if s["labels"]["origin"] == "restored"
+        )
+
+    algos, models, shop_ref = shop
+    unstaged = SimilarModel(
+        item_factors=shop_ref.tables[0],
+        item_map=BiMap([f"i{i}" for i in range(N_ITEMS)]),
+        item_categories={
+            f"i{i}": [f"c{c}"] for i, c in enumerate(shop_ref.category)
+        },
+    )
+    restored = pickle.loads(pickle.dumps(unstaged, 5))
+    assert restored.item_map._index is None
+    staged = algos[0].stage_model(ctx, restored)
+    assert staged.item_map._index is not None
+    registry = MetricRegistry()
+    process0 = restored_builds(get_registry())
+    queries = _mixed_queries()
+    served = _bound(registry, algos[0].batch_predict, staged, queries)
+    assert served == algos[0].batch_predict(models[0], queries)
+    assert restored_builds(registry) == 0
+    assert restored_builds(get_registry()) == process0
+
+
 def _assert_lists_equal_reference(shop_ref, queries, served, a):
     """Algorithm ``a``'s served lists against the reference's L_a."""
     found = reference_simprod.reference_lists(shop_ref, queries, 10)
