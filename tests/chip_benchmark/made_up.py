@@ -208,3 +208,14 @@ def over(both: dict, *kinds: str) -> list:
         pytest.param(which, e, id=f"{which}-{e['name']}")
         for which, bench in both.items() for kind in kinds for e in bench[kind]
     ]
+
+
+def over_cells(both: dict) -> list:
+    """Parameters ``(manifest, entry, cell)`` over each per-layer entry and
+    each cell it is read in, in the repository's manifest and in the grown
+    one."""
+    return [
+        pytest.param(which, e, cell, id=f"{which}-{e['name']}-{cell}")
+        for which, bench in both.items() for e in bench["per_layer"]
+        for cell in e["workloads"]
+    ]

@@ -14,7 +14,11 @@ what follows is free. A PR's own test asserts on what that PR owns
 (`check_owned_block`) and nothing about what follows it. The two KDD Cup
 configurations stay pinned by name (`check_accepted_config`); every
 configuration, those included, meets the general rule
-(`check_config_entry`).
+(`check_config_entry`). `per_layer` holds at most the contract's 128
+entries (`check_per_layer_cap`). A cell brings its own prefixed copies of the
+quantities every cell has; a `benchmark` PR folds them into the shared
+entry of the same reader (`FOLDED`, `check_folded`: the cell appended to
+that entry's `workloads`) and writes `data/accepted.json` anew.
 """
 
 from __future__ import annotations
@@ -48,6 +52,36 @@ KDDCUP_CONFIGS = ("rec-pool-kddcup11", "rec-pool-kddcup11-int8")
 KDDCUP_CELLS = ("serve-pool-batch", "serve-pool-single", "serve-pool-int8-batch")
 #: the lists of the manifest that a PR appends to
 LISTS = ("configs", "workloads", "per_layer")
+#: the most entries the benchmark's contract allows in `per_layer`
+PER_LAYER_CAP = 128
+#: per cell, its prefixed entries that were folded into the shared entry
+#: read by the same reader file (`shared_name`)
+FOLDED = {
+    "serve-ecomm-batch": [
+        "ecomm." + base for base in (
+            "http_request_ms", "queue_wait_ms", "batch_occupancy", "device_dispatch_ms",
+            "topk_device_ms", "device_idle_share", "launch_idle_share", "hbm_peak_gib",
+            "host_cpu_share", "compiles_in_window", "serve_mfu", "launch_calls_share",
+            "setup_compile_s",
+        )
+    ],
+    "serve-simprod-batch": [
+        "simprod." + base for base in (
+            "http_request_ms", "queue_wait_ms", "batch_occupancy", "device_dispatch_ms",
+            "topk_device_ms", "device_idle_share", "launch_idle_share",
+            "launch_calls_share", "hbm_peak_gib", "host_cpu_share", "compiles_in_window",
+            "serve_mfu", "serve_ms", "setup_compile_s",
+        )
+    ],
+    "serve-pool-churn-batch": [
+        "churn." + base for base in (
+            "http_request_ms", "submit_ms", "await_ms", "queue_wait_ms", "batch_occupancy",
+            "device_dispatch_ms", "device_idle_share", "host_cpu_share",
+            "compiles_in_window", "hbm_peak_gib", "launch_calls_share", "topk_device_ms",
+            "topk_roofline", "serve_mfu",
+        )
+    ],
+}
 
 
 def load_bench(root: str) -> dict:
@@ -79,6 +113,25 @@ def config_body(bench: dict, root: str, name: str) -> dict:
 def traffic_body(bench: dict, root: str, name: str) -> dict:
     with open(os.path.join(chip_dir(bench, root), "traffic", name + ".json")) as f:
         return json.load(f)
+
+
+def reader_file(bench: dict, root: str, name: str) -> str | None:
+    """The file `layer_metrics.read` takes for per-layer metric ``name``:
+    ``metrics/<name>``, else ``metrics/<base>`` (the part after the first
+    dot), ``.py`` before ``.json``; None where there is neither."""
+    for stem in (name, name.split(".", 1)[-1]):
+        for ext in (".py", ".json"):
+            path = os.path.join(chip_dir(bench, root), "metrics", stem + ext)
+            if os.path.exists(path):
+                return path
+    return None
+
+
+def shared_name(folded: str) -> str:
+    """The entry a folded name's cell reads it under: `setup_compile_s`
+    itself, else `batch.<base>`."""
+    base = folded.split(".", 1)[1]
+    return base if base == "setup_compile_s" else "batch." + base
 
 
 def cells_of(bench: dict, config: str) -> list[dict]:
@@ -209,13 +262,49 @@ def check_per_layer_metric(bench: dict, root: str, metric: dict) -> None:
     for cell in metric["workloads"]:
         assert cell in cells
         assert cell in target.get("workloads", cells)
-    base = metric["name"].split(".", 1)[-1]
-    assert any(
-        os.path.exists(os.path.join(chip_dir(bench, root), "metrics", stem + ext))
-        for stem in (metric["name"], base) for ext in (".json", ".py")
-    ), f"no reader for {metric['name']!r} under metrics/"
+    assert reader_file(bench, root, metric["name"]), (
+        f"no reader for {metric['name']!r} under metrics/"
+    )
     if metric["name"].endswith("_roofline") or "mfu" in metric["name"]:
         assert metric["unit"] == "%"
+
+
+def check_per_layer_cap(bench: dict, root: str) -> None:
+    """`per_layer` holds no more entries than the contract allows."""
+    count = len(bench["per_layer"])
+    assert count <= PER_LAYER_CAP, (
+        f"per_layer holds {count} entries, over the cap of {PER_LAYER_CAP}"
+    )
+
+
+def check_read_once(bench: dict, root: str, metric: dict, cell: str) -> None:
+    """``cell`` is one of the entry's cells: it reports the end-to-end
+    metric the entry moves, and no other entry it reports is read by the
+    entry's reader file, so no quantity stands twice on its line (a fold
+    that appends the cell to a shared entry removes the cell's own copy)."""
+    assert cell in [w["name"] for w in bench["workloads"]], f"no cell {cell!r} in workloads"
+    assert metric["moves"] in reports(bench, "end_to_end", cell), (
+        f"{cell!r} does not report {metric['moves']!r}, which {metric['name']!r} moves"
+    )
+    path = reader_file(bench, root, metric["name"])
+    twins = [
+        m["name"] for m in bench["per_layer"]
+        if m["name"] != metric["name"] and cell in m.get("workloads", [cell])
+        and reader_file(bench, root, m["name"]) == path
+    ]
+    assert not twins, f"{cell!r} reads {metric['name']!r} again as {twins}"
+
+
+def check_folded(bench: dict, root: str, cell: str) -> None:
+    """The cell's folded entries are gone from `per_layer`, and the shared
+    entry of each lists the cell."""
+    listed = {m["name"]: m for m in bench["per_layer"]}
+    for folded in FOLDED[cell]:
+        assert folded not in listed, f"{folded!r} stands beside its shared entry"
+        shared = listed.get(shared_name(folded))
+        assert shared and cell in shared["workloads"], (
+            f"{shared_name(folded)!r} does not list {cell!r}"
+        )
 
 
 def check_accepted_prefix(bench: dict, root: str, accepted: dict) -> None:
@@ -241,7 +330,8 @@ def check_owned_block(
     its per-layer entries ``names`` stand in `per_layer` together and in
     their order, behind the entries ``after`` that were accepted before
     them; each is read in ``cell`` alone; the cell stands in `workloads`
-    behind the cells those earlier entries are read in, and meets
+    behind the first cell of each of those earlier entries, the one it was
+    accepted for (a fold appends later cells to a shared entry), and meets
     `check_cell`. Returns the block's entries, for what else its PR pins
     (their `moves`, the cell's metrics, its reference). A PR's own test
     calls this for its own block and asserts nothing on the lists' ends."""
@@ -262,8 +352,7 @@ def check_owned_block(
     cells = [w["name"] for w in bench["workloads"]]
     assert cell in cells, f"no cell {cell!r} in workloads"
     earlier = {
-        c for m in bench["per_layer"][:at] if m["name"] in after
-        for c in m["workloads"]
+        m["workloads"][0] for m in bench["per_layer"][:at] if m["name"] in after
     }
     assert all(cells.index(c) < cells.index(cell) for c in earlier), (
         f"{cell!r} comes after the cells accepted before it"

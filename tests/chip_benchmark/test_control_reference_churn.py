@@ -30,20 +30,16 @@ import run as chip_run  # noqa: E402
 BENCH = rules.load_bench(ROOT)
 CONFIG = "rec-pool-ml20m-churn"
 CELL = "serve-pool-churn-batch"
-SHARED = (
-    "http_request_ms", "submit_ms", "await_ms", "queue_wait_ms", "batch_occupancy",
-    "device_dispatch_ms", "device_idle_share", "host_cpu_share",
-    "compiles_in_window", "hbm_peak_gib", "launch_calls_share", "topk_device_ms",
-    "topk_roofline", "serve_mfu",
-)
 POOL = (
     "miss_share", "cold_load_ms", "load_read_ms", "load_deserialize_ms",
     "load_promote_ms", "load_warmup_ms", "evict_close_ms", "cold_wait_ms",
     "loader_busy_share", "evictions_per_s", "resident_tenants", "over_ledger_gib",
     "setup_preload_s",
 )
-#: this PR's per-layer entries, by name and in their order
-PER_LAYER = ["churn." + base for base in SHARED + POOL]
+#: the per-layer entries the cell reads alone, by name and in their order:
+#: the model pool's; its fourteen copies of what every cell has were folded
+#: into the `batch.` entries (`rules.FOLDED`)
+PER_LAYER = ["churn." + base for base in POOL]
 
 
 def _tables(seed, n_users=200, n_items=2000, rank=32):
@@ -174,7 +170,10 @@ def _stages(rows):
     ]}
 
 
-def test_churn_readers_on_hand_made_runs():
+def hand_made_run():
+    """A traced run of the cell as the runner gathers one, on the CPU: 50
+    misses in 1,000 lookups over the window, their loads and 45 evictions
+    beside them, no device plane."""
     before = {
         "pio_pool_hits_total": _counter([({"tenant": "t000"}, 100.0), ({"tenant": "t300"}, 2.0)]),
         "pio_pool_misses_total": _counter([({"tenant": "t000"}, 1.0), ({"tenant": "t300"}, 1.0)]),
@@ -201,11 +200,16 @@ def test_churn_readers_on_hand_made_runs():
             "pool.wait": (456, 60.0),
         }),
     }
-    run = {
+    return {
         "before": before, "after": after, "built": before, "trace": {}, "load": {},
         "config": {}, "traffic": {"route": "/batch/queries.json"},
         "memory_peak_bytes": int(8.25 * 2**30), "peak": None,
     }
+
+
+def test_churn_readers_on_hand_made_runs():
+    run = hand_made_run()
+    before, after = run["before"], run["after"]
     read = lambda base: layer_metrics.read("churn." + base, run)  # noqa: E731
     assert read("miss_share") == pytest.approx(100 * 50 / 1000)
     assert read("cold_load_ms") == pytest.approx(1e3 * 6.0 / 50)
@@ -242,15 +246,13 @@ def test_churn_readers_on_hand_made_runs():
 
 def _check_the_block(bench, root):
     """What this PR owns of the manifest, and nothing about what follows it."""
-    # what was accepted before this block: the last benchmark PR's names,
-    # and PR 33's block behind them
-    before = rules.load_accepted(root)["per_layer"] + [
-        m["name"] for m in bench["per_layer"] if m["name"].startswith("simprod.")
-    ]
+    # what was accepted before this block: the KDD Cup, e-commerce and
+    # similar-product cells' names, less those folded
+    before = rules.load_accepted(root)["per_layer"][:65]
     block = rules.check_owned_block(bench, root, CELL, PER_LAYER, before)
-    assert len(block) == 27
-    assert [m["moves"] for m in block] == ["queries_per_s"] * 26 + ["setup_s"]
-    assert {m["layer"] for m in block[14:]} == {"model pool"}
+    assert len(block) == 13
+    assert [m["moves"] for m in block] == ["queries_per_s"] * 12 + ["setup_s"]
+    assert {m["layer"] for m in block} == {"model pool"}
     assert rules.reports(bench, "end_to_end", CELL) == ["setup_s", "queries_per_s"]
     cell = rules.entry(bench, "workloads", CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
@@ -271,6 +273,7 @@ def _check_the_block(bench, root):
 
 def test_new_cell_and_its_entries_come_after_the_accepted(manifest):
     _check_the_block(*manifest)
+    rules.check_folded(*manifest, CELL)
 
 
 def _an_entry_moved_away(bench):
@@ -350,10 +353,12 @@ def test_sound_rehearsal_is_correct_and_churns(servers_built):
     assert pool["resident_at_end"] == 4 and pool["budget_bytes"] == 4608000
     assert result["sampled"]["queries"] == 128
     assert 3 * result["sampled"]["cold_queries"] >= result["sampled"]["queries"]
-    for name in PER_LAYER[:6] + PER_LAYER[7:9] + PER_LAYER[10:11] + PER_LAYER[14:25] + PER_LAYER[26:]:
+    shared = ("http_request_ms", "submit_ms", "await_ms", "queue_wait_ms", "batch_occupancy",
+              "device_dispatch_ms", "host_cpu_share", "compiles_in_window", "launch_calls_share")
+    for name in ["batch." + base for base in shared] + PER_LAYER[:11] + PER_LAYER[12:]:
         assert name in result["metrics"], name
     value = lambda name: result["metrics"]["churn." + name]["value"]  # noqa: E731
-    assert value("compiles_in_window") == 0
+    assert result["metrics"]["batch.compiles_in_window"]["value"] == 0
     assert 2 < value("miss_share") < 60
     assert value("resident_tenants") == 4
     assert 0 < value("loader_busy_share") < 100
@@ -361,8 +366,9 @@ def test_sound_rehearsal_is_correct_and_churns(servers_built):
     # every bucket was warmed by the first tenant: a later load warms none
     assert value("load_warmup_ms") < 0.2 * value("cold_load_ms")
     # no device on the CPU: a share of a peak, or the chip's memory, is left out
-    for name in ("topk_roofline", "serve_mfu", "hbm_peak_gib", "over_ledger_gib"):
-        assert "churn." + name not in result["metrics"], name
+    for name in ("batch.topk_roofline", "batch.serve_mfu", "batch.hbm_peak_gib",
+                 "churn.over_ledger_gib"):
+        assert name not in result["metrics"], name
     breakdown = result["breakdown"]
     # a cover is a ratio of sums over the window: a post or a load that is
     # open at one of its ends counts on one side only

@@ -27,13 +27,11 @@ import run as chip_run  # noqa: E402
 BENCH = rules.load_bench(ROOT)
 CONFIG = "ecomm-pool-taobao-ub"
 CELL = "serve-ecomm-batch"
-#: PR 28's per-layer entries, by name and in their order; a later PR's
-#: `ecomm.*` entry (PR 32's `ecomm.launch_calls_share`) is none of them
+#: the per-layer entries the cell reads alone, by name and in their order;
+#: its twelve copies of what every cell has, and `ecomm.launch_calls_share`,
+#: were folded into the shared entries (`rules.FOLDED`)
 PER_LAYER = [
     "ecomm." + base for base in (
-        "http_request_ms", "queue_wait_ms", "batch_occupancy", "device_dispatch_ms",
-        "topk_device_ms", "device_idle_share", "launch_idle_share", "hbm_peak_gib",
-        "host_cpu_share", "compiles_in_window", "serve_mfu", "setup_compile_s",
         "rules_lookup_ms", "excluded_per_query", "filtered_share", "cold_share",
         "masked_topk_roofline",
     )
@@ -202,7 +200,9 @@ def _counter(value, labels=None):
     return {"samples": [{"labels": labels or {}, "value": value}]}
 
 
-def test_ecomm_readers_on_hand_made_runs():
+def hand_made_run():
+    """A traced run of the cell as the runner gathers one: 100 batches of
+    64, the rules' counters, 0.5 s of the rules program in a 4 s window."""
     cfg = rules.config_body(BENCH, ROOT, CONFIG)
     before = {
         "pio_batch_occupancy": {"samples": [{"labels": {}, "count": 0, "sum": 0.0}]},
@@ -224,7 +224,7 @@ def test_ecomm_readers_on_hand_made_runs():
             {"labels": {"stage": "predict.rules"}, "count": 100, "sum": 0.05},
         ]},
     }
-    run = {
+    return {
         "before": before, "after": after, "config": cfg,
         "traffic": {"route": "/batch/queries.json"},
         "peak": roofline.peaks("TPU v5 lite"), "traced_queries": 6400.0,
@@ -233,19 +233,23 @@ def test_ecomm_readers_on_hand_made_runs():
             "module_runs": {"jit__rules_top_k": 100},
         },
     }
+
+
+def test_ecomm_readers_on_hand_made_runs():
+    run = hand_made_run()
     assert layer_metrics.read("ecomm.excluded_per_query", run) == pytest.approx(100.0)
     assert layer_metrics.read("ecomm.filtered_share", run) == pytest.approx(50.0)
     assert layer_metrics.read("ecomm.cold_share", run) == pytest.approx(5.0)
     assert layer_metrics.read("ecomm.rules_lookup_ms", run) == pytest.approx(0.5)
-    assert layer_metrics.read("ecomm.topk_device_ms", run) == pytest.approx(5.0)
+    assert layer_metrics.read("batch.topk_device_ms", run) == pytest.approx(5.0)
     least = roofline_ecomm.masked_topk_bytes(64, 4162024, 16, 10, 6400) / 819e9
     assert layer_metrics.read("ecomm.masked_topk_roofline", run) == pytest.approx(
         100 * least / 0.005
     )
     mfu = 100 * (6400 * 2 * 16 * 4162024 / 197e12) / 4.0
-    assert layer_metrics.read("ecomm.serve_mfu", run) == pytest.approx(mfu)
+    assert layer_metrics.read("batch.serve_mfu", run) == pytest.approx(mfu)
     # a program without the counters, or no trace: nothing, never a 0
-    bare = {**run, "after": before, "trace": {}}
+    bare = {**run, "after": run["before"], "trace": {}}
     for name in ("excluded_per_query", "filtered_share", "cold_share",
                  "rules_lookup_ms", "masked_topk_roofline"):
         assert layer_metrics.read("ecomm." + name, bare) is None, name
@@ -255,8 +259,8 @@ def _check_the_block(bench, root):
     """What PR 28 owns of the manifest, and nothing about what follows it."""
     accepted_before = rules.load_accepted(root)["per_layer"][:50]
     block = rules.check_owned_block(bench, root, CELL, PER_LAYER, accepted_before)
-    assert len(block) == 17
-    assert {m["moves"] for m in block} == {"queries_per_s", "setup_s"}
+    assert len(block) == 5
+    assert {m["moves"] for m in block} == {"queries_per_s"}
     assert rules.reports(bench, "end_to_end", CELL) == ["setup_s", "queries_per_s"]
     assert rules.entry(bench, "workloads", CELL)["config"] == CONFIG
     assert rules.reference_module(bench, root, CONFIG) == "reference_ecomm"
@@ -265,12 +269,11 @@ def _check_the_block(bench, root):
 def test_new_cell_and_its_entries_come_after_the_accepted(manifest):
     bench, root = manifest
     _check_the_block(bench, root)
-    listed = [m["name"] for m in bench["per_layer"]]
-    assert listed.index("ecomm.launch_calls_share") > listed.index(PER_LAYER[-1])
+    rules.check_folded(bench, root, CELL)
 
 
 def _an_entry_between(bench):
-    bench["per_layer"].insert(60, bench["per_layer"].pop())
+    bench["per_layer"].insert(52, bench["per_layer"].pop())
 
 
 def _the_block_s_order_changed(bench):
@@ -278,7 +281,7 @@ def _the_block_s_order_changed(bench):
 
 
 def _an_entry_read_in_another_cell_too(bench):
-    rules.entry(bench, "per_layer", "ecomm.queue_wait_ms")["workloads"].append("serve-pool-batch")
+    rules.entry(bench, "per_layer", "ecomm.rules_lookup_ms")["workloads"].append("serve-pool-batch")
 
 
 def _the_block_before_an_accepted_entry(bench):
@@ -296,7 +299,7 @@ def _the_cell_gone(bench):
 @pytest.mark.parametrize("fault,message", [
     (_an_entry_between, "stand together and in their order"),
     (_the_block_s_order_changed, "stand together and in their order"),
-    (_an_entry_read_in_another_cell_too, "'ecomm.queue_wait_ms' is read in 'serve-ecomm-batch' alone"),
+    (_an_entry_read_in_another_cell_too, "'ecomm.rules_lookup_ms' is read in 'serve-ecomm-batch' alone"),
     (_the_block_before_an_accepted_entry, "come after the ones accepted before them"),
     (_the_cell_before_an_accepted_one, "'serve-ecomm-batch' comes after the cells accepted before it"),
     (_the_cell_gone, "no cell 'serve-ecomm-batch' in workloads"),
@@ -328,16 +331,16 @@ def test_sound_rehearsal_is_correct_and_reports_the_rules(servers_built):
         assert result["compared"][name][0] == 0, name
     assert result["sampled"]["probes"] == 128 and result["sampled"]["queries"] == 96
     for name in ("ecomm.rules_lookup_ms", "ecomm.excluded_per_query",
-                 "ecomm.filtered_share", "ecomm.cold_share", "ecomm.queue_wait_ms",
-                 "ecomm.compiles_in_window", "ecomm.batch_occupancy",
-                 "ecomm.launch_calls_share"):
+                 "ecomm.filtered_share", "ecomm.cold_share", "batch.queue_wait_ms",
+                 "batch.compiles_in_window", "batch.batch_occupancy",
+                 "batch.launch_calls_share"):
         assert name in result["metrics"], name
-    assert result["metrics"]["ecomm.compiles_in_window"]["value"] == 0
-    assert 95 < result["metrics"]["ecomm.launch_calls_share"]["value"] < 105
+    assert result["metrics"]["batch.compiles_in_window"]["value"] == 0
+    assert 95 < result["metrics"]["batch.launch_calls_share"]["value"] < 105
     assert 40 < result["metrics"]["ecomm.filtered_share"]["value"] < 60
     # no device plane on the CPU: a share of a peak is left out, not 0
     assert "ecomm.masked_topk_roofline" not in result["metrics"]
-    assert "ecomm.serve_mfu" not in result["metrics"]
+    assert "batch.serve_mfu" not in result["metrics"]
     assert result["control"]["short_answers"] > 0
     assert result["phases"]["events"] > 3 * 200 * 30
     assert list(result)[-1] == "compared"

@@ -30,15 +30,13 @@ import run as chip_run  # noqa: E402
 BENCH = rules.load_bench(ROOT)
 CONFIG = "simprod-pool-taobao-ub"
 CELL = "serve-simprod-batch"
-#: this PR's per-layer entries, by name and in their order
+#: the per-layer entries the cell reads alone, by name and in their order;
+#: its fourteen copies of what every cell has were folded into the shared
+#: entries (`rules.FOLDED`)
 PER_LAYER = [
     "simprod." + base for base in (
-        "http_request_ms", "queue_wait_ms", "batch_occupancy", "device_dispatch_ms",
-        "topk_device_ms", "device_idle_share", "launch_idle_share",
-        "launch_calls_share", "hbm_peak_gib", "host_cpu_share", "compiles_in_window",
-        "serve_mfu", "serve_ms", "launches_per_post", "items_per_query",
-        "excluded_per_query", "filtered_share", "like_short_share",
-        "both_lists_share", "masked_topk_roofline", "setup_compile_s",
+        "launches_per_post", "items_per_query", "excluded_per_query", "filtered_share",
+        "like_short_share", "both_lists_share", "masked_topk_roofline",
     )
 ]
 PLAN = {
@@ -268,7 +266,10 @@ def _counter(samples):
     return {"samples": [{"labels": labels, "value": value} for labels, value in samples]}
 
 
-def test_simprod_readers_on_hand_made_runs():
+def hand_made_run():
+    """A traced run of the cell as the runner gathers one: 100 posts of 64,
+    each two batches (one a batcher), the two algorithms' counters, 1.2 s
+    of the rules program in a 4 s window, busy 3 s."""
     cfg = rules.config_body(BENCH, ROOT, CONFIG)
     before = {
         "pio_batch_occupancy": {"samples": [{"labels": {}, "count": 0, "sum": 0.0}]},
@@ -305,7 +306,7 @@ def test_simprod_readers_on_hand_made_runs():
             ({"lists": "1"}, 60000.0), ({"lists": "2"}, 3000.0),
         ]),
     }
-    run = {
+    return {
         "before": before, "after": after, "config": cfg,
         "traffic": {"route": "/batch/queries.json"},
         "peak": roofline.peaks("TPU v5 lite"), "traced_queries": 12800.0,
@@ -314,14 +315,18 @@ def test_simprod_readers_on_hand_made_runs():
             "module_runs": {"jit__rules_top_k": 200},
         },
     }
+
+
+def test_simprod_readers_on_hand_made_runs():
+    run = hand_made_run()
     assert layer_metrics.read("simprod.launches_per_post", run) == pytest.approx(2.0)
     assert layer_metrics.read("simprod.items_per_query", run) == pytest.approx(4.5)
     assert layer_metrics.read("simprod.excluded_per_query", run) == pytest.approx(12.0)
     assert layer_metrics.read("simprod.filtered_share", run) == pytest.approx(50.0)
     assert layer_metrics.read("simprod.like_short_share", run) == pytest.approx(25.0)
     assert layer_metrics.read("simprod.both_lists_share", run) == pytest.approx(100 / 21)
-    assert layer_metrics.read("simprod.topk_device_ms", run) == pytest.approx(6.0)
-    assert layer_metrics.read("simprod.device_idle_share", run) == pytest.approx(25.0)
+    assert layer_metrics.read("batch.topk_device_ms", run) == pytest.approx(6.0)
+    assert layer_metrics.read("batch.device_idle_share", run) == pytest.approx(25.0)
     least = roofline_ecomm.masked_topk_bytes(64, 4162024, 16, 10, 768) / 819e9
     assert layer_metrics.read("simprod.masked_topk_roofline", run) == pytest.approx(
         100 * least / 0.006
@@ -329,19 +334,21 @@ def test_simprod_readers_on_hand_made_runs():
     # 2 x 2 x 16 x I operations a query answered: each query is a row of
     # two device batches, which is what the window's occupancy sums
     mfu = 100 * (6400 * 2 * 2 * 16 * 4162024 / 197e12) / 4.0
-    assert layer_metrics.read("simprod.serve_mfu", run) == pytest.approx(mfu)
+    assert layer_metrics.read("batch.serve_mfu", run) == pytest.approx(mfu)
     # a program without the counters, or no trace: nothing, never a 0
-    bare = {**run, "after": before, "trace": {}}
-    for name in PER_LAYER[13:20]:
+    bare = {**run, "after": run["before"], "trace": {}}
+    for name in PER_LAYER:
         assert layer_metrics.read(name, bare) is None, name
 
 
 def _check_the_block(bench, root):
     """What this PR owns of the manifest, and nothing about what follows it."""
-    accepted_before = rules.load_accepted(root)["per_layer"]
+    # the names accepted before this cell's: the KDD Cup and e-commerce
+    # cells', less those folded
+    accepted_before = rules.load_accepted(root)["per_layer"][:58]
     block = rules.check_owned_block(bench, root, CELL, PER_LAYER, accepted_before)
-    assert len(block) == 21
-    assert [m["moves"] for m in block] == ["queries_per_s"] * 20 + ["setup_s"]
+    assert len(block) == 7
+    assert [m["moves"] for m in block] == ["queries_per_s"] * 7
     assert rules.reports(bench, "end_to_end", CELL) == ["setup_s", "queries_per_s"]
     cell = rules.entry(bench, "workloads", CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
@@ -353,6 +360,7 @@ def _check_the_block(bench, root):
 
 def test_new_cell_and_its_entries_come_after_the_accepted(manifest):
     _check_the_block(*manifest)
+    rules.check_folded(*manifest, CELL)
 
 
 def _an_entry_moved_away(bench):
@@ -361,7 +369,7 @@ def _an_entry_moved_away(bench):
 
 
 def _an_entry_read_in_another_cell_too(bench):
-    rules.entry(bench, "per_layer", "simprod.serve_ms")["workloads"].append("serve-pool-batch")
+    rules.entry(bench, "per_layer", "simprod.launches_per_post")["workloads"].append("serve-pool-batch")
 
 
 def _the_cell_before_an_accepted_one(bench):
@@ -375,7 +383,7 @@ def _the_cell_gone(bench):
 
 @pytest.mark.parametrize("fault,message", [
     (_an_entry_moved_away, "stand together and in their order"),
-    (_an_entry_read_in_another_cell_too, "'simprod.serve_ms' is read in 'serve-simprod-batch' alone"),
+    (_an_entry_read_in_another_cell_too, "'simprod.launches_per_post' is read in 'serve-simprod-batch' alone"),
     (_the_cell_before_an_accepted_one, "'serve-simprod-batch' comes after the cells accepted before it"),
     (_the_cell_gone, "no cell 'serve-simprod-batch' in workloads"),
 ], ids=lambda v: getattr(v, "__name__", "message"))
@@ -403,19 +411,21 @@ def test_sound_rehearsal_is_correct_and_crosses_both_batchers(servers_built):
     for name in ("rule_violations", "short_answers", "bad_answers", "evictions"):
         assert result["compared"][name][0] == 0, name
     assert result["sampled"]["queries"] == 96
-    value = lambda name: result["metrics"]["simprod." + name]["value"]  # noqa: E731
-    for name in PER_LAYER[:4] + PER_LAYER[7:8] + PER_LAYER[9:11] + PER_LAYER[12:19]:
+    value = lambda name: result["metrics"][name]["value"]  # noqa: E731
+    shared = ("http_request_ms", "queue_wait_ms", "batch_occupancy", "device_dispatch_ms",
+              "launch_calls_share", "host_cpu_share", "compiles_in_window", "serve_ms")
+    for name in ["batch." + base for base in shared] + PER_LAYER[:6]:
         assert name in result["metrics"], name
-    assert value("compiles_in_window") == 0
-    assert 95 < value("launch_calls_share") < 105
-    assert 1.9 < value("launches_per_post") < 2.1
-    assert 3.5 < value("items_per_query") < 5.5
-    assert 40 < value("filtered_share") < 60
-    assert 5 < value("like_short_share") < 95
-    assert 0 <= value("both_lists_share") < 50
+    assert value("batch.compiles_in_window") == 0
+    assert 95 < value("batch.launch_calls_share") < 105
+    assert 1.9 < value("simprod.launches_per_post") < 2.1
+    assert 3.5 < value("simprod.items_per_query") < 5.5
+    assert 40 < value("simprod.filtered_share") < 60
+    assert 5 < value("simprod.like_short_share") < 95
+    assert 0 <= value("simprod.both_lists_share") < 50
     # no device plane on the CPU: a share of a peak is left out, not 0
     assert "simprod.masked_topk_roofline" not in result["metrics"]
-    assert "simprod.serve_mfu" not in result["metrics"]
+    assert "batch.serve_mfu" not in result["metrics"]
     assert result["control"]["short_answers"] > 0
     assert "predict.prep" in result["breakdown"]["stage_ms"] if "breakdown" in result else True
     assert list(result)[-1] == "compared"

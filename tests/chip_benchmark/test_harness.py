@@ -122,6 +122,17 @@ def test_per_layer_metric_has_reader_and_target(manifest, entry):
     rules.check_per_layer_metric(*manifest, entry)
 
 
+@pytest.mark.parametrize(
+    "manifest,entry,cell", made_up.over_cells(BOTH), indirect=["manifest"]
+)
+def test_each_cell_of_an_entry_reports_its_target_and_reads_it_once(manifest, entry, cell):
+    rules.check_read_once(*manifest, entry, cell)
+
+
+def test_per_layer_holds_no_more_entries_than_the_contract_allows(manifest):
+    rules.check_per_layer_cap(*manifest)
+
+
 @_over("configs")
 def test_config_entry(manifest, entry):
     rules.check_config_entry(*manifest, entry)
@@ -135,7 +146,7 @@ def test_accepted_configurations_and_cells_are_all_there(manifest):
     rules.check_accepted_prefix(bench, root, accepted)
     assert tuple(accepted["configs"][:2]) == rules.KDDCUP_CONFIGS
     assert tuple(accepted["workloads"][:3]) == rules.KDDCUP_CELLS
-    assert [len(accepted[kind]) for kind in rules.LISTS] == [3, 4, 71]
+    assert [len(accepted[kind]) for kind in rules.LISTS] == [5, 6, 86]
 
 
 @_over("configs")
@@ -209,6 +220,7 @@ def test_no_test_reads_the_manifest_s_lists_but_through_the_fixture(manifest, tm
 
 def _all_rules(bench, root, accepted):
     """Every rule of the manifest over every entry of it."""
+    rules.check_per_layer_cap(bench, root)
     for entry in bench["configs"]:
         rules.check_config_entry(bench, root, entry)
         rules.check_control_is_tested(bench, root, entry)
@@ -218,6 +230,8 @@ def _all_rules(bench, root, accepted):
         rules.check_cell(bench, root, cell)
     for metric in bench["per_layer"]:
         rules.check_per_layer_metric(bench, root, metric)
+        for cell in metric["workloads"]:
+            rules.check_read_once(bench, root, metric, cell)
     rules.check_accepted_prefix(bench, root, accepted)
 
 
@@ -272,6 +286,20 @@ def _name_an_entry_twice(bench, root):
     bench["per_layer"].append(dict(bench["per_layer"][-1]))
 
 
+def _grow_per_layer_to_133(bench, root):
+    """133 entries, each sound by itself: five past the cap."""
+    last = rules.entry(bench, "per_layer", MADE_UP["per_layer"][1])
+    for i in range(133 - len(bench["per_layer"])):
+        bench["per_layer"].append({**last, "name": f"ml20m{i}.encode_ms"})
+
+
+def _read_a_quantity_twice(bench, root):
+    """A second name for what `ml20m.encode_ms` reads: `metrics/encode_ms.json`."""
+    bench["per_layer"].append({
+        **rules.entry(bench, "per_layer", MADE_UP["per_layer"][1]), "name": "bursts.encode_ms",
+    })
+
+
 def _restate_a_default_that_is_not_deploy_s(bench, root):
     body = rules.config_body(bench, root, "rec-pool-ml20m")
     _write(root, ML20M, {**body, "server": {**body["server"], "max_wait_ms": 5.0}})
@@ -312,6 +340,8 @@ def _leave_out_what_the_runner_reads(bench, root):
     (_put_the_new_cell_first, "the accepted workloads names come first, in their order"),
     (_put_the_new_configuration_second, "the accepted configs names come first, in their order"),
     (_name_an_entry_twice, "a name stands twice in per_layer"),
+    (_grow_per_layer_to_133, "per_layer holds 133 entries, over the cap of 128"),
+    (_read_a_quantity_twice, "'serve-pool-ml20m-bursts' reads 'ml20m.encode_ms' again as ['bursts.encode_ms']"),
     (_restate_a_default_that_is_not_deploy_s, "server setting 'max_wait_ms' differs from deploy's default and is not under assumed"),
     (_name_a_runner_that_is_not_there, "no runner 'serve_elsewhere' under runners/"),
     (_name_a_reference_that_is_not_there, "names a reference 'reference_sequences' that is not there"),

@@ -108,7 +108,8 @@ def test_new_entries_only_follow_the_accepted_ones(manifest):
     rules.check_accepted_prefix(bench, root, rules.load_accepted(root))
     names = [m["name"] for m in bench["per_layer"]]
     # PR 24's 23, PR 25's 21 with setup_compile_s their last, PR 27's six,
-    # then PR 28's 17 (test_control_reference_ecomm.py) and PR 32's four
+    # then the e-commerce cell's five (test_control_reference_ecomm.py), and
+    # the two `launch_calls_share` entries and the tail that the fold left
     assert ACCEPTED_PER_LAYER[23:44] == [
         *_new_metrics("single"), *_new_metrics("batch"), "setup_compile_s",
     ]
@@ -116,17 +117,19 @@ def test_new_entries_only_follow_the_accepted_ones(manifest):
         f"{prefix}.{base}" for prefix in ("single", "batch")
         for base in (*WINDOW_METRICS, "launch_idle_share")
     ]
-    assert ACCEPTED_PER_LAYER[67:70] == [
-        f"{prefix}.launch_calls_share" for prefix in ("single", "batch", "ecomm")
+    assert ACCEPTED_PER_LAYER[55:57] == [
+        f"{prefix}.launch_calls_share" for prefix in ("single", "batch")
     ]
     # and the tail that PR 32's check found too noisy to judge end to end
-    assert ACCEPTED_PER_LAYER[70:] == ["single.query_p90_ms"]
-    assert len(ACCEPTED_PER_LAYER) == 71 <= len(names)
+    assert ACCEPTED_PER_LAYER[57] == "single.query_p90_ms"
+    assert len(ACCEPTED_PER_LAYER) == 86 <= len(names)
 
 
 def test_launch_calls_share_and_its_entries(manifest):
     """PR 32's three, as `PERF.md` specified them from PR 29 on: one reader
-    file, the accepted `counter_share` over the batches dispatched."""
+    file, the accepted `counter_share` over the batches dispatched; since
+    the fold the `batch.` entry also serves the three cells that read their
+    own copy before."""
     bench, _root = manifest
     with open(os.path.join(CHIP, "metrics", "launch_calls_share.json")) as f:
         spec = json.load(f)
@@ -136,9 +139,12 @@ def test_launch_calls_share_and_its_entries(manifest):
     }
     cells = {
         "single": ("query_p50_ms", ["serve-pool-single"]),
-        "batch": ("queries_per_s", ["serve-pool-batch", "serve-pool-int8-batch"]),
-        "ecomm": ("queries_per_s", ["serve-ecomm-batch"]),
+        "batch": ("queries_per_s", [
+            "serve-pool-batch", "serve-pool-int8-batch", "serve-ecomm-batch",
+            "serve-simprod-batch", "serve-pool-churn-batch",
+        ]),
     }
+    assert "ecomm.launch_calls_share" not in [m["name"] for m in bench["per_layer"]]
     for prefix, (moves, workloads) in cells.items():
         entry = rules.entry(bench, "per_layer", f"{prefix}.launch_calls_share")
         assert (entry["moves"], entry["workloads"]) == (moves, workloads)
@@ -155,7 +161,7 @@ def test_launch_calls_share_and_its_entries(manifest):
         }
 
     run = {"before": snapshot(100, 300), "after": snapshot(1100, 1301), "traffic": {}}
-    assert layer_metrics.read("ecomm.launch_calls_share", run) == pytest.approx(100.1)
+    assert layer_metrics.read("batch.launch_calls_share", run) == pytest.approx(100.1)
     bare = {"before": {}, "after": {}, "traffic": {}}
     assert layer_metrics.read("single.launch_calls_share", bare) is None
 
@@ -195,10 +201,16 @@ def test_window_and_launch_metrics_and_their_entries(manifest, base):
         spec = json.load(f)
     unit, source = WINDOW_METRICS.get(base, ("%", "device_trace"))
     layer = "predict" if base == "launch_idle_share" else "micro-batcher"
-    for prefix, moves in (("single", "query_p50_ms"), ("batch", "queries_per_s")):
+    # each read in its prefix's cells, and since the fold
+    # `batch.launch_idle_share` in the two rules cells beside them
+    for prefix, moves, cells in (
+        ("single", "query_p50_ms", ["serve-pool-single"]),
+        ("batch", "queries_per_s", ["serve-pool-batch", "serve-pool-int8-batch"]),
+    ):
         entry = rules.entry(bench, "per_layer", f"{prefix}.{base}")
-        sibling = rules.entry(bench, "per_layer", f"{prefix}.queue_wait_ms")
-        assert (entry["moves"], entry["workloads"]) == (moves, sibling["workloads"])
+        if f"{prefix}.{base}" == "batch.launch_idle_share":
+            cells = [*cells, "serve-ecomm-batch", "serve-simprod-batch"]
+        assert (entry["moves"], entry["workloads"]) == (moves, cells)
         assert (entry["unit"], entry["source"], entry["layer"]) == (unit, source, layer)
         assert entry["better"] == "lower"
 
