@@ -7,17 +7,22 @@ it — the nearest in-tree pattern is
 ``examples/scala-parallel-classification``, whose DASE layout this
 follows). Documents arrive as ``$set`` events on a ``document`` entity
 carrying ``text`` and ``label`` properties; queries
-``{"text": "..."}`` answer ``{"label": ..., "scores": {...}}``.
+``{"text": "...", "num": k}`` answer ``{"label": ..., "scores": {...}}``:
+the ``k`` best classes, best first, or every class without ``num``.
 
 TPU-first redesign: instead of a collected vocabulary + tf-idf RDD
-pipeline, tokens are FEATURE-HASHED into a fixed-width count vector —
-the matrix shape ``[n_docs, n_features]`` is a compile-time constant
-independent of corpus vocabulary, so the jitted fit/score programs
-never recompile as data grows (the vocabulary-sized path would change
-shape with every new token). Fitting is the existing one-matmul
-multinomial NB (:func:`predictionio_tpu.ops.naive_bayes
-.fit_multinomial`); scoring one query is a tiny fixed-shape
-matvec against the class-conditional log-probability table.
+pipeline, tokens are FEATURE-HASHED into a fixed width ``d`` — the
+table's shape is a compile-time constant independent of corpus
+vocabulary, so the jitted fit/score programs never recompile as data
+grows. A document is a handful of (bucket, count) pairs, never a
+``d``-wide row: the fit scatter-adds the pairs into the [d, C] count
+table (:func:`predictionio_tpu.ops.naive_bayes.fit_multinomial_sparse`),
+and scoring a batch gathers the rows its pairs name from the transposed
+log-likelihood table and sums them per query
+(:func:`~predictionio_tpu.ops.naive_bayes.sparse_log_scores`). At a
+taxonomy's width (3,008 classes × 2^20 buckets, 12.6 GB) a launch reads
+a few MB of the table, where a dense [B, d] @ [d, C] product read it
+all.
 """
 
 from __future__ import annotations
@@ -26,6 +31,7 @@ import dataclasses
 import logging
 import re
 
+import jax
 import numpy as np
 
 from predictionio_tpu.core import (
@@ -39,7 +45,10 @@ from predictionio_tpu.core import (
 )
 from predictionio_tpu.core.controller import SanityCheck
 from predictionio_tpu.data.store import EventStore
+from predictionio_tpu.models.staged_rules import RegistryCounters
+from predictionio_tpu.obs import tracing
 from predictionio_tpu.ops import naive_bayes as nb
+from predictionio_tpu.ops.similarity import host_operand
 from predictionio_tpu.parallel.mesh import ComputeContext
 from predictionio_tpu.utils.bimap import BiMap
 
@@ -71,6 +80,46 @@ def hash_counts(
             h = ((h ^ byte) * _FNV_PRIME) & _MASK64
         vec[h % n_features] += 1.0
     return vec
+
+
+def hash_buckets(
+    tokens: list[str], n_features: int, seed: int = 0
+) -> np.ndarray:
+    """:func:`hash_counts`' bucket of each token, int64 [len(tokens)]:
+    the same FNV-1a 64-bit, vectorised over the tokens one byte column
+    at a time in numpy ``uint64`` (which wraps, as the mask does).
+    Tokens are ASCII, as ``tokenize`` yields them (its pattern has no
+    other character), so a token's bytes are its characters; any other
+    raises ``UnicodeEncodeError``."""
+    if not tokens:
+        return np.zeros(0, np.int64)
+    lengths = np.fromiter(map(len, tokens), np.int64, len(tokens))
+    data = np.frombuffer("".join(tokens).encode("ascii"), np.uint8)
+    starts = np.cumsum(lengths) - lengths
+    h = np.full(len(tokens), (_FNV_OFFSET + seed) & _MASK64, np.uint64)
+    prime = np.uint64(_FNV_PRIME)
+    for j in range(int(lengths.max())):
+        live = np.flatnonzero(lengths > j)
+        byte = data[starts[live] + j].astype(np.uint64)
+        h[live] = (h[live] ^ byte) * prime
+    return (h % np.uint64(n_features)).astype(np.int64)
+
+
+def hash_pairs(
+    texts: list[str], n_features: int, seed: int = 0
+) -> tuple[np.ndarray, np.ndarray, np.ndarray, int]:
+    """The distinct (text, bucket) pairs of ``texts`` with their token
+    counts, sorted by text then bucket: ``(row, bucket, count, tokens)``
+    as int64 arrays and the number of tokens hashed. What
+    ``np.stack([hash_counts(tokenize(t), d) for t in texts])`` holds as
+    nonzeros, without the [len(texts), d] array."""
+    per_text = [tokenize(t) for t in texts]
+    sizes = np.fromiter(map(len, per_text), np.int64, len(per_text))
+    flat = [tok for toks in per_text for tok in toks]
+    keys = np.repeat(np.arange(len(texts), dtype=np.int64), sizes)
+    keys = keys * n_features + hash_buckets(flat, n_features, seed)
+    keys, counts = np.unique(keys, return_counts=True)
+    return keys // n_features, keys % n_features, counts, len(flat)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -136,25 +185,30 @@ class TextDataSource(DataSource[TextTrainingData, dict, dict, list]):
 
 @dataclasses.dataclass(frozen=True)
 class TextPreparatorParams(Params):
-    #: hashed feature-vector width (compile-time constant: the jitted
-    #: programs never recompile as the corpus vocabulary grows)
+    #: hashed feature width (compile-time constant: the jitted programs
+    #: never recompile as the corpus vocabulary grows). 4,096 is a
+    #: quick-start size; a taxonomy of thousands of classes wants 2^20
+    #: (scikit-learn HashingVectorizer's default) so that words collide
+    #: rarely
     n_features: int = 4096
 
 
 @dataclasses.dataclass
 class TextPrepared:
-    x: object           # [n_pad, n_features] hashed counts, data-sharded
-    y: object           # int32 [n_pad], data-sharded
-    mask: object        # float32 [n_pad] 1.0 real / 0.0 padding
+    """The corpus as the nonzeros of its hashed count matrix."""
+
+    doc: np.ndarray      # int32 [nnz] document of each pair
+    bucket: np.ndarray   # int32 [nnz] hashed feature of each pair
+    count: np.ndarray    # int32 [nnz] the token's count in the document
+    y: np.ndarray        # int32 [n_docs] label code of each document
     label_map: BiMap
     n_features: int
 
 
 class TextPreparator(Preparator[TextTrainingData, TextPrepared]):
-    """Fixed-shape boundary: hash to the constant feature width, pad
-    rows to the data-axis multiple, and place on the mesh (the sibling
-    classification preparator's pattern; fit_multinomial's ``mask``
-    makes the padded rows exact)."""
+    """Hash every document to its (bucket, count) pairs at the constant
+    feature width: the fit never sees a [n_docs, n_features] matrix,
+    which at 800,000 titles × 2^20 buckets would be 3.4 TB."""
 
     params_class = TextPreparatorParams
 
@@ -165,13 +219,12 @@ class TextPreparator(Preparator[TextTrainingData, TextPrepared]):
         label_map, y = BiMap.string_int_with_codes(
             np.asarray(td.labels)
         )
-        x = np.stack(
-            [hash_counts(tokenize(t), n) for t in td.texts]
-        )
+        doc, bucket, count, _ = hash_pairs(td.texts, n)
         return TextPrepared(
-            x=ctx.shard_rows(x),
-            y=ctx.shard_rows(y),
-            mask=ctx.shard_rows(np.ones(len(td.texts), np.float32)),
+            doc=doc.astype(np.int32),
+            bucket=bucket.astype(np.int32),
+            count=count.astype(np.int32),
+            y=np.asarray(y, np.int32),
             label_map=label_map,
             n_features=n,
         )
@@ -185,29 +238,99 @@ class TextNBParams(Params):
 
 @dataclasses.dataclass
 class TextNBModel:
-    nb_model: nb.MultinomialNBModel
+    """log-prior ``pi`` [C] and the log-likelihood table transposed,
+    ``theta_t`` [d, C] (f32): a hashed feature's row is what a query
+    gathers. Classes are padded to a lane multiple
+    (:func:`~predictionio_tpu.ops.naive_bayes.pad_classes`; the label map
+    holds the real ones). Device arrays after train and after
+    :meth:`TextNBAlgorithm.stage_model`."""
+
+    pi: np.ndarray | jax.Array
+    theta_t: np.ndarray | jax.Array
     label_map: BiMap
     n_features: int
+
+    def __setstate__(self, state: dict) -> None:
+        # a model pickled before the transposed layout held a
+        # MultinomialNBModel of theta [C, d]: transposed once, here, on
+        # the host, and it answers as it did
+        old = state.pop("nb_model", None)
+        if old is not None:
+            state["pi"], state["theta_t"] = nb.pad_classes(
+                np.asarray(old.pi), np.asarray(old.theta).T
+            )
+        self.__dict__.update(state)
+
+
+#: each query's share of a batch's pair slots: a batch of B queries sends
+#: a power of two of at least ``PAIRS_PER_QUERY[0] * B`` slots, and the
+#: first launch of a batch bucket compiles the pair buckets up to
+#: ``PAIRS_PER_QUERY[-1] * B`` beside it
+PAIRS_PER_QUERY = (16, 32, 64)
+#: the least top-k a batch with ``num`` asks the device for: every num up
+#: to it shares one program
+MIN_TOP_K = 8
+
+
+def _pow2(n: int) -> int:
+    return 1 << max(0, n - 1).bit_length()
+
+
+class _Counters(RegistryCounters):
+    """The template's counters in one registry."""
+
+    def __init__(self, registry):
+        self.tokens = registry.counter(
+            "pio_textcls_tokens_total",
+            "Tokens the text-classification launch tokenized and hashed",
+        )
+        self.pairs = registry.counter(
+            "pio_textcls_pairs_total",
+            "Distinct (query, hashed bucket) pairs sent to the device: "
+            "the table rows a batch gathers",
+        )
+        self.slots = registry.counter(
+            "pio_textcls_pair_slots_total",
+            "Pair slots sent to the device, padding to the batch's "
+            "power-of-two pair bucket included",
+        )
 
 
 class TextNBAlgorithm(Algorithm[TextPrepared, TextNBModel, dict, dict]):
     params_class = TextNBParams
 
+    def __init__(self, params=None):
+        super().__init__(params)
+        #: (table shape, batch bucket) whose pair buckets are compiled
+        self._warm: set = set()
+
     def train(self, ctx: ComputeContext, data: TextPrepared) -> TextNBModel:
-        model = nb.fit_multinomial(
-            data.x, data.y,
+        pi, theta_t = nb.fit_multinomial_sparse(
+            data.doc, data.bucket, data.count, data.y,
+            n_features=data.n_features,
             n_classes=len(data.label_map),
             alpha=self.params.alpha,
-            mask=data.mask,
         )
         logger.info(
-            "text NB: %d classes, %d hashed features",
-            len(data.label_map), data.n_features,
+            "text NB: %d classes, %d hashed features, %d pairs",
+            len(data.label_map), data.n_features, len(data.doc),
         )
         return TextNBModel(
-            nb_model=model,
+            pi=pi, theta_t=theta_t,
             label_map=data.label_map,
             n_features=data.n_features,
+        )
+
+    def stage_model(
+        self, ctx: ComputeContext, model: TextNBModel
+    ) -> TextNBModel:
+        """Commit the table once at deploy, classes padded; device arrays
+        (a train's, already padded) pass through."""
+        if isinstance(model.theta_t, jax.Array):
+            return model
+        pi, theta_t = nb.pad_classes(model.pi, model.theta_t)
+        return dataclasses.replace(
+            model, pi=ctx.replicate(pi), theta_t=ctx.replicate(theta_t)
         )
 
     def predict(self, model: TextNBModel, query: dict) -> dict:
@@ -220,42 +343,114 @@ class TextNBAlgorithm(Algorithm[TextPrepared, TextNBModel, dict, dict]):
             model, self.batch_predict_launch(model, queries), queries
         )
 
+    def _top_k(self, model: TextNBModel, queries) -> int | None:
+        """The batch's top-k on the device: a power of two of at least
+        ``MIN_TOP_K`` over the largest ``num``, or None (every class's
+        score) where a query asks for all."""
+        nums = [q.get("num") for q in queries]
+        if any(n is None for n in nums):
+            return None
+        n_classes = len(model.label_map)
+        return min(n_classes, max(MIN_TOP_K, _pow2(max(map(int, nums)))))
+
+    def _warm_pair_buckets(self, model, n_rows: int) -> None:
+        """A batch bucket's first launch (a server's warm-up) also
+        compiles the step at each of its pair buckets for queries with a
+        ``num`` up to ``MIN_TOP_K``, so that such traffic compiles nothing
+        after warm-up while its titles run up to ``PAIRS_PER_QUERY[-1]``
+        tokens. (Each compile of a pair bucket takes seconds on the TPU,
+        so the all-class form compiles at the least bucket only, by the
+        warm-up's own launch.)"""
+        key = (model.theta_t.shape, n_rows)
+        if key in self._warm:
+            return
+        self._warm.add(key)
+        k = min(MIN_TOP_K, len(model.label_map))
+        jax.block_until_ready([
+            nb.sparse_log_scores(
+                model.theta_t, model.pi,
+                np.zeros((3, per_query * n_rows), np.int32), n_rows, k,
+            )
+            for per_query in PAIRS_PER_QUERY
+        ])
+
     def batch_predict_launch(self, model: TextNBModel, queries):
-        """Two-phase serving: featurize on host, enqueue the jitted
-        scorer, return the un-fetched log-probabilities."""
+        """Host prep + device enqueue, no barrier: tokenize and hash the
+        batch into its distinct (query, bucket) pairs, pad them to a
+        power-of-two bucket, and enqueue the gather-and-sum step."""
         if not queries:
             return None
-        x = np.stack([
-            hash_counts(
-                tokenize(str(q.get("text", ""))), model.n_features
+        n_rows = _pow2(len(queries))
+        self._warm_pair_buckets(model, n_rows)
+        with tracing.stage(tracing.PREDICT_PREP):
+            row, bucket, count, n_tokens = hash_pairs(
+                [str(q.get("text", "")) for q in queries], model.n_features
             )
-            for q in queries
-        ])
-        # pad the batch dim to the next power of two: the jitted scorer
-        # compiles per static shape, and the micro-batcher delivers
-        # arbitrary batch sizes — without bucketing, every new size
-        # compiles mid-traffic (recommendation.py does the same)
-        bucket = 1 << (len(queries) - 1).bit_length()
-        x = np.pad(x, ((0, bucket - len(queries)), (0, 0)))
-        return nb.log_scores(model.nb_model, x)
+            slots = max(
+                _pow2(len(row)), PAIRS_PER_QUERY[0] * n_rows
+            )
+            # padding pairs: count 0 on the last row, so rows stay sorted
+            pairs = np.zeros((3, slots), np.int32)
+            pairs[0] = n_rows - 1
+            pairs[0, :len(row)] = row
+            pairs[1, :len(row)] = bucket
+            pairs[2, :len(row)] = count
+            k = self._top_k(model, queries)
+            counters = _Counters.of(tracing.bound_registry())
+            counters.tokens.inc(n_tokens)
+            counters.pairs.inc(len(row))
+            counters.slots.inc(slots)
+        with tracing.stage(tracing.PREDICT_ENQUEUE):
+            tracing.launch_call(2)  # the pairs' upload, the step
+            out = nb.sparse_log_scores(
+                host_operand(model.theta_t), host_operand(model.pi),
+                pairs, n_rows, k,
+            )
+        return out, k
 
     def batch_predict_collect(
         self, model: TextNBModel, handle, queries
     ) -> list[dict]:
+        """Device barrier + per-query answers for a launch's handle."""
         if handle is None:
             return []
-        logp = np.asarray(handle)[: len(queries)]  # the device barrier
-        best = logp.argmax(axis=1)
-        out = []
-        for row, b in zip(logp, best):
-            out.append({
-                "label": model.label_map.inverse(int(b)),
-                "scores": {
-                    model.label_map.inverse(i): float(s)
-                    for i, s in enumerate(row)
-                },
+        out, k = handle
+        with tracing.stage(tracing.PREDICT_DEVICE_GET):
+            out = jax.device_get(out)
+        with tracing.stage(tracing.PREDICT_MATERIALIZE):
+            label = model.label_map.inverse
+            if k is None:
+                return self._answers_of_scores(model, out, queries)
+            scores, classes = out
+            answers = []
+            for i, q in enumerate(queries):
+                n = max(1, int(q["num"]))
+                answers.append({
+                    "label": label(int(classes[i, 0])),
+                    "scores": {
+                        label(int(c)): float(s)
+                        for c, s in zip(classes[i, :n], scores[i, :n])
+                    },
+                })
+            return answers
+
+    @staticmethod
+    def _answers_of_scores(model, logp, queries) -> list[dict]:
+        """Answers from every class's score: all classes for a query
+        without ``num``, its ``num`` best, best first, for one with."""
+        names = model.label_map.keys().tolist()
+        answers = []
+        for row, q in zip(logp[:, :len(names)], queries):
+            if q.get("num") is None:
+                order = range(len(names))
+            else:
+                n = min(max(1, int(q["num"])), len(names))
+                order = np.argsort(-row, kind="stable")[:n].tolist()
+            answers.append({
+                "label": names[int(row.argmax())],
+                "scores": {names[c]: float(row[c]) for c in order},
             })
-        return out
+        return answers
 
     def warmup_query(self) -> dict:
         return {"text": ""}

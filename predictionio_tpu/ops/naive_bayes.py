@@ -10,6 +10,12 @@ TPU-first design: the per-class feature sums are ``onehot(y).T @ X`` — a
 single [C, n] × [n, d] matmul on the MXU — instead of the reference's
 ``combineByKey`` shuffle. Everything is jitted with static (n, d, C)
 shapes; a padding row mask makes padded batches exact.
+
+For hashed text, whose rows are a few nonzeros of a width d of 2^20, the
+sparse form takes (row, feature, count) pairs: the fit scatter-adds them
+into the transposed [d, C] table, and scoring gathers the rows a batch's
+pairs name and sums them per row (:func:`sparse_log_scores`), so neither
+builds a [n, d] matrix nor reads the whole table.
 """
 
 from __future__ import annotations
@@ -80,6 +86,109 @@ def log_scores(model: MultinomialNBModel, x: jax.Array) -> jax.Array:
 @jax.jit
 def predict_classes(model: MultinomialNBModel, x: jax.Array) -> jax.Array:
     return jnp.argmax(log_scores(model, x), axis=1)
+
+
+# --------------------------------------------------------------------------
+# Multinomial NB over sparse (row, feature, count) pairs, table [d, C]
+# --------------------------------------------------------------------------
+
+#: lanes of a TPU vector register. A [d, C] f32 table whose C is no
+#: multiple of them is laid out class-major on the TPU (d minor, no
+#: padding), and a row gather then copies the whole table first; at C a
+#: multiple of 128 the rows are the minor dimension. So the sparse form
+#: pads its classes to a lane multiple: padding classes have theta 0 and
+#: pi -inf, and never rank.
+LANES = 128
+
+
+def padded_classes(n_classes: int) -> int:
+    return -(-n_classes // LANES) * LANES
+
+
+def pad_classes(
+    pi: np.ndarray, theta_t: np.ndarray
+) -> tuple[np.ndarray, np.ndarray]:
+    """Host ``(pi [C], theta_t [d, C])`` padded to a lane multiple of
+    classes (f32), as :func:`fit_multinomial_sparse` makes them."""
+    pi = np.asarray(pi, np.float32)
+    theta_t = np.asarray(theta_t, np.float32)
+    extra = padded_classes(len(pi)) - len(pi)
+    if extra == 0:
+        return pi, theta_t
+    return (
+        np.concatenate([pi, np.full(extra, -np.inf, np.float32)]),
+        np.pad(theta_t, ((0, 0), (0, extra))),
+    )
+
+
+@partial(jax.jit, static_argnames=("n_features", "n_classes"))
+def fit_multinomial_sparse(
+    doc: jax.Array,
+    feature: jax.Array,
+    count: jax.Array,
+    y: jax.Array,
+    n_features: int,
+    n_classes: int,
+    alpha: float = 1.0,
+) -> tuple[jax.Array, jax.Array]:
+    """``fit_multinomial`` from the nonzeros of ``x``: ``(pi, theta_t)``,
+    the log-likelihood table transposed so that a feature's row is what
+    sparse scoring gathers, both padded to :func:`padded_classes`.
+
+    doc, feature: int [nnz]; count: [nnz]; y: int [n] (one label a row).
+    Padding pairs carry count 0. The counts are scatter-added into one
+    [d, C] buffer and normalised in place: no [n, d] matrix exists, and
+    the table is the only buffer of its size."""
+    padded = padded_classes(n_classes)
+    real = jnp.arange(padded) < n_classes
+    class_count = jnp.zeros(padded, jnp.float32).at[y].add(1.0)
+    counts = jnp.zeros((n_features, padded), jnp.float32).at[
+        feature, y[doc]
+    ].add(count.astype(jnp.float32))
+    pi = jnp.where(
+        real,
+        jnp.log(class_count + alpha)
+        - jnp.log(class_count.sum() + alpha * n_classes),
+        -jnp.inf,
+    )
+    theta_t = jnp.where(
+        real,
+        jnp.log(counts + alpha)
+        - jnp.log(counts.sum(axis=0, keepdims=True) + alpha * n_features),
+        0.0,
+    )
+    return pi, theta_t
+
+
+#: the named scope of the sparse scoring step (device-trace attribution)
+SPARSE_SCOPE = "textcls_score"
+
+
+@partial(jax.jit, static_argnames=("n_rows", "k"))
+def sparse_log_scores(
+    theta_t: jax.Array,
+    pi: jax.Array,
+    pairs: jax.Array,
+    n_rows: int,
+    k: int | None = None,
+):
+    """Joint log-scores of ``n_rows`` sparse rows: ``pi + sum count *
+    theta_t[feature]`` per row, as f32 adds (no matmul, so no operand
+    is rounded to bfloat16 on the way).
+
+    pairs: int32 [3, P] of (row, feature, count), sorted by row; padding
+    pairs carry count 0. Returns the [n_rows, C] scores (padding classes
+    at -inf), or with ``k`` the ``(scores, classes)`` of each row's ``k``
+    best, best first."""
+    with jax.named_scope(SPARSE_SCOPE):
+        rows = jnp.take(theta_t, pairs[1], axis=0)          # [P, C]
+        terms = rows * pairs[2].astype(jnp.float32)[:, None]
+        scores = jax.ops.segment_sum(
+            terms, pairs[0], num_segments=n_rows, indices_are_sorted=True
+        ) + pi[None, :]
+    if k is None:
+        return scores
+    return jax.lax.top_k(scores, k)
 
 
 # --------------------------------------------------------------------------

@@ -106,6 +106,35 @@ class TestKernel:
             np.asarray(ref.pi), np.asarray(padded.pi), rtol=1e-6
         )
 
+    @pytest.mark.parametrize("n_classes", [3, 128, 130])
+    def test_sparse_fit_equals_dense_fit(self, n_classes):
+        """The fit from (doc, feature, count) pairs is the dense fit,
+        transposed, with classes padded to a lane multiple: theta 0 and
+        pi -inf past the real ones. Pairs of count 0 change nothing."""
+        rng = np.random.default_rng(n_classes)
+        n, d = 40, 64
+        x = rng.poisson(0.3, (n, d)).astype(np.float32)
+        y = np.arange(n) % n_classes
+        dense = nb.fit_multinomial(jnp.asarray(x), jnp.asarray(y), n_classes)
+        doc, feature = np.nonzero(x)
+        count = x[doc, feature].astype(np.int32)
+        pad = np.zeros(7, np.int32)
+        pi, theta_t = nb.fit_multinomial_sparse(
+            np.concatenate([doc, pad]), np.concatenate([feature, pad + 3]),
+            np.concatenate([count, pad]), y, n_features=d, n_classes=n_classes,
+        )
+        padded = nb.padded_classes(n_classes)
+        assert padded % nb.LANES == 0 and theta_t.shape == (d, padded)
+        np.testing.assert_allclose(
+            np.asarray(theta_t)[:, :n_classes], np.asarray(dense.theta).T,
+            rtol=1e-6,
+        )
+        np.testing.assert_allclose(
+            np.asarray(pi)[:n_classes], np.asarray(dense.pi), rtol=1e-6
+        )
+        assert np.all(np.asarray(theta_t)[:, n_classes:] == 0.0)
+        assert np.all(np.asarray(pi)[n_classes:] == -np.inf)
+
     def test_categorical_nb(self):
         codes = np.asarray([[0, 1], [0, 0], [1, 1]])
         onehot = nb.encode_categorical(codes, [2, 2])

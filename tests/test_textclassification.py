@@ -184,3 +184,239 @@ class TestTraining:
         query = {"text": "win free money now"}
         preds = algorithms[0].batch_predict(models[0], [query])
         assert serving.serve(query, [preds[0]])["label"] == "spam"
+
+
+# -- the sparse form: hashing, scoring, answers, the parent's pickles --------
+
+import os  # noqa: E402
+import pickle  # noqa: E402
+import sys  # noqa: E402
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from predictionio_tpu.models.textclassification import (  # noqa: E402
+    PAIRS_PER_QUERY,
+    TextNBModel,
+    hash_buckets,
+    hash_pairs,
+)
+from predictionio_tpu.ops import naive_bayes as nb  # noqa: E402
+from predictionio_tpu.utils.bimap import BiMap  # noqa: E402
+
+sys.path.insert(0, os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks", "chip"
+))
+import reference_textcls  # noqa: E402
+
+#: texts with repeated tokens, no tokens at all, case and punctuation, and
+#: one longer than a query's least share of a batch's pair slots
+TEXTS = [
+    "the the the red shoe",
+    "",
+    "!!! ---",
+    "Men's LEATHER wallet, brown / 2-pack",
+    "4k tv 55\" smart tv 2024 model x55q",
+    " ".join(f"w{i}" for i in range(3 * PAIRS_PER_QUERY[0])),
+    "a b c d e f g h i j k l m n o p q r s t u v w x y z",
+]
+
+
+def _random_ascii(rng, n):
+    alphabet = np.frombuffer(b"abcdefghijklmnopqrstuvwxyz0123456789'", np.uint8)
+    return [
+        bytes(alphabet[rng.integers(0, len(alphabet), rng.integers(1, 12))]).decode()
+        for _ in range(n)
+    ]
+
+
+def _model(seed, n_features, n_classes=5):
+    """A random model, as host arrays of the real classes (unpadded)."""
+    rng = np.random.default_rng(seed)
+    pi = rng.standard_normal(n_classes).astype(np.float32) - 2.0
+    theta_t = (rng.standard_normal((n_features, n_classes)) - 8.0).astype(np.float32)
+    labels = BiMap([f"c{c:04d}" for c in range(n_classes)])
+    return TextNBModel(pi=pi, theta_t=theta_t, label_map=labels, n_features=n_features)
+
+
+def _score_matrix(answers, n_classes):
+    return np.array([[a["scores"][f"c{c:04d}"] for c in range(n_classes)] for a in answers])
+
+
+class TestSparseHashing:
+    def test_vectorised_hash_equals_hash_counts(self):
+        rng = np.random.default_rng(7)
+        tokens = _random_ascii(rng, 500) + ["don't", "o'", "'", "42", "a1b2"]
+        for d in (64, 4096, 2**20):
+            got = hash_buckets(tokens, d)
+            want = [int(np.argmax(hash_counts([t], d))) for t in tokens]
+            assert got.tolist() == want
+        assert hash_buckets([], 64).shape == (0,)
+        # tokenize yields ASCII whatever the text; a token that is not
+        # ASCII is refused, not hashed as some other bytes
+        assert tokenize("Café NAÏVE x") == ["caf", "na", "ve", "x"]
+        with pytest.raises(UnicodeEncodeError):
+            hash_buckets(["café"], 64)
+
+    @pytest.mark.parametrize("d", [64, 4096])
+    def test_pairs_are_the_nonzeros_of_the_dense_rows(self, d):
+        texts = TEXTS + [" ".join(_random_ascii(np.random.default_rng(d), 30))]
+        row, bucket, count, n_tokens = hash_pairs(texts, d)
+        dense = np.stack([hash_counts(tokenize(t), d) for t in texts])
+        want_row, want_bucket = np.nonzero(dense)
+        assert row.tolist() == want_row.tolist()
+        assert bucket.tolist() == want_bucket.tolist()
+        assert count.tolist() == dense[want_row, want_bucket].tolist()
+        assert n_tokens == dense.sum()
+
+
+class TestSparseScoring:
+    @pytest.mark.parametrize("d", [64, 4096])
+    def test_sparse_scores_equal_the_reference_and_the_dense_product(self, d):
+        """Every class's score, sparse, against `reference_textcls`'s
+        arithmetic (float64) and the template's earlier dense product at
+        full f32 precision: to 1e-5. At d 64 the texts' tokens collide."""
+        model = _model(d, d)
+        algo = TextNBAlgorithm(TextNBParams())
+        got = _score_matrix(
+            algo.batch_predict(model, [{"text": t} for t in TEXTS]), 5
+        )
+        want = np.tile(model.pi.astype(np.float64), (len(TEXTS), 1))
+        for q, text in enumerate(TEXTS):
+            for b, n in reference_textcls.bucket_counts(text, d).items():
+                want[q] += n * model.theta_t[b].astype(np.float64)
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+        x = np.stack([hash_counts(tokenize(t), d) for t in TEXTS])
+        with jax.default_matmul_precision("highest"):
+            dense = nb.log_scores(
+                nb.MultinomialNBModel(
+                    pi=jnp.asarray(model.pi), theta=jnp.asarray(model.theta_t.T)
+                ),
+                x,
+            )
+        np.testing.assert_allclose(got, np.asarray(dense), rtol=1e-5, atol=1e-5)
+
+    def test_without_num_the_answer_is_every_class(self):
+        model = _model(3, 256)
+        algo = TextNBAlgorithm(TextNBParams())
+        answers = algo.batch_predict(model, [{"text": t} for t in TEXTS])
+        for answer in answers:
+            scores = answer["scores"]
+            assert list(scores) == [f"c{c:04d}" for c in range(5)]
+            assert answer["label"] == max(scores, key=scores.get)
+
+    @pytest.mark.parametrize("num", [1, 3, 5, 9])
+    def test_num_gives_the_k_best_in_order(self, num):
+        model = _model(4, 256)
+        algo = TextNBAlgorithm(TextNBParams())
+        full = algo.batch_predict(model, [{"text": t} for t in TEXTS])
+        top = algo.batch_predict(model, [{"text": t, "num": num} for t in TEXTS])
+        for whole, best in zip(full, top):
+            ranked = sorted(whole["scores"], key=whole["scores"].get, reverse=True)
+            assert list(best["scores"]) == ranked[:min(num, 5)]
+            assert best["label"] == whole["label"] == ranked[0]
+            for name, score in best["scores"].items():
+                assert score == pytest.approx(whole["scores"][name], abs=1e-5)
+        # a batch that mixes the two: each query as asked
+        mixed = algo.batch_predict(
+            model, [{"text": TEXTS[0], "num": 2}, {"text": TEXTS[0]}]
+        )
+        assert len(mixed[0]["scores"]) == 2 and len(mixed[1]["scores"]) == 5
+
+    def test_a_pickle_of_the_dense_form_loads_and_answers_the_same(self):
+        """A model pickled with the template's earlier form (a
+        MultinomialNBModel of theta [C, d]) loads as the transposed,
+        lane-padded table and answers as the earlier dense path did."""
+        model = _model(5, 512)
+        old = TextNBModel.__new__(TextNBModel)
+        old.__dict__.update(
+            nb_model=nb.MultinomialNBModel(
+                pi=jnp.asarray(model.pi), theta=jnp.asarray(model.theta_t.T)
+            ),
+            label_map=model.label_map, n_features=512,
+        )
+        loaded = pickle.loads(pickle.dumps(old))
+        assert not hasattr(loaded, "nb_model")
+        assert loaded.theta_t.shape == (512, nb.padded_classes(5))
+        np.testing.assert_array_equal(loaded.theta_t[:, :5], model.theta_t)
+        algo = TextNBAlgorithm(TextNBParams())
+        got = algo.batch_predict(
+            algo.stage_model(ComputeContext.create(batch="t"), loaded),
+            [{"text": t} for t in TEXTS],
+        )
+        x = np.stack([hash_counts(tokenize(t), 512) for t in TEXTS])
+        with jax.default_matmul_precision("highest"):
+            dense = np.asarray(nb.log_scores(old.nb_model, x))
+        for answer, row in zip(got, dense):
+            assert answer["label"] == f"c{int(row.argmax()):04d}"
+            np.testing.assert_allclose(
+                [answer["scores"][f"c{c:04d}"] for c in range(5)], row,
+                rtol=1e-5, atol=1e-5,
+            )
+
+    def test_a_batch_bucket_compiles_its_pair_buckets_once(self, monkeypatch):
+        """The first launch of a batch bucket runs the step at each of its
+        pair buckets for queries with num; later launches run it once."""
+        model = _model(6, 256)
+        algo = TextNBAlgorithm(TextNBParams())
+        calls = []
+        step = nb.sparse_log_scores
+
+        def counting(theta_t, pi, pairs, n_rows, k=None):
+            calls.append((pairs.shape[1], n_rows, k))
+            return step(theta_t, pi, pairs, n_rows, k)
+
+        monkeypatch.setattr(nb, "sparse_log_scores", counting)
+        algo.batch_predict(model, [{"text": "a b"}] * 3)
+        assert calls == [(p * 4, 4, 5) for p in PAIRS_PER_QUERY] + [(64, 4, None)]
+        calls.clear()
+        algo.batch_predict(model, [{"text": TEXTS[5], "num": 2}] * 4)
+        assert calls == [(256, 4, 5)]
+
+
+def test_stages_and_counters_on_the_engine_server(ctx, memory_storage):
+    """Served through a single-tenant EngineServer's batcher: the launch
+    and collect run the four predict stages once, and the three counters
+    hold the batch's tokens, distinct pairs and padded slots."""
+    from predictionio_tpu.core import EngineParams
+    from predictionio_tpu.core.workflow import run_train
+    from predictionio_tpu.obs import MetricRegistry
+    from predictionio_tpu.serving.engine_server import EngineServer
+
+    _seed(memory_storage)
+    engine = textclassification_engine()
+    params = EngineParams(
+        data_source=("", TextDataSourceParams(app_name="TextApp")),
+        preparator=("", TextPreparatorParams(n_features=512)),
+        algorithms=[("nb", TextNBParams())],
+    )
+    run_train(engine, params, engine_id="text-stages", ctx=ctx, storage=memory_storage)
+    registry = MetricRegistry()
+    es = EngineServer(
+        engine, params, engine_id="text-stages", storage=memory_storage,
+        ctx=ctx, warmup=False, registry=registry, max_wait_ms=200.0,
+        adaptive_wait=False,
+    )
+    queries = [{"text": "win free money now", "num": 1}, {"text": "money money"}]
+    try:
+        futures = [es._batchers[0].submit(q) for q in queries]
+        answers = [f.result(30) for f in futures]
+    finally:
+        es.close()
+    assert answers[0]["label"] == "spam" and len(answers[0]["scores"]) == 1
+    assert len(answers[1]["scores"]) == 2
+    data = registry.to_dict()
+    stages = {
+        s["labels"]["stage"]: s["count"] for s in data["pio_stage_seconds"]["samples"]
+    }
+    for stage in ("predict.prep", "predict.enqueue", "predict.device_get",
+                  "predict.materialize"):
+        assert stages.get(stage) == 1, stage
+
+    def value(family):
+        [sample] = data[family]["samples"]
+        return sample["value"]
+
+    assert value("pio_textcls_tokens_total") == 6
+    assert value("pio_textcls_pairs_total") == 5  # "money money" is one pair
+    assert value("pio_textcls_pair_slots_total") == PAIRS_PER_QUERY[0] * 2
