@@ -367,7 +367,7 @@ class Smoke:
         """The tool's copy may carry another machine's ``native/*.so``;
         what git would commit has none. Remove them and build through
         the repo's own loader, so a missing ``g++`` fails here."""
-        for name in ("alspack", "eventlog"):
+        for name in ("alspack", "eventlog", "textpack"):
             path = os.path.join(REPO, "native", f"libpio_{name}.so")
             if os.path.exists(path):
                 os.unlink(path)
@@ -376,12 +376,12 @@ class Smoke:
             [
                 sys.executable, "-c",
                 "from predictionio_tpu.utils.native import load_native_lib\n"
-                "for n in ('alspack', 'eventlog'):\n"
+                "for n in ('alspack', 'eventlog', 'textpack'):\n"
                 "    load_native_lib(n)\n",
             ],
         )
-        print(f"  native: built libpio_alspack.so, libpio_eventlog.so "
-              f"from source ({self.phases['native']}s)")
+        print(f"  native: built libpio_alspack.so, libpio_eventlog.so, "
+              f"libpio_textpack.so from source ({self.phases['native']}s)")
 
     def preflight(self) -> None:
         """``pio-tpu status`` first, as the quickstart does: a missing

@@ -27,9 +27,12 @@ all.
 
 from __future__ import annotations
 
+import ctypes
 import dataclasses
 import logging
+import os
 import re
+import types
 
 import jax
 import numpy as np
@@ -82,27 +85,118 @@ def hash_counts(
     return vec
 
 
+_TEXTPACK = None
+_TEXTPACK_TRIED = False
+
+
+def _load_textpack():
+    """The two loops of ``native/textpack.cc`` as ctypes functions that
+    keep the interpreter lock (``PYFUNCTYPE``: they take tens of µs, and
+    a lock handed back costs a busy server's thread more than that to win
+    again), or None where the library cannot be built or loaded: the
+    numpy code then runs. ``PIO_NO_NATIVE=1`` turns it off (tests run
+    both paths). Staging a model loads it, so a server's first launch
+    does not."""
+    global _TEXTPACK, _TEXTPACK_TRIED
+    if _TEXTPACK_TRIED:
+        return _TEXTPACK
+    _TEXTPACK_TRIED = True
+    if os.environ.get("PIO_NO_NATIVE", "").strip() in ("1", "true"):
+        return None
+    from predictionio_tpu.utils.native import load_native_lib
+
+    try:
+        lib = load_native_lib("textpack")
+        c = ctypes
+        i64 = np.ctypeslib.ndpointer(np.int64, 1, flags="C_CONTIGUOUS")
+        out64 = np.ctypeslib.ndpointer(
+            np.int64, 1, flags="C_CONTIGUOUS,WRITEABLE"
+        )
+        out32 = np.ctypeslib.ndpointer(
+            np.int32, 2, flags="C_CONTIGUOUS,WRITEABLE"
+        )
+        _TEXTPACK = types.SimpleNamespace(
+            hash=c.PYFUNCTYPE(
+                None, c.c_char_p, i64, c.c_int64, c.c_uint64, c.c_uint64,
+                out64,
+            )(("pio_text_hash", lib)),
+            pack=c.PYFUNCTYPE(
+                c.c_int64, i64, c.c_int64, i64, c.c_int64, out32, c.c_int64,
+                c.c_int32,
+            )(("pio_text_pack", lib)),
+        )
+        logger.info("text prep: native (native/libpio_textpack.so)")
+    except Exception as e:  # noqa: BLE001 - native is an optimization only
+        # load_native_lib's RuntimeError carries the compiler's output
+        logger.warning(
+            "text prep: numpy fallback — native/libpio_textpack.so "
+            "unavailable (%s: %s)", type(e).__name__, e,
+        )
+        _TEXTPACK = None
+    return _TEXTPACK
+
+
 def hash_buckets(
     tokens: list[str], n_features: int, seed: int = 0
 ) -> np.ndarray:
     """:func:`hash_counts`' bucket of each token, int64 [len(tokens)]:
-    the same FNV-1a 64-bit, vectorised over the tokens one byte column
-    at a time in numpy ``uint64`` (which wraps, as the mask does).
-    Tokens are ASCII, as ``tokenize`` yields them (its pattern has no
-    other character), so a token's bytes are its characters; any other
-    raises ``UnicodeEncodeError``."""
+    the same FNV-1a 64-bit, in one native pass over the tokens' bytes,
+    else vectorised over the tokens one byte column at a time in numpy
+    ``uint64`` (which wraps, as the mask does). Tokens are ASCII, as
+    ``tokenize`` yields them (its pattern has no other character), so a
+    token's bytes are its characters; any other raises
+    ``UnicodeEncodeError``."""
     if not tokens:
         return np.zeros(0, np.int64)
     lengths = np.fromiter(map(len, tokens), np.int64, len(tokens))
-    data = np.frombuffer("".join(tokens).encode("ascii"), np.uint8)
+    text = "".join(tokens).encode("ascii")
+    basis = (_FNV_OFFSET + seed) & _MASK64
+    native = _load_textpack()
+    if native is not None and n_features > 0:  # a 0 would trap in C++
+        out = np.empty(len(tokens), np.int64)
+        native.hash(text, lengths, len(tokens), basis, n_features, out)
+        return out
+    data = np.frombuffer(text, np.uint8)
     starts = np.cumsum(lengths) - lengths
-    h = np.full(len(tokens), (_FNV_OFFSET + seed) & _MASK64, np.uint64)
+    h = np.full(len(tokens), basis, np.uint64)
     prime = np.uint64(_FNV_PRIME)
     for j in range(int(lengths.max())):
         live = np.flatnonzero(lengths > j)
         byte = data[starts[live] + j].astype(np.uint64)
         h[live] = (h[live] ^ byte) * prime
     return (h % np.uint64(n_features)).astype(np.int64)
+
+
+def _tokens(texts: list[str]) -> tuple[np.ndarray, list[str]]:
+    """Each text's token count, int64, and all their tokens in order."""
+    per_text = [tokenize(t) for t in texts]
+    sizes = np.fromiter(map(len, per_text), np.int64, len(per_text))
+    return sizes, [tok for toks in per_text for tok in toks]
+
+
+def _unique_pairs(sizes, buckets, n_features: int):
+    """numpy's ``(row, bucket, count)``, int64, of the distinct pairs."""
+    keys = np.repeat(np.arange(len(sizes), dtype=np.int64), sizes)
+    keys = keys * n_features + buckets
+    keys, counts = np.unique(keys, return_counts=True)
+    return keys // n_features, keys % n_features, counts
+
+
+def _native_pack(pack, sizes, buckets, slots: int, pad_row: int):
+    """``native/textpack.cc``'s pairs in an int32 [3, slots] array, the
+    slots past them padding; where the pairs exceed ``slots``, the next
+    power of two of them. Returns the array and the number of pairs."""
+    pairs = np.empty((3, slots), np.int32)
+    n = pack(sizes, len(sizes), buckets, len(buckets), pairs, slots, pad_row)
+    if n < 0:
+        raise ValueError(
+            f"{len(buckets)} buckets for {len(sizes)} texts' token counts"
+        )
+    if n > slots:
+        slots = _pow2(n)
+        pairs = np.empty((3, slots), np.int32)
+        pack(sizes, len(sizes), buckets, len(buckets), pairs, slots, pad_row)
+    return pairs, n
 
 
 def hash_pairs(
@@ -112,14 +206,17 @@ def hash_pairs(
     counts, sorted by text then bucket: ``(row, bucket, count, tokens)``
     as int64 arrays and the number of tokens hashed. What
     ``np.stack([hash_counts(tokenize(t), d) for t in texts])`` holds as
-    nonzeros, without the [len(texts), d] array."""
-    per_text = [tokenize(t) for t in texts]
-    sizes = np.fromiter(map(len, per_text), np.int64, len(per_text))
-    flat = [tok for toks in per_text for tok in toks]
-    keys = np.repeat(np.arange(len(texts), dtype=np.int64), sizes)
-    keys = keys * n_features + hash_buckets(flat, n_features, seed)
-    keys, counts = np.unique(keys, return_counts=True)
-    return keys // n_features, keys % n_features, counts, len(flat)
+    nonzeros, without the [len(texts), d] array. Packed by the serving
+    launch's own routine where it loads (int32, so below 2^31 buckets),
+    so that train and serve hash alike."""
+    sizes, flat = _tokens(texts)
+    buckets = hash_buckets(flat, n_features, seed)
+    native = _load_textpack()
+    if native is None or n_features > 2**31:
+        return (*_unique_pairs(sizes, buckets, n_features), len(flat))
+    pairs, n = _native_pack(native.pack, sizes, buckets, len(flat), 0)
+    row, bucket, count = pairs[:, :n].astype(np.int64)
+    return row, bucket, count, len(flat)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -276,6 +373,27 @@ def _pow2(n: int) -> int:
     return 1 << max(0, n - 1).bit_length()
 
 
+def _pair_operand(sizes, buckets, n_features: int, n_rows: int):
+    """A launch's int32 [3, slots] pairs, the number of them, and the
+    path that packed them (``"native"`` or ``"numpy"``). Slots are a
+    power of two of at least ``PAIRS_PER_QUERY[0] * n_rows``; padding
+    pairs have count 0 on the last row, so rows stay sorted. The native
+    pack writes straight into the operand, so no numpy call over the
+    batch runs between the tokens and the step."""
+    slots = PAIRS_PER_QUERY[0] * n_rows
+    native = _load_textpack()
+    if native is not None:
+        pairs, n = _native_pack(native.pack, sizes, buckets, slots, n_rows - 1)
+        return pairs, n, "native"
+    row, bucket, count = _unique_pairs(sizes, buckets, n_features)
+    pairs = np.zeros((3, max(_pow2(len(row)), slots)), np.int32)
+    pairs[0] = n_rows - 1
+    pairs[0, :len(row)] = row
+    pairs[1, :len(row)] = bucket
+    pairs[2, :len(row)] = count
+    return pairs, len(row), "numpy"
+
+
 class _Counters(RegistryCounters):
     """The template's counters in one registry."""
 
@@ -294,6 +412,13 @@ class _Counters(RegistryCounters):
             "Pair slots sent to the device, padding to the batch's "
             "power-of-two pair bucket included",
         )
+        prep = registry.counter(
+            "pio_textcls_prep_batches_total",
+            "Text-classification launches by the path their prep took: "
+            "native (native/textpack.cc) or numpy (its fallback)",
+            label_names=("path",),
+        )
+        self.prep = {path: prep.labels(path) for path in ("native", "numpy")}
 
 
 class TextNBAlgorithm(Algorithm[TextPrepared, TextNBModel, dict, dict]):
@@ -325,7 +450,9 @@ class TextNBAlgorithm(Algorithm[TextPrepared, TextNBModel, dict, dict]):
         self, ctx: ComputeContext, model: TextNBModel
     ) -> TextNBModel:
         """Commit the table once at deploy, classes padded; device arrays
-        (a train's, already padded) pass through."""
+        (a train's, already padded) pass through. The native text prep
+        loads here, not in a server's first launch."""
+        _load_textpack()
         if isinstance(model.theta_t, jax.Array):
             return model
         pi, theta_t = nb.pad_classes(model.pi, model.theta_t)
@@ -383,23 +510,17 @@ class TextNBAlgorithm(Algorithm[TextPrepared, TextNBModel, dict, dict]):
         n_rows = _pow2(len(queries))
         self._warm_pair_buckets(model, n_rows)
         with tracing.stage(tracing.PREDICT_PREP):
-            row, bucket, count, n_tokens = hash_pairs(
-                [str(q.get("text", "")) for q in queries], model.n_features
+            sizes, flat = _tokens([str(q.get("text", "")) for q in queries])
+            buckets = hash_buckets(flat, model.n_features)
+            pairs, n_pairs, path = _pair_operand(
+                sizes, buckets, model.n_features, n_rows
             )
-            slots = max(
-                _pow2(len(row)), PAIRS_PER_QUERY[0] * n_rows
-            )
-            # padding pairs: count 0 on the last row, so rows stay sorted
-            pairs = np.zeros((3, slots), np.int32)
-            pairs[0] = n_rows - 1
-            pairs[0, :len(row)] = row
-            pairs[1, :len(row)] = bucket
-            pairs[2, :len(row)] = count
             k = self._top_k(model, queries)
             counters = _Counters.of(tracing.bound_registry())
-            counters.tokens.inc(n_tokens)
-            counters.pairs.inc(len(row))
-            counters.slots.inc(slots)
+            counters.prep[path].inc()
+            counters.tokens.inc(len(flat))
+            counters.pairs.inc(n_pairs)
+            counters.slots.inc(pairs.shape[1])
         with tracing.stage(tracing.PREDICT_ENQUEUE):
             tracing.launch_call(2)  # the pairs' upload, the step
             out = nb.sparse_log_scores(

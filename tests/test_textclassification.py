@@ -195,6 +195,7 @@ import sys  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
+from predictionio_tpu.models import textclassification as text_module  # noqa: E402
 from predictionio_tpu.models.textclassification import (  # noqa: E402
     PAIRS_PER_QUERY,
     TextNBModel,
@@ -268,6 +269,246 @@ class TestSparseHashing:
         assert bucket.tolist() == want_bucket.tolist()
         assert count.tolist() == dense[want_row, want_bucket].tolist()
         assert n_tokens == dense.sum()
+
+
+#: titles beside TEXTS that a tokenizer or a packer could get wrong: none,
+#: a title of 40 tokens, tokens of one and of 100+ characters, repeats,
+#: apostrophes and digits, and text that is not ASCII (Unicode lower-casing
+#: gives "i" and a combining dot for "İ", and "k" for the Kelvin sign)
+EDGE_TEXTS = [
+    "",
+    " ".join(f"t{i % 29}x" for i in range(40)),
+    "a " + "b" * 130 + " a 7 " + "0123456789" * 11,
+    "dup dup DUP Dup dup x dup",
+    "it's o'neil's '' 4'' 2x4 007 1.5",
+    "Café crème", "İstanbul İİ", "\u212a\u212a k", "👟 shoe 👟 🇯🇵",
+]
+
+
+def _cell_titles(seed, n=64):
+    """``n`` titles as the text cell's generator draws them: Zipf words
+    over a vocabulary, 1-40 tokens, mixed case and separators."""
+    import json
+
+    import loadgen_textcls
+    from loadgen import zipf_weights
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+        root, "benchmarks", "chip", "configs", "textcls-rakuten-sigir18.json"
+    )) as f:
+        config = json.load(f)
+    words = loadgen_textcls.vocabulary(seed, 20_000)
+    cdf = np.cumsum(zipf_weights(len(words), config["word_zipf_exponent"]))
+    rng = np.random.default_rng(seed)
+    return loadgen_textcls.draw_titles(rng, words, cdf, n, config["title_tokens"])[0]
+
+
+def _parents_operand(texts, d, n_rows):
+    """The launch's pairs as the template built them before the native
+    prep (call it on the numpy path): numpy pairs, padded into a
+    power-of-two bucket."""
+    row, bucket, count, _ = hash_pairs(texts, d)
+    slots = max(1 << max(0, len(row) - 1).bit_length(), PAIRS_PER_QUERY[0] * n_rows)
+    pairs = np.zeros((3, slots), np.int32)
+    pairs[0] = n_rows - 1
+    pairs[0, :len(row)] = row
+    pairs[1, :len(row)] = bucket
+    pairs[2, :len(row)] = count
+    return pairs
+
+
+@pytest.fixture
+def native(monkeypatch):
+    """The native text prep, loaded afresh."""
+    monkeypatch.delenv("PIO_NO_NATIVE", raising=False)
+    monkeypatch.setattr(text_module, "_TEXTPACK", None)
+    monkeypatch.setattr(text_module, "_TEXTPACK_TRIED", False)
+    lib = text_module._load_textpack()
+    assert lib is not None
+    return lib
+
+
+def _use_numpy(monkeypatch):
+    """Force the fallback, as a failed load leaves the loader."""
+    monkeypatch.setattr(text_module, "_TEXTPACK", None)
+    monkeypatch.setattr(text_module, "_TEXTPACK_TRIED", True)
+
+
+class TestNativePrep:
+    """`native/textpack.cc` against the numpy code it stands in for: the
+    same bits on every input, and no numpy call over the batch on the
+    launching thread."""
+
+    @pytest.mark.parametrize("seed", [0, 2**33 + 17])
+    @pytest.mark.parametrize("d", [64, 4096, 2**20])
+    def test_native_hash_equals_numpy(self, native, monkeypatch, d, seed):
+        rng = np.random.default_rng(d + seed)
+        printable = [chr(c) for c in range(32, 127)]
+        tokens = _random_ascii(rng, 700) + [
+            "".join(rng.choice(printable, int(n))) for n in (1, 2, 63, 64, 65, 300)
+        ] + ["don't", "o'", "'", "42", "a1b2", "dup", "dup"]
+        got = hash_buckets(tokens, d, seed)
+        _use_numpy(monkeypatch)
+        want = hash_buckets(tokens, d, seed)
+        assert got.dtype == want.dtype == np.int64
+        assert got.tolist() == want.tolist()
+        # and both are hash_counts' bucket, token by token
+        assert got[:50].tolist() == [
+            int(np.argmax(hash_counts([t], d, seed))) for t in tokens[:50]
+        ]
+
+    @pytest.mark.parametrize("path", ["native", "numpy"])
+    def test_a_token_that_is_not_ascii_raises(self, native, monkeypatch, path):
+        if path == "numpy":
+            _use_numpy(monkeypatch)
+        assert hash_buckets([], 64).shape == (0,)
+        with pytest.raises(UnicodeEncodeError):
+            hash_buckets(["ok", "café"], 64)
+
+    def test_buckets_that_disagree_with_the_token_counts_raise(self, native):
+        """The pack checks the token counts against the buckets before it
+        reads one, whatever the hash handed it."""
+        pack = native.pack
+        sizes = np.array([2, 3], np.int64)
+        for buckets in (np.arange(4), np.arange(6)):
+            with pytest.raises(ValueError, match="token counts"):
+                text_module._native_pack(pack, sizes, buckets, 16, 1)
+        with pytest.raises(ValueError, match="token counts"):
+            text_module._native_pack(pack, np.array([-1, 6]), np.arange(5), 16, 1)
+        pairs, n = text_module._native_pack(pack, sizes, np.array([4, 4, 1, 1, 1]), 2, 1)
+        assert n == 2 and pairs.tolist() == [[0, 1], [4, 1], [2, 3]]
+
+    @pytest.mark.parametrize("d", [64, 4096, 2**20])
+    def test_native_pairs_equal_numpy(self, native, monkeypatch, d):
+        texts = TEXTS + EDGE_TEXTS + _cell_titles(d)
+        got = hash_pairs(texts, d)
+        _use_numpy(monkeypatch)
+        want = hash_pairs(texts, d)
+        for g, w in zip(got[:3], want[:3]):
+            assert g.dtype == w.dtype == np.int64
+            np.testing.assert_array_equal(g, w)
+        assert got[3] == want[3] == sum(len(tokenize(t)) for t in texts)
+        # the edge titles tokenize as Python's Unicode lower() then the class
+        assert tokenize(EDGE_TEXTS[6]) == ["i", "stanbul", "i", "i"]
+        assert tokenize(EDGE_TEXTS[7]) == ["kk", "k"]
+
+    @pytest.mark.parametrize("batch", ["cell", "overflow", "warmup"])
+    @pytest.mark.parametrize("path", ["native", "numpy"])
+    def test_launch_operand_is_the_parents(self, native, monkeypatch, path, batch):
+        """The [3, slots] operand the step receives, padding included: a
+        batch of cell titles; one whose pairs overflow 16 a row into the
+        next power of two; the empty warm-up query."""
+        d = 4096
+        texts = {
+            "cell": _cell_titles(3),
+            "overflow": [" ".join(f"w{q}x{i}" for i in range(40)) for q in range(3)],
+            "warmup": [""],
+        }[batch]
+        n_rows = 1 << max(0, len(texts) - 1).bit_length()
+        with monkeypatch.context() as numpy_only:
+            _use_numpy(numpy_only)
+            want = _parents_operand(texts, d, n_rows)
+        if batch == "overflow":
+            assert want.shape[1] == 128 > PAIRS_PER_QUERY[0] * n_rows
+        if path == "numpy":
+            _use_numpy(monkeypatch)
+        model = _model(9, d)
+        algo = TextNBAlgorithm(TextNBParams())
+        seen = []
+        step = nb.sparse_log_scores
+
+        def capturing(theta_t, pi, pairs, n_rows, k=None):
+            seen.append(pairs)
+            return step(theta_t, pi, pairs, n_rows, k)
+
+        monkeypatch.setattr(nb, "sparse_log_scores", capturing)
+        algo.batch_predict(model, [{"text": t, "num": 1} for t in texts])
+        assert seen[-1].dtype == np.int32
+        np.testing.assert_array_equal(seen[-1], want)
+
+    def test_a_failed_load_falls_back_with_a_warning(self, monkeypatch, caplog):
+        import logging
+
+        from predictionio_tpu.utils import native as native_loader
+
+        def broken(name):
+            raise RuntimeError(f"building lib{name}.so failed:\ng++: boom")
+
+        monkeypatch.setattr(native_loader, "load_native_lib", broken)
+        monkeypatch.setattr(text_module, "_TEXTPACK", None)
+        monkeypatch.setattr(text_module, "_TEXTPACK_TRIED", False)
+        monkeypatch.delenv("PIO_NO_NATIVE", raising=False)
+        with caplog.at_level(logging.WARNING, logger=text_module.__name__):
+            assert text_module._load_textpack() is None
+        (record,) = [r for r in caplog.records if "text prep" in r.getMessage()]
+        assert "numpy fallback" in record.getMessage()
+        assert "g++: boom" in record.getMessage()
+        sizes, flat = text_module._tokens(TEXTS)
+        _, n_pairs, path = text_module._pair_operand(
+            sizes, hash_buckets(flat, 64), 64, 8
+        )
+        assert (n_pairs, path) == (len(hash_pairs(TEXTS, 64)[0]), "numpy")
+
+    def test_pio_no_native_selects_the_fallback(self, monkeypatch):
+        from predictionio_tpu.utils import native as native_loader
+
+        def never(name):
+            raise AssertionError("PIO_NO_NATIVE=1 must not load a library")
+
+        monkeypatch.setattr(native_loader, "load_native_lib", never)
+        monkeypatch.setattr(text_module, "_TEXTPACK", None)
+        monkeypatch.setattr(text_module, "_TEXTPACK_TRIED", False)
+        monkeypatch.setenv("PIO_NO_NATIVE", "1")
+        assert text_module._load_textpack() is None
+        assert text_module._pair_operand(
+            np.array([2]), np.array([5, 5]), 64, 1
+        )[1:] == (1, "numpy")
+
+    @pytest.mark.parametrize("path", ["native", "numpy"])
+    def test_the_native_prep_makes_no_numpy_call_over_the_batch(
+        self, native, monkeypatch, path
+    ):
+        """The lock rule: a numpy call over 500 elements hands the
+        interpreter lock back, which a busy server's launching thread pays
+        for in milliseconds. On 64 cell titles (some 700 tokens) the
+        native launch's prep makes none but the allocations it fills
+        (`np.fromiter`, `np.empty`); the numpy fallback makes many."""
+        if path == "numpy":
+            _use_numpy(monkeypatch)
+        model = _model(8, 4096)
+        algo = TextNBAlgorithm(TextNBParams())
+        titles = _cell_titles(8)
+        queries = [{"text": t, "num": 5} for t in titles]
+        algo.batch_predict_launch(model, queries)  # warm: compiles
+        calls = []
+
+        class Recording:
+            def __getattr__(self, name):
+                attr = getattr(np, name)
+                if isinstance(attr, type) or not callable(attr):
+                    return attr
+
+                def recorded(*args, **kwargs):
+                    out = attr(*args, **kwargs)
+                    sizes = [
+                        a.size for a in (*args, *kwargs.values(), out)
+                        if isinstance(a, np.ndarray)
+                    ]
+                    calls.append((name, max(sizes, default=0)))
+                    return out
+
+                return recorded
+
+        monkeypatch.setattr(text_module, "np", Recording())
+        algo.batch_predict_launch(model, queries)
+        monkeypatch.undo()
+        assert sum(len(tokenize(t)) for t in titles) > 500
+        large = {name for name, size in calls if size > 500}
+        if path == "native":
+            assert large == {"fromiter", "empty"}, calls
+        else:
+            assert {"unique", "repeat", "flatnonzero"} <= large, calls
 
 
 class TestSparseScoring:
@@ -420,3 +661,9 @@ def test_stages_and_counters_on_the_engine_server(ctx, memory_storage):
     assert value("pio_textcls_tokens_total") == 6
     assert value("pio_textcls_pairs_total") == 5  # "money money" is one pair
     assert value("pio_textcls_pair_slots_total") == PAIRS_PER_QUERY[0] * 2
+    path = "numpy" if text_module._load_textpack() is None else "native"
+    prep = {
+        s["labels"]["path"]: s["value"]
+        for s in data["pio_textcls_prep_batches_total"]["samples"]
+    }
+    assert prep == {path: 1, ({"native", "numpy"} - {path}).pop(): 0}
