@@ -24,6 +24,7 @@ from predictionio_tpu.models.recommendation import (
 from predictionio_tpu.obs import tracing
 from predictionio_tpu.obs.device import CompileWatch
 from predictionio_tpu.obs.registry import MetricRegistry
+from predictionio_tpu.ops import cooccurrence as CO
 from predictionio_tpu.ops import naive_bayes as NB
 from predictionio_tpu.ops import quantize as Q
 from predictionio_tpu.ops import similarity as S
@@ -300,9 +301,10 @@ def test_jit_names_of_a_configuration_are_jitted_programs(config):
         names = json.load(f)["jit_names"]
     assert names
     for name in names:
-        found = [getattr(m, name) for m in (S, Q, NB) if hasattr(m, name)]
+        found = [getattr(m, name) for m in (S, Q, NB, CO) if hasattr(m, name)]
         assert len(found) == 1, (
-            f"{name} is in one of ops/similarity.py, ops/quantize.py, ops/naive_bayes.py"
+            f"{name} is in one of ops/similarity.py, ops/quantize.py, "
+            "ops/naive_bayes.py, ops/cooccurrence.py"
         )
         assert isinstance(found[0], _JITTED)
         assert found[0].__name__ == name  # the trace's module is jit_<name>

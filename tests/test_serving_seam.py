@@ -16,6 +16,7 @@ import test_templates as tpl
 import test_textclassification as text
 from fake_engine import FakeAlgorithm
 from predictionio_tpu.core import EngineParams
+from predictionio_tpu.core.controller import Algorithm
 from predictionio_tpu.core.registry import engine_registry
 from predictionio_tpu.core.workflow import load_deployment, run_train
 from predictionio_tpu.models.complementarypurchase import (
@@ -108,6 +109,14 @@ TEMPLATES = {
 }
 
 
+#: the templates that implement the launch and collect themselves, and
+#: time the four predict stages in them
+OWN_LAUNCH = (
+    "recommendation", "ecommerce", "similarproduct", "textclassification",
+    "complementarypurchase",
+)
+
+
 def test_every_registered_template_has_a_case():
     import predictionio_tpu.models  # noqa: F401 - templates self-register
 
@@ -154,6 +163,18 @@ def test_template_serves_through_launch_and_collect(
     ):
         [sample] = data[family]["samples"]
         assert sample.get("count", sample.get("value")) == 1, family
+    if name in OWN_LAUNCH:
+        assert (
+            type(algos[0]).batch_predict_launch
+            is not Algorithm.batch_predict_launch
+        )
+        stages = {
+            s["labels"]["stage"]: s["count"]
+            for s in data["pio_stage_seconds"]["samples"]
+        }
+        for stage in ("predict.prep", "predict.enqueue", "predict.device_get",
+                      "predict.materialize"):
+            assert stages.get(stage) == 1, stage
 
 
 class _AsyncAlgorithm(FakeAlgorithm):
@@ -194,7 +215,6 @@ def test_a_two_algorithm_engine_stages_two_batchers_a_tenant_in_a_pool(
     import urllib.request
 
     import predictionio_tpu.models  # noqa: F401 - templates self-register
-    from predictionio_tpu.core.controller import Algorithm
     from predictionio_tpu.models.similarproduct import SimilarALSAlgorithm
     from predictionio_tpu.ops import quantize
 
